@@ -729,6 +729,9 @@ def main(argv: list[str] | None = None) -> None:
     args = ap.parse_args(argv)
 
     from benchmarks import intensity, paper_tables
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     bench: dict = {"dry_run": args.dry_run, "workloads": {}}
 

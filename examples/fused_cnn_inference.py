@@ -9,10 +9,9 @@ the END tile-skip cascade firing on spatially sparse input.
 Run:  PYTHONPATH=src python examples/fused_cnn_inference.py --model lenet
       PYTHONPATH=src python examples/fused_cnn_inference.py --model resnet18
 
-Big models default to reduced spatial scale so interpret mode (CPU) stays
-quick; pass --input-size to override (the partitioner and kernels are the
-same code that handles paper scale — see benchmarks/run.py for the analytic
-224^2 numbers).
+On a TPU every model runs at its published input size with compiled
+kernels; elsewhere big models default to a reduced spatial scale so
+interpret mode stays quick.  Pass --input-size to override either default.
 """
 
 import argparse
@@ -21,6 +20,8 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
+from repro.core import resolve_interpret
 from repro.net.graph import MODELS, infer_shapes
 from repro.net.partition import auto_partition, layerwise_partition
 from repro.net.runner import (
@@ -32,8 +33,8 @@ from repro.net.runner import (
 )
 from repro.obs import tracing
 
-# interpret-friendly default scales (paper scale for LeNet only)
-DEFAULT_SIZE = {"lenet": 32, "alexnet": 67, "vgg16": 32, "resnet18": 32}
+# interpret-friendly default scales off the chip (paper scale for LeNet only)
+INTERPRET_SIZE = {"lenet": 32, "alexnet": 67, "vgg16": 32, "resnet18": 32}
 
 
 def main() -> None:
@@ -46,8 +47,13 @@ def main() -> None:
                     help="compute dtype for activations/weights; "
                          "accumulation stays f32 either way (DESIGN.md #11)")
     args = ap.parse_args()
+    use_compile_cache()
 
-    size = args.input_size or DEFAULT_SIZE[args.model]
+    interpret = resolve_interpret(None)
+    size = args.input_size or (
+        INTERPRET_SIZE[args.model] if interpret
+        else MODELS[args.model]().input_size
+    )
     graph = MODELS[args.model](input_size=size, num_classes=10,
                                compute_dtype=args.dtype)
     shapes = infer_shapes(graph)
@@ -68,8 +74,9 @@ def main() -> None:
     t0 = time.time()
     logits, skips = run_network(x, params, plan=plan)
     jax.block_until_ready(logits)
+    mode = "interpret mode" if interpret else "compiled kernels"
     print(f"run_network: logits {logits.shape} in {time.time() - t0:.1f}s "
-          "(interpret mode, includes compile)")
+          f"({jax.default_backend()} backend, {mode}, includes compile)")
     ref = reference_network(x, graph, params)
     err = float(jnp.abs(logits.astype(jnp.float32) - ref).max())
     print("max |err| vs monolithic f32 reference:", err)

@@ -82,19 +82,22 @@ def reference_forward(
     x: jnp.ndarray, spec: FusionSpec, params: PyramidParams, *, relu: bool = True
 ) -> jnp.ndarray:
     """Layer-by-layer execution with full intermediate maps (the baseline
-    dataflow whose off-chip traffic fusion eliminates)."""
+    dataflow whose off-chip traffic fusion eliminates).  Convolutions run at
+    ``highest`` matmul precision, so on a TPU the oracle is f32, not a
+    bf16-pass approximation of it."""
     ci = 0
-    for lvl in spec.levels:
-        if lvl.kind == "conv":
-            x = _conv2d(x, params.weights[ci], params.biases[ci], lvl.S, lvl.pad)
-            if relu:
-                x = jax.nn.relu(x)
-            ci += 1
-        else:
-            x = _maxpool(x, lvl.K, lvl.S)
+    with jax.default_matmul_precision("highest"):
+        for lvl in spec.levels:
+            if lvl.kind == "conv":
+                x = _conv2d(
+                    x, params.weights[ci], params.biases[ci], lvl.S, lvl.pad
+                )
+                if relu:
+                    x = jax.nn.relu(x)
+                ci += 1
+            else:
+                x = _maxpool(x, lvl.K, lvl.S)
     return x
-
-
 
 
 def fused_forward(

@@ -35,7 +35,68 @@ from dataclasses import dataclass, replace
 from .dtypes import DTYPE_BYTES, canonical_dtype
 from .fusion import FusionSpec, receptive_window
 
-VMEM_BUDGET_BYTES = 16 * 1024 * 1024  # v5e per-core VMEM
+# Planning budget for one launch's VMEM buffers: Mosaic's default scoped
+# limit on v5e (the chip has 128 MiB).
+VMEM_BUDGET_BYTES = 16 * 1024 * 1024
+
+# VMEM Mosaic takes beyond the declared buffers: vector spills, and for f32
+# operands the bf16 splits of its full-precision matmul.  Compiled for v5e,
+# a zoo launch takes up to 10.3 MiB more than its buffers, so every launch is
+# compiled with this much on top of its planning budget (the chip has
+# 128 MiB of VMEM).
+MOSAIC_HEADROOM_BYTES = 16 * 1024 * 1024
+
+# TPU vector layout: the last dim of a VMEM buffer is padded to 128 lanes, the
+# second-to-last to one sublane tile (8 rows of 32-bit, 16 of bf16).
+LANES = 128
+# HBM arrays are tiled the same way, so a DMA window over the sublane (W) dim
+# must start and end on a multiple of 8.
+DMA_ALIGN = 8
+
+
+def channel_blocks(c: int) -> tuple[int, int]:
+    """``(blocks, lanes)`` of a ``c``-channel VMEM tile buffer.
+
+    The kernel stores every tile as ``(blocks, H, W, lanes)``: channels
+    split into 128-lane blocks when they tile exactly, else one block.  A
+    strided read on the W dim needs a buffer whose last dim is 128 lanes."""
+    lanes = LANES if c % LANES == 0 else c
+    return c // lanes, lanes
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def padded_bytes(shape: tuple[int, ...], dtype: str) -> int:
+    """Bytes a VMEM buffer of ``shape`` occupies on the chip: the last dim
+    padded to 128 lanes, the one before it to a sublane tile."""
+    itemsize = DTYPE_BYTES[dtype]
+    *lead, rows, lanes = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    n = _round_up(rows, 8 * 4 // itemsize) * _round_up(lanes, LANES)
+    for d in lead:
+        n *= d
+    return n * itemsize
+
+
+def padded_lanes(n: int) -> int:
+    """``n`` rounded up to whole 128-lane vregs.  Weights are stored with
+    their output channels padded so: every MXU dot is ``(W, Cin) @ (Cin,
+    128)``, one shape for every channel tiling, which keeps tiled and
+    untiled launches bitwise equal on every backend."""
+    return _round_up(n, LANES)
+
+
+def weight_slot(levels) -> tuple[int, int, int, int]:
+    """``(K, K, Cin, Cout)`` of a streamed-weight scratch slot that can hold
+    any one of ``levels``' (lane-padded) weight tensors."""
+    return (
+        max(p.K for p in levels),
+        max(p.K for p in levels),
+        max(p.n_in for p in levels),
+        max(padded_lanes(p.n_out) for p in levels),
+    )
+
 
 # Modeled HBM service rate of the cycle model's 100 MHz accelerator, in bytes
 # per cycle (6.4 GB/s).  Only ratios matter: the constant sets how expensive a
@@ -207,58 +268,143 @@ class TileProgram:
         m = self.levels[-1].n_out
         return tuple(c for c in range(2, m // 2 + 1) if m % c == 0)
 
-    def _tile_floats(self, x_slots: int = 1, c_tiles: int = 1) -> int:
-        """Per-grid-cell pyramid tile buffers: ``x_slots`` level-0 halo-tile
-        landing buffers (DMA destinations; 2 = the revolving cross-cell
-        prefetch pipeline), the live level-0 tile value, and every level's
-        conv/pool output tile.  With ``c_tiles > 1`` the last level's
-        conv/pool tiles hold one ``Cout / c_tiles`` channel block at a time
-        (the per-``k`` working tile of the channel-tiled grid), and a Q > 1
-        chain additionally carries the *persistent* mid-pyramid scratch the
-        kernel re-reads at ``k > 0`` — live alongside the transient mid
-        tiles at ``k == 0``, so it is counted on top of them."""
-        c0 = self.levels[0].n_in
-        floats = (1 + x_slots) * self.tile0 ** 2 * c0
-        for li, p in enumerate(self.levels):
-            n_out = p.n_out
-            if li == len(self.levels) - 1:
-                n_out = -(-n_out // c_tiles)
-            floats += p.out_size ** 2 * n_out
+    def input_window(self) -> int:
+        """W extent of the level-0 landing buffer: the columns one cell's
+        halo DMA moves.  A 1x1 grid takes the whole (aligned) padded row; a
+        larger grid takes a :data:`DMA_ALIGN`-aligned window wide enough to
+        hold the ``tile0`` halo at every cell's offset inside it."""
+        if self.alpha == 1:
+            return _round_up(self.padded_input, DMA_ALIGN)
+        slack = max((j * self.stride0) % DMA_ALIGN for j in range(self.alpha))
+        return _round_up(self.tile0 + slack, DMA_ALIGN)
+
+    def input_cols(self) -> int:
+        """W extent the kernel needs of its input: a multiple of
+        :data:`DMA_ALIGN` that holds the last cell's aligned window."""
+        if self.alpha == 1:
+            return self.input_window()
+        last = (self.alpha - 1) * self.stride0 // DMA_ALIGN * DMA_ALIGN
+        return max(
+            _round_up(self.padded_input, DMA_ALIGN), last + self.input_window()
+        )
+
+    def input_lanes(self) -> int:
+        """Channel extent the kernel needs of its input: a DMA moves whole
+        128-lane tiles, so fewer channels than one block pad to 128."""
+        cb, cl = channel_blocks(self.levels[0].n_in)
+        return cb * (padded_lanes(cl) if cb == 1 else cl)
+
+    def staged_levels(self) -> tuple[bool, ...]:
+        """Per level: whether its input rows go through the f32 row stage.
+        Mosaic reads a strided W window only from a 32-bit, 128-lane buffer,
+        and a packed bf16 buffer only at a static offset; level 0 of a grid
+        with ``alpha > 1`` reads at a per-cell (dynamic) column offset."""
+        return tuple(
+            p.S > 1 or (li == 0 and self.alpha > 1)
+            for li, p in enumerate(self.levels)
+        )
+
+    def vmem_buffers(
+        self,
+        x_slots: int = 1,
+        c_tiles: int = 1,
+        *,
+        streamed: bool = False,
+        w_slots: int = 1,
+    ) -> list[tuple[str, tuple[int, ...], str]]:
+        """Every VMEM buffer one launch allocates, as ``(name, shape,
+        dtype)`` — the single list the kernel wrapper allocates scratch from
+        and :meth:`vmem_bytes` / :meth:`vmem_stream_bytes` price.
+
+        Tiles are stored channel-blocked (:func:`channel_blocks`): the
+        ``x_slots`` level-0 landing slots, the f32 row stage of staged
+        levels (:meth:`staged_levels`), the f32 conv output of each pooled
+        level (the pool reads it strided, so its lanes pad to 128), and each
+        non-last level's output tile.  Pallas double-buffers the output and
+        skip-flag blocks; weights and biases whose block is the whole array
+        are single-buffered.  With ``c_tiles > 1`` the last level works on
+        ``Cout / c_tiles`` channels at a time and its weights are laid out
+        ``(c_tiles, K, K, Cin, Cout / c_tiles)``.  Streamed launches hold
+        ``w_slots`` ring slots sized for the largest level (untiled) or one
+        blocking mid-level slot plus ``w_slots`` slice slots (tiled)."""
+        cdt = self.compute_dtype
+        levels = self.levels
+        q = len(levels)
+        last = levels[-1]
+        ct = last.n_out // c_tiles
+        cb0 = channel_blocks(levels[0].n_in)[0]
+        ww = self.input_window()
+        bufs = [(
+            "x_land",
+            (x_slots, cb0, self.tile0, ww, self.input_lanes() // cb0),
+            cdt,
+        )]
+        staged = [li for li, s in enumerate(self.staged_levels()) if s]
+        if staged:
+            width = max(ww if li == 0 else levels[li].in_size for li in staged)
+            lanes = max(
+                _round_up(channel_blocks(levels[li].n_in)[1], LANES)
+                for li in staged
+            )
+            bufs.append(("stage", (width, lanes), "float32"))
+        for li, p in enumerate(levels):
+            cb, cl = channel_blocks(ct if li == q - 1 else p.n_out)
             if p.pool is not None:
-                floats += p.pool_out ** 2 * n_out
-        if c_tiles > 1 and len(self.levels) > 1:
-            last = self.levels[-1]
-            floats += last.in_size ** 2 * last.n_in  # mid_scratch carry
-        return floats
+                bufs.append((
+                    "conv_out",
+                    (cb, p.out_size, p.out_size, _round_up(cl, LANES)),
+                    "float32",
+                ))
+            if li < q - 1:
+                bufs.append(("mid", (cb, p.pool_out, p.pool_out, cl), cdt))
+        region = self.out_region
+        bufs.append(("out_block", (2, region, region, ct), cdt))
+        bufs.append(("skip_block", (2, 1, q), "int32"))
+        for li, p in enumerate(levels):
+            tiled = li == q - 1 and c_tiles > 1
+            bufs.append(("bias", (c_tiles, 1, ct) if tiled else (1, p.n_out), cdt))
+            if not streamed:
+                shape = (p.K, p.K, p.n_in, padded_lanes(ct if tiled else p.n_out))
+                bufs.append(("weights", (c_tiles, *shape) if tiled else shape, cdt))
+        if streamed:
+            if c_tiles > 1:
+                if q > 1:
+                    bufs.append(("w_mid", (1, *weight_slot(levels[:-1])), cdt))
+                bufs.append(
+                    ("w_slices",
+                     (w_slots, last.K, last.K, last.n_in, padded_lanes(ct)), cdt)
+                )
+            else:
+                bufs.append(("w_ring", (w_slots, *weight_slot(levels)), cdt))
+        return bufs
 
     def vmem_bytes(self, x_slots: int = 1, c_tiles: int = 1) -> int:
-        """Resident working set of one kernel instance, in bytes.
+        """VMEM one resident-weight launch allocates, in bytes, padded as the
+        chip lays it out (:func:`padded_bytes` over :meth:`vmem_buffers`).
 
-        The input stays in HBM; only the level-0 halo tile (``tile0 x tile0``,
-        DMA'd per grid cell into one of ``x_slots`` landing slots) is
-        VMEM-resident, plus all weights ("filters are loaded into the kernel
-        buffers only once", §3.3.1) and the per-level tile buffers of the
-        pyramid.  ``c_tiles`` only shrinks the last level's working tile —
-        resident weights stay whole, so channel tiling is a streamed-regime
-        tool (the planner never picks it resident); the resident kernel still
-        accepts it for parity testing.  Every buffer holds ``compute_dtype``
-        values (the per-level f32 dot accumulator is compiler-managed vector
-        state, not declared scratch), so the whole set scales with
-        ``bytes_per_val`` — halving it is what flips streamed plans back to
-        resident under bf16.
+        The input stays in HBM; only the level-0 halo rows (DMA'd per grid
+        cell into one of ``x_slots`` landing slots) are VMEM-resident, plus
+        all weights ("filters are loaded into the kernel buffers only once",
+        §3.3.1) and the per-level tile buffers of the pyramid.  ``c_tiles``
+        only shrinks the last level's working tile — resident weights stay
+        whole, so channel tiling is a streamed-regime tool (the planner never
+        picks it resident); the resident kernel still accepts it for parity
+        testing.  Tiles, weights and the output move at ``compute_dtype``;
+        the row stage and pooled conv outputs are f32.
         """
-        return self.bytes_per_val * (
-            self._tile_floats(x_slots, c_tiles) + self.weight_floats()
+        return sum(
+            padded_bytes(shape, dt)
+            for _, shape, dt in self.vmem_buffers(x_slots, c_tiles)
         )
 
     def vmem_stream_bytes(
         self, slots: int = 1, x_slots: int = 1, c_tiles: int = 1
     ) -> int:
-        """Working set with per-level weight streaming: only ``slots`` copies
-        of the largest single level's weights are VMEM-resident at once
-        (DMA'd from HBM level by level; ``slots=2`` is the double-buffered
-        pipeline that overlaps level ``l+1``'s fetch with level ``l``'s
-        compute); biases stay resident.  The fallback when
+        """VMEM of a launch with per-level weight streaming: only ``slots``
+        copies of the largest single level's weights are VMEM-resident at
+        once (DMA'd from HBM level by level; ``slots=2`` is the
+        double-buffered pipeline that overlaps level ``l+1``'s fetch with
+        level ``l``'s compute); biases stay resident.  The fallback when
         :meth:`vmem_bytes` busts the budget — e.g. ResNet-18's last block,
         whose two 512x512 3x3 weight tensors alone exceed 16 MiB.
         ``x_slots`` counts input landing buffers as in :meth:`vmem_bytes`.
@@ -269,16 +415,12 @@ class TileProgram:
         the largest mid level — streamed slices shrink by ``c_tiles``, which
         is what lets ResNet-18 b7 afford the double-buffered ``slots=2``
         regime its untiled weights bust."""
-        cnts = self.level_weight_counts()
-        floats = self._tile_floats(x_slots, c_tiles)
-        if c_tiles > 1:
-            if len(cnts) > 1:
-                floats += max(cnts[:-1])  # one blocking mid-level slot
-            floats += slots * -(-cnts[-1] // c_tiles)  # per-k slice slots
-        else:
-            floats += slots * max(cnts)
-        floats += sum(p.n_out for p in self.levels)  # biases
-        return self.bytes_per_val * floats
+        return sum(
+            padded_bytes(shape, dt)
+            for _, shape, dt in self.vmem_buffers(
+                x_slots, c_tiles, streamed=True, w_slots=slots
+            )
+        )
 
     def resolve_stream_regime(
         self,

@@ -12,12 +12,17 @@ grid.
 The kernel is compiled from a :class:`~repro.core.program.TileProgram` — the
 single tile-program lowering shared with the value-level executor — and
 receives one ``ConvLevelProg`` per conv level (pool epilogues folded in).
+Every VMEM buffer it allocates comes from
+:meth:`~repro.core.program.TileProgram.vmem_buffers`, the same list the
+planner prices, and it is compiled with ``vmem_limit_bytes`` set from the
+budget the plan was made under.
 
 Per grid cell (b, i, j):
-  * the input stays in HBM (memory space ANY); the level-0 halo tile
-    (``tile0 x tile0``, neighbours overlapping by the pyramid halo) is DMA'd
-    into a VMEM landing buffer with ``make_async_copy`` at offset
-    ``(i*stride0, j*stride0)`` — per-cell input traffic is ``tile0^2 * C``
+  * the input stays in HBM (memory space ANY); the level-0 halo rows
+    (``tile0`` rows starting at ``i*stride0``; the whole padded row on a 1x1
+    grid, else an 8-aligned column window holding the ``tile0`` halo at
+    column ``j*stride0``) are DMA'd into a VMEM landing buffer with
+    ``make_async_copy`` — per-cell input traffic is about ``tile0^2 * C``
     (Algorithm 4's uniform minimal movement), not the whole padded image;
   * with ``x_slots=2`` the landing buffer is a *revolving two-slot pipeline
     across grid cells*: before running its own pyramid, cell ``n`` (row-major
@@ -32,9 +37,13 @@ Per grid cell (b, i, j):
     prefetch precedes the cascade, outside every liveness branch), so a dead
     region never stalls the pipeline.  ``x_slots=1`` is the serial
     start();wait() path — bit-identical, only the movement schedule differs;
-  * conv levels run as K*K unrolled strided-slice + MXU dot-general
-    (``(P, Cin) @ (Cin, Cout)``) accumulations — the WPU array of Fig. 5 maps
-    onto MXU tiles;
+  * conv levels run one output row at a time from VMEM refs: K*K
+    ``(W, Cin) @ (Cin, Cout)`` MXU dots per row, f32-accumulated, each
+    reading its input window with a (possibly strided) ref read — the WPU
+    array of Fig. 5 maps onto MXU tiles.  Tiles are channel-blocked
+    ``(blocks, H, W, lanes)`` (:func:`~repro.core.program.channel_blocks`);
+    strided and per-cell-offset reads go through an f32 row stage, the only
+    form Mosaic reads them from;
   * inner-layer padding is realized by *validity masking*: rows whose global
     coordinate falls outside a level's valid output range are zeroed — zeros
     are exactly the next level's pad value, and post-ReLU zeros are neutral
@@ -45,14 +54,15 @@ Per grid cell (b, i, j):
     output collapses to the closed form ``epilogue(relu(b_l))``; the constant
     tile feeds the next level, which applies the same test — so a dead tile
     with non-positive downstream biases short-circuits the whole remaining
-    pyramid.  A per-level skip flag is emitted for energy/cycle statistics.
+    pyramid.  Each level tracks the max of what it stored, which is the next
+    level's test.  A per-level skip flag is emitted for statistics.
 
 Weight regimes ("filters are loaded into the kernel buffers only once",
 §3.3.1, vs the VMEM-busting fallback):
   * resident — all weights live whole in VMEM for the launch;
-  * streamed, double-buffered (``w_slots=2``) — weights stay in HBM as one
-    flat array; level ``l+1``'s slice is DMA'd into the idle scratch slot
-    before level ``l``'s MXU pass so the transfer hides behind compute
+  * streamed, double-buffered (``w_slots=2``) — each level's weights stay in
+    HBM; level ``l+1``'s tensor is DMA'd into the idle scratch slot before
+    level ``l``'s MXU pass so the transfer hides behind compute
     (START-wait-flip).  The prefetch for level ``l+1`` is issued inside level
     ``l``'s *live* branch, so a cascade of END-skipped levels issues no
     weight DMAs at all; the one speculative case (level ``l`` live but its
@@ -62,12 +72,8 @@ Weight regimes ("filters are loaded into the kernel buffers only once",
     level, when even two copies of the largest level's weights bust VMEM
     (e.g. ResNet-18's 512-channel block).
 
-The VMEM working set of each regime is accounted by
-:meth:`~repro.core.program.TileProgram.vmem_bytes` /
-:meth:`~repro.core.program.TileProgram.vmem_stream_bytes` and asserted in
-ops.py; the regime itself is chosen once by
-:func:`~repro.core.program.plan_launch` so planner cost and launched kernel
-can never disagree.
+The regime itself is chosen once by :func:`~repro.core.program.plan_launch`
+so planner cost and launched kernel can never disagree.
 """
 
 from __future__ import annotations
@@ -81,411 +87,545 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import resolve_interpret
 from repro.core.dtypes import EXEC_DTYPES, jnp_dtype
-from repro.core.program import ConvLevelProg, TileProgram  # noqa: F401 (re-export)
+from repro.core.program import (  # noqa: F401 (ConvLevelProg re-export)
+    DMA_ALIGN,
+    LANES,
+    ConvLevelProg,
+    TileProgram,
+    channel_blocks,
+    padded_lanes,
+    weight_slot,
+)
+
+# vmem_buffers entries the kernel allocates as scratch (the rest are the
+# BlockSpec buffers Pallas allocates itself)
+_SCRATCH = ("x_land", "stage", "conv_out", "mid", "w_ring", "w_mid", "w_slices")
 
 
-def _conv_tile(x, w, b, K: int, S: int, out: int):
-    """Valid conv on a (h, w, Cin) tile via K*K strided-slice MXU dots.
+def _window(n: int, full: int):
+    """Index of the first ``n`` of ``full`` elements: the whole dim when it
+    covers it (Mosaic refuses an explicit full-extent slice of a tiled dim)."""
+    return slice(None) if n == full else pl.ds(0, n)
 
-    Operands may be any compute dtype (f32 or bf16); the accumulator is
-    always float32 via ``preferred_element_type`` — DESIGN.md §11's
-    "low-precision operands, full-precision accumulation" contract, the MXU's
-    native mixed-precision mode.  The bias add also runs in f32 (the f32
-    accumulator promotes a bf16 ``b``)."""
-    cin, cout = w.shape[2], w.shape[3]
-    acc = jnp.zeros((out * out, cout), jnp.float32)
-    hi = (out - 1) * S + 1
-    for ki in range(K):
-        for kj in range(K):
-            patch = x[ki : ki + hi : S, kj : kj + hi : S, :]
-            acc = acc + jnp.dot(
-                patch.reshape(out * out, cin),
-                w[ki, kj],
-                preferred_element_type=jnp.float32,
+
+def _lanes(c: int, cl: int, full: int):
+    """Index of channel block ``c`` (``cl`` lanes) of a ``full``-lane dim."""
+    return slice(None) if cl == full else pl.ds(c * cl, cl)
+
+
+class _Copies(list):
+    """DMA descriptors started and waited on together."""
+
+    def start(self):
+        for cp in self:
+            cp.start()
+
+    def wait(self):
+        for cp in self:
+            cp.wait()
+
+
+class _Tile:
+    """A channel-blocked ``(blocks, H, W, lanes)`` VMEM tile (``lead``
+    indexes a slot of a larger buffer), read from column ``off`` on."""
+
+    def __init__(self, ref, lead=(), off=0):
+        self.ref, self.lead, self.off = ref, lead, off
+
+    def row(self, c, r):
+        return self.ref[(*self.lead, c, r)]
+
+    def window(self, c, r, start, n):
+        return self.ref[(*self.lead, c, r, pl.ds(start, n), slice(None))]
+
+
+def _in_range(g, valid: int):
+    return (g >= 0) & (g < valid)
+
+
+def _emit(emit, r, c, v, cdt, m):
+    """Cast channel block ``c`` of output row ``r`` (f32) to the compute
+    dtype once, store it, and fold its max into ``m``."""
+    v = v.astype(cdt)
+    emit(r, c, v)
+    return jnp.maximum(m, jnp.max(v.astype(jnp.float32)))
+
+
+def _conv_level(
+    src: _Tile,
+    w_at,
+    bias,
+    prog: ConvLevelProg,
+    idx,
+    *,
+    n_out: int,
+    relu: bool,
+    dots: bool,
+    stage,
+    conv_out,
+    emit,
+    cdt,
+):
+    """One conv level and its pool epilogue, one output row at a time.
+
+    ``w_at(ki, kj, c, o)`` gives tap ``(ki, kj)``'s ``(lanes, 128)`` weights
+    for input channel block ``c`` and 128-lane output block ``o``; ``bias``
+    is ``(1, n_out)`` f32.
+    ``dots=False`` is the closed form of an all-zero input (the conv output
+    is the bias everywhere) — bit-identical to the live path, which adds the
+    bias to an exact-zero accumulator.  Rows are masked to the level's valid
+    range, pooled from the f32 ``conv_out`` buffer, masked again, cast once,
+    and handed to ``emit(r, c, value)``.  Returns the max of the emitted
+    values: the END predicate of the next level."""
+    cb_in, cl_in = channel_blocks(prog.n_in)
+    cb, cl = channel_blocks(n_out)
+    n_blocks = padded_lanes(n_out) // LANES
+    # Mosaic multiplies f32 operands in one bf16 pass unless asked for full
+    # f32 passes (bf16 operands are exact in one pass, and refuse HIGHEST)
+    precision = jax.lax.Precision.HIGHEST if cdt == jnp.float32 else None
+    K, S, W = prog.K, prog.S, prog.out_size
+    g0 = prog.o_base + idx[0] * prog.o_step
+    g1 = prog.o_base + idx[1] * prog.o_step
+    col_ok = _in_range(
+        jax.lax.broadcasted_iota(jnp.int32, (W, n_out), 0) + g1, prog.valid
+    )
+
+    def patch(c, r, ki, kj):
+        if stage is None:
+            return src.window(c, r + ki, src.off + kj, W)[:, :cl_in]
+        rows = pl.ds(src.off + kj, W, stride=S) if S > 1 else pl.ds(src.off + kj, W)
+        return stage[rows, :][:, :cl_in]
+
+    def conv_row(r, m):
+        acc = jnp.zeros((W, n_out), jnp.float32)
+        if dots:
+            accs = [jnp.zeros((W, LANES), jnp.float32)] * n_blocks
+            for ki in range(K):
+                for c in range(cb_in):
+                    if stage is not None:
+                        v = src.row(c, r * S + ki)[:, :cl_in]
+                        stage[: v.shape[0], :cl_in] = v.astype(jnp.float32)
+                    for kj in range(K):
+                        lhs = patch(c, r, ki, kj).astype(cdt)
+                        accs = [
+                            a + jnp.dot(lhs, w_at(ki, kj, c, o),
+                                        precision=precision,
+                                        preferred_element_type=jnp.float32)
+                            for o, a in enumerate(accs)
+                        ]
+            acc = accs[0] if n_blocks == 1 else jnp.concatenate(accs, axis=1)
+            acc = acc[:, :n_out]
+        acc = acc + bias
+        if relu:
+            acc = jnp.maximum(acc, 0.0)
+        acc = acc * (col_ok & _in_range(r + g0, prog.valid))
+        for c in range(cb):
+            block = acc[:, c * cl : (c + 1) * cl]
+            if prog.pool is None:
+                m = _emit(emit, r, c, block, cdt, m)
+            else:
+                conv_out[c, r, :, :cl] = block
+        return m
+
+    m = jax.lax.fori_loop(0, W, conv_row, jnp.float32(0.0))
+    if prog.pool is None:
+        return m
+
+    pk, ps = prog.pool
+    P = prog.pool_out
+    pg0 = prog.pool_o_base + idx[0] * prog.pool_o_step
+    pg1 = prog.pool_o_base + idx[1] * prog.pool_o_step
+    pcol_ok = _in_range(
+        jax.lax.broadcasted_iota(jnp.int32, (P, cl), 0) + pg1, prog.pool_valid
+    )
+
+    def pool_row(r, m):
+        ok = pcol_ok & _in_range(r + pg0, prog.pool_valid)
+        for c in range(cb):
+            mx = None
+            for pi in range(pk):
+                for pj in range(pk):
+                    cols = pl.ds(pj, P, stride=ps) if ps > 1 else pl.ds(pj, P)
+                    v = conv_out[c, r * ps + pi, cols, :][:, :cl]
+                    mx = v if mx is None else jnp.maximum(mx, v)
+            m = _emit(emit, r, c, mx * ok, cdt, m)
+        return m
+
+    return jax.lax.fori_loop(0, P, pool_row, jnp.float32(0.0))
+
+
+def _flag_vector(flags):
+    """The per-level int32 skip flags as one ``(1, Q)`` row."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, (1, len(flags)), 1)
+    row = jnp.zeros((1, len(flags)), jnp.int32)
+    for l, f in enumerate(flags):
+        row = jnp.where(iota == l, f, row)
+    return row
+
+
+class _Launch:
+    """Static per-launch context shared by both kernel bodies: the program,
+    the named scratch buffers and the halo DMA."""
+
+    def __init__(self, program: TileProgram, scratch, names, x_hbm, x_sem):
+        self.prog = program
+        self.cdt = jnp_dtype(program.compute_dtype)
+        bufs: dict[str, list] = {}
+        for name, ref in zip(names, scratch):
+            bufs.setdefault(name, []).append(ref)
+        self.bufs = bufs
+        self.x_land = bufs["x_land"][0]
+        self.stage = bufs.get("stage", [None])[0]
+        pooled = iter(bufs.get("conv_out", []))
+        self.conv_out = [
+            next(pooled) if p.pool is not None else None for p in program.levels
+        ]
+        self.mid = bufs.get("mid", [])
+        self.staged = program.staged_levels()
+        self.x_hbm, self.x_sem = x_hbm, x_sem
+
+    def x_dma(self, bi, ii, jj, slot) -> _Copies:
+        """Halo DMA of cell (bi, ii, jj) into landing slot ``slot``.  All
+        cells of a prefetch chain share ``bi``: the batch axis is
+        ``parallel`` (possibly core-partitioned), so the chain must never
+        cross a batch boundary."""
+        p = self.prog
+        cb = channel_blocks(p.levels[0].n_in)[0]
+        cl = p.input_lanes() // cb
+        rows = pl.ds(ii * p.stride0, p.tile0)
+        if p.alpha == 1:
+            cols = slice(None)
+        else:
+            start = jj * p.stride0 // DMA_ALIGN * DMA_ALIGN
+            cols = pl.ds(pl.multiple_of(start, DMA_ALIGN), p.input_window())
+        return _Copies(
+            pltpu.make_async_copy(
+                self.x_hbm.at[bi, rows, cols, _lanes(c, cl, cb * cl)],
+                self.x_land.at[slot, c],
+                self.x_sem.at[slot],
             )
-    return acc.reshape(out, out, cout) + b
+            for c in range(cb)
+        )
+
+    def x_tile(self, j, slot) -> _Tile:
+        """The level-0 input: landing slot ``slot``, read from cell column
+        ``j``'s offset inside the aligned window."""
+        p = self.prog
+        off = 0
+        if p.alpha > 1:
+            off = j * p.stride0 - j * p.stride0 // DMA_ALIGN * DMA_ALIGN
+        return _Tile(self.x_land, (slot,), off)
+
+    def fetch_halo(self, bi, i, j, x_slots, during=None):
+        """Land cell (i, j)'s halo and return its slot.  With ``x_slots=2``
+        the first cell of each batch element self-fetches and every cell
+        starts its successor's fetch into the idle slot before waiting on its
+        own.  ``during()`` runs between the starts and the wait (the weight
+        pipeline's warm-up)."""
+        alpha = self.prog.alpha
+        if x_slots > 1:
+            cell = i * alpha + j
+            slot = jax.lax.rem(cell, x_slots)
+
+            @pl.when(cell == 0)
+            def _():
+                self.x_dma(bi, i, j, slot).start()
+
+            ni = jnp.where(j == alpha - 1, i + 1, i)
+            nj = jnp.where(j == alpha - 1, 0, j + 1)
+
+            @pl.when(cell + 1 < alpha * alpha)
+            def _():  # issued unconditionally w.r.t. the END cascade
+                self.x_dma(bi, ni, nj, 1 - slot).start()
+        else:
+            slot = 0
+            self.x_dma(bi, i, j, slot).start()
+        if during is not None:
+            during()
+        self.x_dma(bi, i, j, slot).wait()
+        return slot
+
+    def level(self, l, src, w_at, bias, idx, emit, *, n_out, relu, dots):
+        stage = self.stage if self.staged[l] else None
+        return _conv_level(
+            src, w_at, bias, self.prog.levels[l], idx, n_out=n_out, relu=relu,
+            dots=dots, stage=stage, conv_out=self.conv_out[l], emit=emit,
+            cdt=self.cdt,
+        )
+
+    def mid_emit(self, l):
+        def emit(r, c, v):
+            self.mid[l][c, r] = v
+        return emit
 
 
-def _pool_tile(x, K: int, S: int):
-    out = (x.shape[0] - K) // S + 1
-    hi = (out - 1) * S + 1
-    r = None
-    for pi in range(K):
-        for pj in range(K):
-            v = x[pi : pi + hi : S, pj : pj + hi : S, :]
-            r = v if r is None else jnp.maximum(r, v)
-    return r
+def _out_emit(out_ref, n_out: int):
+    """Store rows of the launch's output block, channel block by block."""
+    cb, cl = channel_blocks(n_out)
+
+    def emit(r, c, v):
+        out_ref[0, 0, 0, 0, r, :, _lanes(c, cl, n_out)] = v
+    return emit
 
 
-def _mask(t, idx, o_base: int, o_step: int, valid: int):
-    """Zero rows/cols whose global coordinate is outside [0, valid)."""
-    g0 = o_base + idx[0] * o_step
-    g1 = o_base + idx[1] * o_step
-    rows = jnp.arange(t.shape[0])
-    cols = jnp.arange(t.shape[1])
-    mrow = (rows + g0 >= 0) & (rows + g0 < valid)
-    mcol = (cols + g1 >= 0) & (cols + g1 < valid)
-    return t * (mrow[:, None, None] & mcol[None, :, None])
+def _slot_reader(ref, slot, prog: ConvLevelProg, full):
+    """``w_at`` over the ``(K, K, Cin, Cout)`` weights held in ``ref[slot]``
+    (a scratch slot of shape ``full``, possibly larger than this level)."""
+    _, cl = channel_blocks(prog.n_in)
+
+    def w_at(ki, kj, c, o):
+        return ref[slot, ki, kj, pl.ds(c * cl, cl), _lanes(o, LANES, full[3])]
+    return w_at
 
 
-def _level_epilogue(t, idx, prog: ConvLevelProg):
-    """Mask conv output to its valid range, pool, mask the pool output."""
-    t = _mask(t, idx, prog.o_base, prog.o_step, prog.valid)
-    if prog.pool is not None:
-        t = _pool_tile(t, *prog.pool)
-        t = _mask(t, idx, prog.pool_o_base, prog.pool_o_step, prog.pool_valid)
-    return t
+def _ref_reader(ref, lead=()):
+    """``w_at`` over a whole (lane-padded) weight tensor ``ref[lead]``."""
+    cin, cout = ref.shape[-2:]
+    _, cl = channel_blocks(cin)
+
+    def w_at(ki, kj, c, o):
+        return ref[(*lead, ki, kj, _lanes(c, cl, cin), _lanes(o, LANES, cout))]
+    return w_at
 
 
-def _const_level(idx, prog: ConvLevelProg, b, relu: bool, out_dtype):
-    """Closed form of a level whose input tile is all zero: the conv output
-    is the bias everywhere, so the tile is ``epilogue(relu(b))``.
-
-    Bit-identical to the live path at every compute dtype: the live path
-    accumulates ``0 + b`` in f32 then casts after the epilogue, and relu /
-    validity masks / maxpool all commute exactly with the f32->bf16
-    round-trip of a bf16-representable ``b`` (monotone or multiply-by-{0,1}
-    ops on exactly-representable values)."""
-    c = jnp.maximum(b, 0.0) if relu else b
-    t = jnp.broadcast_to(c, (prog.out_size, prog.out_size, c.shape[-1]))
-    return _level_epilogue(t, idx, prog).astype(out_dtype)
+def _slot_dma(w_hbm, ring, slot, prog: ConvLevelProg, full, sem):
+    """DMA one level's whole lane-padded ``(K, K, Cin, Cout)`` tensor into
+    a slot."""
+    shape = (prog.K, prog.K, prog.n_in, padded_lanes(prog.n_out))
+    dst = ring.at[(slot,) + tuple(_window(n, f) for n, f in zip(shape, full))]
+    return pltpu.make_async_copy(w_hbm, dst, sem)
 
 
 def _pyramid_kernel(
     *refs,
-    progs: tuple[ConvLevelProg, ...],
-    tile0: int,
-    stride0: int,
-    alpha: int,
+    program: TileProgram,
+    names: tuple[str, ...],
     relu: bool,
     end_skip: bool,
     stream: bool,
     w_slots: int,
     x_slots: int,
-    cnts: tuple[int, ...],
-    out_dtype,
 ):
+    progs = program.levels
     q = len(progs)
     x_hbm = refs[0]
-    if stream:
-        # weights arrive as one flat HBM-space array; each level's slice is
-        # DMA'd into one of the w_slots VMEM scratch slots.
-        wflat_ref = refs[1]
-        b_refs = refs[2 : 2 + q]
-        out_ref, skip_ref = refs[2 + q], refs[3 + q]
-        x_scratch, x_sem = refs[4 + q], refs[5 + q]
-        w_scratch, w_sem = refs[6 + q], refs[7 + q]
-    else:
-        w_refs = refs[1 : 1 + 2 * q : 2]
-        b_refs = refs[2 : 2 + 2 * q : 2]
-        out_ref, skip_ref = refs[1 + 2 * q], refs[2 + 2 * q]
-        x_scratch, x_sem = refs[3 + 2 * q], refs[4 + 2 * q]
+    w_refs = refs[1 : 1 + 2 * q : 2]
+    b_refs = refs[2 : 2 + 2 * q : 2]
+    out_ref, skip_ref = refs[1 + 2 * q], refs[2 + 2 * q]
+    scratch = refs[3 + 2 * q :]
+    sems = scratch[len(names) :]
+    ctx = _Launch(program, scratch, names, x_hbm, sems[0])
     bi = pl.program_id(0)
     i = pl.program_id(1)
     j = pl.program_id(2)
     idx = (i, j)
 
-    offs = [sum(cnts[:l]) for l in range(q)]
+    if stream:
+        ring, w_sem = ctx.bufs["w_ring"][0], sems[1]
+        full = weight_slot(progs)
 
-    def w_dma(l):
-        """DMA descriptor for level l's weight slice into its scratch slot."""
-        return pltpu.make_async_copy(
-            wflat_ref.at[pl.ds(offs[l], cnts[l])],
-            w_scratch.at[l % w_slots, pl.ds(0, cnts[l])],
-            w_sem.at[l % w_slots],
-        )
+        def w_dma(l):
+            """Level l's weights into its ring slot."""
+            return _slot_dma(
+                w_refs[l], ring, l % w_slots, progs[l], full, w_sem.at[l % w_slots]
+            )
 
-    def x_dma(ii, jj, slot):
-        """DMA descriptor for cell (bi, ii, jj)'s halo tile into one landing
-        slot.  All cells of the chain share ``bi``: the batch axis is
-        ``parallel`` (possibly core-partitioned), so the prefetch chain must
-        never cross a batch boundary."""
-        return pltpu.make_async_copy(
-            x_hbm.at[
-                bi, pl.ds(ii * stride0, tile0), pl.ds(jj * stride0, tile0), :
-            ],
-            x_scratch.at[slot],
-            x_sem.at[slot],
-        )
+    warm = None
+    if stream and w_slots > 1:
+        def warm():  # pipeline warm-up: level 0 always computes
+            w_dma(0).start()
+    slot = ctx.fetch_halo(bi, i, j, x_slots, during=warm)
+    src = ctx.x_tile(j, slot)
 
-    # ---- halo tile fetch: HBM -> VMEM landing buffer(s), overlapped with
-    # the level-0 weight DMA in the double-buffered streamed regime ----
-    if x_slots > 1:
-        # revolving cross-cell pipeline: cell n's tile was prefetched by cell
-        # n-1 into slot n % 2; this cell starts cell n+1's fetch into the
-        # idle slot (just vacated by cell n-1) before waiting on its own.
-        cell = i * alpha + j
-        slot = jax.lax.rem(cell, x_slots)
-
-        @pl.when(cell == 0)
-        def _():  # warm-up: each batch element's first cell self-fetches
-            x_dma(i, j, slot).start()
-
-        ni = jnp.where(j == alpha - 1, i + 1, i)
-        nj = jnp.where(j == alpha - 1, 0, j + 1)
-
-        @pl.when(cell + 1 < alpha * alpha)
-        def _():  # issued unconditionally w.r.t. the END cascade
-            x_dma(ni, nj, 1 - slot).start()
-
-        if stream and w_slots > 1:
-            w_dma(0).start()  # pipeline warm-up: level 0 always computes
-        x_dma(i, j, slot).wait()
-        t = x_scratch[slot]
-    else:
-        serial_dma = x_dma(i, j, 0)
-        serial_dma.start()
-        if stream and w_slots > 1:
-            w_dma(0).start()  # pipeline warm-up: level 0 always computes
-        serial_dma.wait()
-        t = x_scratch[0]
-
-    skips = []
+    flags = []
     # per level: None = statically live (always computed), else the traced
     # liveness predicate — the prefetch-bookkeeping contract: level l+1's
     # weight DMA was issued iff level l ran its live branch.
     live_flags: list = []
+    m = None
     for l, prog in enumerate(progs):
         prev_live = live_flags[l - 1] if l else None
-        statically_live = l == 0 or not (end_skip and relu)
-        if stream:
-            def fetch_w(l=l, prog=prog, cnt=cnts[l], prev_live=prev_live):
-                # called inside level l's live branch only
-                if w_slots > 1:
-                    if l > 0 and prev_live is not None:
-                        # predecessor skipped => no prefetch: fetch on demand
-                        @pl.when(jnp.logical_not(prev_live))
-                        def _():
-                            w_dma(l).start()
-                else:
-                    w_dma(l).start()
-                w_dma(l).wait()
-                return w_scratch[l % w_slots, 0:cnt].reshape(
-                    prog.K, prog.K, prog.n_in, prog.n_out
-                )
-        else:
-            def fetch_w(l=l):
-                return w_refs[l][...]
+        emit = ctx.mid_emit(l) if l < q - 1 else _out_emit(out_ref, prog.n_out)
+        bias = b_refs[l][...].astype(jnp.float32)
 
-        b = b_refs[l][...]
+        def fetch_w(l=l, prev_live=prev_live):
+            # called inside level l's live branch only
+            if not stream:
+                return _ref_reader(w_refs[l])
+            if w_slots > 1:
+                if l > 0 and prev_live is not None:
+                    # predecessor skipped => no prefetch: fetch on demand
+                    @pl.when(jnp.logical_not(prev_live))
+                    def _():
+                        w_dma(l).start()
+            else:
+                w_dma(l).start()
+            w_dma(l).wait()
+            return _slot_reader(ring, l % w_slots, progs[l], full)
 
-        def run_level(t_in, fetch_w=fetch_w, b=b, prog=prog, l=l):
-            w = fetch_w()
+        def run_level(l=l, src=src, emit=emit, bias=bias, fetch_w=fetch_w):
+            w_at = fetch_w()
             if stream and w_slots > 1 and l + 1 < q:
                 # double-buffer flip: start the next level's weight DMA into
                 # the idle slot before this level's K^2 MXU pass
                 w_dma(l + 1).start()
-            tl = _conv_tile(t_in, w, b, prog.K, prog.S, prog.out_size)
-            if relu:
-                tl = jnp.maximum(tl, 0.0)
-            # relu/mask/pool run in the f32 accumulator dtype; the cast to
-            # the compute dtype happens once, after the epilogue, so every
-            # inter-level tile (VMEM and HBM alike) is compute-dtype wide
-            return _level_epilogue(tl, idx, prog).astype(out_dtype)
+            return ctx.level(l, src, w_at, bias, idx, emit,
+                             n_out=progs[l].n_out, relu=relu, dots=True)
 
-        if statically_live:
+        def skip_level(l=l, src=src, emit=emit, bias=bias, prev_live=prev_live):
+            if stream and w_slots > 1:
+                # drain the speculative prefetch (issued iff the previous
+                # level ran live) so the semaphore stays balanced
+                if prev_live is None:
+                    w_dma(l).wait()
+                else:
+                    @pl.when(prev_live)
+                    def _():
+                        w_dma(l).wait()
+            return ctx.level(l, src, None, bias, idx, emit,
+                             n_out=progs[l].n_out, relu=relu, dots=False)
+
+        if l == 0 or not (end_skip and relu):
             # level 0 always computes; without ReLU the all-zero test is not
             # a sound skip predicate (negatives would survive).
             live_flags.append(None)
-            skips.append(jnp.int32(0))
-            t = run_level(t)
+            flags.append(jnp.int32(0))
+            m = run_level()
         else:
             # END cascade: post-ReLU tiles are >= 0, so max == 0 proves the
             # whole tile (masked halo included) is zero and the conv input is
-            # literally the zero tensor — @cond skips the K^2 MXU pass and
+            # literally the zero tensor — the cond skips the K^2 MXU pass and
             # emits the closed form instead, bit-exactly.
-            live = jnp.max(t) > 0.0
+            live = m > 0.0
             live_flags.append(live)
-            skips.append(jnp.where(live, 0, 1).astype(jnp.int32))
+            flags.append(jnp.where(live, 0, 1).astype(jnp.int32))
+            m = jax.lax.cond(live, run_level, skip_level)
+        if l < q - 1:
+            src = _Tile(ctx.mid[l])
 
-            def skip_level(t_in, b=b, prog=prog, l=l, prev_live=prev_live):
-                if stream and w_slots > 1:
-                    # drain the speculative prefetch (issued iff the previous
-                    # level ran live) so the semaphore stays balanced
-                    if prev_live is None:
-                        w_dma(l).wait()
-                    else:
-                        @pl.when(prev_live)
-                        def _():
-                            w_dma(l).wait()
-                return _const_level(idx, prog, b, relu, out_dtype)
-
-            t = jax.lax.cond(live, run_level, skip_level, t)
-
-    out_ref[0, :, :, :] = t
-    skip_ref[0, 0, 0, :] = jnp.stack(skips)
+    skip_ref[0, 0, 0] = _flag_vector(flags)
 
 
 def _ktiled_kernel(
     *refs,
-    progs: tuple[ConvLevelProg, ...],
-    tile0: int,
-    stride0: int,
-    alpha: int,
+    program: TileProgram,
+    names: tuple[str, ...],
     relu: bool,
     end_skip: bool,
     stream: bool,
     w_slots: int,
     x_slots: int,
     c_tiles: int,
-    cnts: tuple[int, ...],
-    out_dtype,
 ):
     """Channel-tiled variant over the (B, alpha, alpha, c_tiles) grid.
 
     The fourth grid axis ``k`` walks ``Cout / c_tiles`` output-channel tiles
     of the *last* level (the column-parallel axis of the paper's Fig. 5 WPU
-    array).  Levels ``0..Q-2`` run once per cell, at ``k == 0``, into a
-    persistent VMEM scratch (Pallas TPU scratch survives sequential grid
+    array).  Levels ``0..Q-2`` run once per cell, at ``k == 0``, into their
+    VMEM tile buffers (Pallas TPU scratch survives sequential grid
     iterations — the same property the revolving landing buffer relies on);
-    ``k > 0`` re-reads the scratch and computes only the last level's k-th
-    channel block, written through a channel-indexed out BlockSpec.
+    every ``k`` re-reads the last level's input tile and computes only its
+    k-th channel block into output block ``k``.  The last level's weights
+    arrive as ``(c_tiles, K, K, Cin, Cout/c_tiles)`` and its bias as
+    ``(c_tiles, 1, Cout/c_tiles)``.
 
-    Streamed weights split in two: mid levels fetch their whole tensor from
-    the flat HBM array through one *blocking* scratch slot inside their live
-    branch (the double-buffer budget belongs to the slices), while the last
-    level DMAs per-``k`` ``(K, K, Cin, Cout/c_tiles)`` slices from its
-    natural 4D HBM ref through ``w_slots`` revolving slots — slice 0 starts
-    at the top of the ``k == 0`` body so it fills behind the mid pyramid,
-    slice ``k+1`` starts before slice ``k``'s MXU pass.  Slice DMAs are
-    issued and drained *unconditionally* with respect to the END cascade
-    (only the MXU pass is gated), so the semaphores stay balanced with no
-    speculative drain paths; the END flag vector is written once, at
-    ``k == 0`` (the last level's liveness predicate is k-invariant: every k
-    reads the same mid tile)."""
+    Streamed weights split in two: mid levels fetch their whole tensor
+    through one *blocking* scratch slot inside their live branch (the
+    double-buffer budget belongs to the slices), while the last level DMAs
+    per-``k`` slices through ``w_slots`` revolving slots — slice 0 starts at
+    the top of the ``k == 0`` body so it fills behind the mid pyramid, slice
+    ``k+1`` starts before slice ``k``'s MXU pass.  Slice DMAs are issued and
+    drained *unconditionally* with respect to the END cascade (only the MXU
+    pass is gated), so the semaphores stay balanced with no speculative
+    drain paths.  The END flag vector is written once, at ``k == 0``; the
+    mid tile's max, the last level's liveness test, is kept in SMEM for
+    ``k > 0`` (it is k-invariant: every k reads the same mid tile)."""
+    progs = program.levels
     q = len(progs)
     last = progs[-1]
-    ct_out = last.n_out // c_tiles
-    if stream:
-        x_hbm, wflat_ref, wlast_ref = refs[0], refs[1], refs[2]
-        b_refs = refs[3 : 3 + q]
-        out_ref, skip_ref = refs[3 + q], refs[4 + q]
-        scratch = list(refs[5 + q :])
-    else:
-        x_hbm = refs[0]
-        w_refs = refs[1 : 1 + 2 * q : 2]
-        b_refs = refs[2 : 2 + 2 * q : 2]
-        out_ref, skip_ref = refs[1 + 2 * q], refs[2 + 2 * q]
-        scratch = list(refs[3 + 2 * q :])
-    x_scratch, x_sem = scratch.pop(0), scratch.pop(0)
-    mid_scratch = scratch.pop(0) if q > 1 else None
+    ct = last.n_out // c_tiles
+    x_hbm = refs[0]
+    w_refs = refs[1 : 1 + 2 * q : 2]
+    b_refs = refs[2 : 2 + 2 * q : 2]
+    out_ref, skip_ref = refs[1 + 2 * q], refs[2 + 2 * q]
+    scratch = refs[3 + 2 * q :]
+    sems = list(scratch[len(names) :])
+    live_max = sems.pop()  # SMEM (1,) f32
+    ctx = _Launch(program, scratch, names, x_hbm, sems.pop(0))
     if stream:
         if q > 1:
-            wm_scratch, wm_sem = scratch.pop(0), scratch.pop(0)
-        wk_scratch, wk_sem = scratch.pop(0), scratch.pop(0)
+            w_mid, wm_sem = ctx.bufs["w_mid"][0], sems.pop(0)
+            mid_full = weight_slot(progs[:-1])
+        w_slices, wk_sem = ctx.bufs["w_slices"][0], sems.pop(0)
+
+        def wm_dma(l):
+            """Blocking mid-level fetch into the single mid slot."""
+            return _slot_dma(w_refs[l], w_mid, 0, progs[l], mid_full, wm_sem)
+
+        def wk_dma(kk):
+            """Per-k slice fetch: the last level's kk-th Cout block."""
+            return pltpu.make_async_copy(
+                w_refs[q - 1].at[kk],
+                w_slices.at[kk % w_slots],
+                wk_sem.at[kk % w_slots],
+            )
 
     bi = pl.program_id(0)
     i = pl.program_id(1)
     j = pl.program_id(2)
     k = pl.program_id(3)
     idx = (i, j)
-
-    def x_dma(ii, jj, slot):
-        return pltpu.make_async_copy(
-            x_hbm.at[
-                bi, pl.ds(ii * stride0, tile0), pl.ds(jj * stride0, tile0), :
-            ],
-            x_scratch.at[slot],
-            x_sem.at[slot],
-        )
-
-    if stream:
-        offs = [sum(cnts[:l]) for l in range(q)]
-
-        def wm_dma(l):
-            """Blocking mid-level fetch: level l's whole slice of the flat
-            HBM weight array into the single mid scratch slot."""
-            return pltpu.make_async_copy(
-                wflat_ref.at[pl.ds(offs[l], cnts[l])],
-                wm_scratch.at[0, pl.ds(0, cnts[l])],
-                wm_sem,
-            )
-
-        def wk_dma(kk):
-            """Per-k slice fetch: the last level's kk-th Cout block, a
-            strided read of the natural 4D HBM ref."""
-            return pltpu.make_async_copy(
-                wlast_ref.at[:, :, :, pl.ds(kk * ct_out, ct_out)],
-                wk_scratch.at[kk % w_slots],
-                wk_sem.at[kk % w_slots],
-            )
-
-    if x_slots > 1:
-        cell = i * alpha + j
-        slot = jax.lax.rem(cell, x_slots)
-    else:
-        slot = 0
+    slot = jax.lax.rem(i * program.alpha + j, x_slots) if x_slots > 1 else 0
+    last_live = q == 1 or not (end_skip and relu)
 
     # ---- k == 0: input halo fetch (+ cross-cell prefetch chain) and the
-    # mid pyramid, persisted into mid_scratch for k > 0 ----
+    # mid pyramid, kept in the level tile buffers for k > 0 ----
     @pl.when(k == 0)
     def _():
-        if x_slots > 1:
-            @pl.when(cell == 0)
-            def _():  # warm-up: each batch element's first cell self-fetches
-                x_dma(i, j, slot).start()
-
-            ni = jnp.where(j == alpha - 1, i + 1, i)
-            nj = jnp.where(j == alpha - 1, 0, j + 1)
-
-            @pl.when(cell + 1 < alpha * alpha)
-            def _():  # successor prefetch, unconditional w.r.t. END
-                x_dma(ni, nj, 1 - slot).start()
-
-            if stream and w_slots > 1:
-                wk_dma(0).start()  # slice 0 fills behind the mid pyramid
-            x_dma(i, j, slot).wait()
-        else:
-            serial_dma = x_dma(i, j, 0)
-            serial_dma.start()
-            if stream and w_slots > 1:
-                wk_dma(0).start()  # slice 0 fills behind the mid pyramid
-            serial_dma.wait()
-        t = x_scratch[slot]
-
-        skips = []
+        warm = None
+        if stream and w_slots > 1:
+            def warm():  # slice 0 fills behind the mid pyramid
+                wk_dma(0).start()
+        ctx.fetch_halo(bi, i, j, x_slots, during=warm)
+        src = ctx.x_tile(j, slot)
+        flags = []
+        m = None
         for l, prog in enumerate(progs[:-1]):
-            b = b_refs[l][...]
+            bias = b_refs[l][...].astype(jnp.float32)
+            emit = ctx.mid_emit(l)
 
-            def run_level(t_in, l=l, prog=prog, b=b):
+            def run_level(l=l, src=src, bias=bias, emit=emit):
                 if stream:
                     wm_dma(l).start()
                     wm_dma(l).wait()
-                    w = wm_scratch[0, 0 : cnts[l]].reshape(
-                        prog.K, prog.K, prog.n_in, prog.n_out
-                    )
+                    w_at = _slot_reader(w_mid, 0, progs[l], mid_full)
                 else:
-                    w = w_refs[l][...]
-                tl = _conv_tile(t_in, w, b, prog.K, prog.S, prog.out_size)
-                if relu:
-                    tl = jnp.maximum(tl, 0.0)
-                # cast after the epilogue, exactly as the untiled kernel, so
-                # mid_scratch (and hence every k's input) is compute dtype
-                return _level_epilogue(tl, idx, prog).astype(out_dtype)
+                    w_at = _ref_reader(w_refs[l])
+                return ctx.level(l, src, w_at, bias, idx, emit,
+                                 n_out=progs[l].n_out, relu=relu, dots=True)
+
+            def skip_level(l=l, src=src, bias=bias, emit=emit):
+                return ctx.level(l, src, None, bias, idx, emit,
+                                 n_out=progs[l].n_out, relu=relu, dots=False)
 
             if l == 0 or not (end_skip and relu):
-                skips.append(jnp.int32(0))
-                t = run_level(t)
+                flags.append(jnp.int32(0))
+                m = run_level()
             else:
-                live = jnp.max(t) > 0.0
-                skips.append(jnp.where(live, 0, 1).astype(jnp.int32))
-                t = jax.lax.cond(
-                    live,
-                    run_level,
-                    lambda t_in, b=b, prog=prog: _const_level(
-                        idx, prog, b, relu, out_dtype
-                    ),
-                    t,
-                )
-        if q > 1:
-            mid_scratch[...] = t
-            skip_ref[0, 0, 0, 0 : q - 1] = jnp.stack(skips)
+                live = m > 0.0
+                flags.append(jnp.where(live, 0, 1).astype(jnp.int32))
+                m = jax.lax.cond(live, run_level, skip_level)
+            src = _Tile(ctx.mid[l])
+        if last_live:
+            flags.append(jnp.int32(0))
+        else:
+            live_max[0] = m
+            flags.append(jnp.where(m > 0.0, 0, 1).astype(jnp.int32))
+        skip_ref[0, 0, 0] = _flag_vector(flags)
 
     # ---- every k: the last level's k-th output-channel block ----
-    t_in = mid_scratch[...] if q > 1 else x_scratch[slot]
-    b_full = b_refs[q - 1][...]
-    bk = jax.lax.dynamic_slice_in_dim(b_full, k * ct_out, ct_out, 0)
-
+    src = _Tile(ctx.mid[q - 2]) if q > 1 else ctx.x_tile(j, slot)
+    bias = b_refs[q - 1][k].astype(jnp.float32)
     if stream:
         if w_slots > 1:
             @pl.when(k + 1 < c_tiles)
@@ -494,40 +634,26 @@ def _ktiled_kernel(
         else:
             wk_dma(k).start()  # blocking single-slot fallback
         wk_dma(k).wait()  # unconditional: doubles as the END drain
-        w_k = wk_scratch[k % w_slots]
+        w_at = _ref_reader(w_slices, (k % w_slots,))
     else:
-        w_k = jax.lax.dynamic_slice_in_dim(w_refs[q - 1][...], k * ct_out,
-                                           ct_out, 3)
+        w_at = _ref_reader(w_refs[q - 1], (k,))
+    emit = _out_emit(out_ref, ct)
 
-    def run_last(t_mid):
-        tl = _conv_tile(t_mid, w_k, bk, last.K, last.S, last.out_size)
-        if relu:
-            tl = jnp.maximum(tl, 0.0)
-        return _level_epilogue(tl, idx, last).astype(out_dtype)
+    def run_last(dots):
+        return ctx.level(q - 1, src, w_at, bias, idx, emit,
+                         n_out=ct, relu=relu, dots=dots)
 
-    if q == 1 or not (end_skip and relu):
-        last_flag = jnp.int32(0)
-        res = run_last(t_in)
+    if last_live:
+        run_last(True)
     else:
-        live = jnp.max(t_in) > 0.0  # k-invariant: same mid tile every k
-        last_flag = jnp.where(live, 0, 1).astype(jnp.int32)
-        res = jax.lax.cond(
-            live,
-            run_last,
-            lambda t_mid: _const_level(idx, last, bk, relu, out_dtype),
-            t_in,
+        jax.lax.cond(
+            live_max[0] > 0.0, lambda: run_last(True), lambda: run_last(False)
         )
-
-    out_ref[0, :, :, :] = res
-
-    @pl.when(k == 0)
-    def _():
-        skip_ref[0, 0, 0, q - 1 :] = last_flag.reshape(1)
 
 
 def fused_pyramid_pallas(
     x_padded: jnp.ndarray,  # (B, Hp, Wp, C) pre-padded input
-    weights: list[jnp.ndarray] | None,
+    weights: list[jnp.ndarray],
     biases: list[jnp.ndarray],
     *,
     program: TileProgram,
@@ -538,20 +664,20 @@ def fused_pyramid_pallas(
     w_slots: int = 2,
     x_slots: int = 2,
     c_tiles: int = 1,
-    weights_flat: jnp.ndarray | None = None,
+    vmem_limit_bytes: int | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Launch the variadic fused pyramid over the (B, alpha, alpha) grid.
 
-    The input stays in HBM; each grid cell DMAs its ``tile0 x tile0`` halo
-    tile into VMEM.  With ``x_slots=2`` (default) the landing buffer
-    revolves across grid cells: each cell prefetches its successor's halo
-    tile into the idle slot before running its own pyramid, hiding the input
-    stream behind compute after the per-image warm-up; ``x_slots=1`` is the
-    serial fetch-then-compute path (bit-identical output).  The grid is
-    launched with ``dimension_semantics=("parallel", "arbitrary",
-    "arbitrary")`` so the compiler may partition the batch axis across
-    TensorCores — the prefetch chain never crosses a batch boundary, so the
-    partitioning is safe.
+    The input stays in HBM; each grid cell DMAs its halo rows into VMEM.
+    With ``x_slots=2`` (default) the landing buffer revolves across grid
+    cells: each cell prefetches its successor's halo into the idle slot
+    before running its own pyramid, hiding the input stream behind compute
+    after the per-image warm-up; ``x_slots=1`` is the serial
+    fetch-then-compute path (bit-identical output).  The grid is launched
+    with ``dimension_semantics=("parallel", "arbitrary", "arbitrary")`` so
+    the compiler may partition the batch axis across TensorCores — the
+    prefetch chain never crosses a batch boundary, so the partitioning is
+    safe.
 
     Weights/biases are flat per-conv-level lists, index-aligned with
     ``program.levels``.  With ``stream_weights`` the weights stay in HBM
@@ -559,28 +685,27 @@ def fused_pyramid_pallas(
     ``w_slots`` shared VMEM scratch slots — double-buffered (prefetch
     overlapping compute) when ``w_slots == 2`` — the fallback when the
     fully-resident working set busts the VMEM budget (see
-    ``TileProgram.vmem_stream_bytes``).  ``weights_flat`` supplies the
-    pre-flattened concatenated weights (see
-    :func:`repro.kernels.fused_conv.ops.flatten_weights`) so plan-driven
-    callers don't re-concatenate per step; streamed callers holding only the
-    flat form may pass ``weights=None``.  ``interpret=None`` auto-resolves
-    to compiled on TPU, interpreted elsewhere.
+    ``TileProgram.vmem_stream_bytes``).
+    ``interpret=None`` auto-resolves to compiled on TPU, interpreted
+    elsewhere.  ``vmem_limit_bytes`` is the scoped-VMEM limit the compiled
+    kernel gets: the budget its plan was made under plus
+    :data:`~repro.core.program.MOSAIC_HEADROOM_BYTES`.
 
     With ``c_tiles > 1`` the launch runs the channel-tiled grid
     ``(B, alpha, alpha, c_tiles)``: a fourth sequential axis over
     ``Cout / c_tiles`` output-channel tiles of the last level, the mid
-    pyramid computed once per cell at ``k == 0`` into persistent VMEM
-    scratch, and (when streamed) per-``k`` weight-slice DMAs revolving
-    through ``w_slots`` scratch slots — the regime that restores DMA/MXU
-    overlap to ``alpha == 1`` launches (see ``_ktiled_kernel``).
-    ``c_tiles`` must divide the last level's ``Cout``; output and skip
-    shapes are unchanged, and the result is bit-identical to ``c_tiles=1``.
+    pyramid computed once per cell at ``k == 0`` and kept in VMEM, and (when
+    streamed) per-``k`` weight-slice DMAs revolving through ``w_slots``
+    scratch slots — the regime that restores DMA/MXU overlap to
+    ``alpha == 1`` launches (see ``_ktiled_kernel``).  ``c_tiles`` must
+    divide the last level's ``Cout``; output and skip shapes are unchanged,
+    and the result is bit-identical to ``c_tiles=1``.
 
     All operands must arrive in ``program.compute_dtype`` (DESIGN.md §11):
     halo tiles, weight slices, inter-level tiles, and the output all move at
-    that width — matching the byte model byte for byte — while every conv
-    accumulates in f32 (``preferred_element_type``) and casts once after the
-    level epilogue.  The int32 skip map is dtype-invariant.
+    that width, while every conv accumulates in f32
+    (``preferred_element_type``) and casts once after the level epilogue.
+    The int32 skip map is dtype-invariant.
 
     Returns ``(out, skip)`` with ``skip`` shaped ``(B, alpha, alpha, Q)`` —
     ``skip[..., l] == 1`` where level ``l``'s conv was short-circuited by the
@@ -600,35 +725,17 @@ def fused_pyramid_pallas(
     assert all(b.dtype == cdt for b in biases), (
         f"bias dtypes must match the program compute dtype {cdt}"
     )
-    assert weights is None or all(w.dtype == cdt for w in weights), (
+    assert all(w.dtype == cdt for w in weights), (
         f"weight dtypes must match the program compute dtype {cdt}"
     )
-    assert weights_flat is None or weights_flat.dtype == cdt, (
-        f"weights_flat dtype {weights_flat.dtype} != compute dtype {cdt}"
-    )
     assert x_slots in (1, 2), "x_slots: 1 (serial) or 2 (revolving pipeline)"
+    assert len(weights) == q, "one weight tensor per conv level"
     assert len(biases) == q, "one bias per conv level"
-    if not stream_weights and weights_flat is not None:
-        raise ValueError(
-            "weights_flat was passed with stream_weights=False: the resident"
-            " kernel reads per-level weight tensors and would silently"
-            " ignore it — pass stream_weights=True (or drop weights_flat)"
-        )
-    if weights is None:
-        assert stream_weights and weights_flat is not None, (
-            "weights=None requires stream_weights=True and weights_flat"
-        )
-    elif weights_flat is None:
-        assert len(weights) == q, "one weight tensor per conv level"
-    if weights_flat is not None:
-        assert weights_flat.size == sum(program.level_weight_counts()), (
-            "weights_flat does not match the program's level weight counts"
-        )
-    assert c_tiles >= 1 and program.levels[-1].n_out % c_tiles == 0, (
-        f"c_tiles {c_tiles} must divide the last level's Cout"
-        f" {program.levels[-1].n_out}"
+    last = program.levels[-1]
+    assert c_tiles >= 1 and last.n_out % c_tiles == 0, (
+        f"c_tiles {c_tiles} must divide the last level's Cout {last.n_out}"
     )
-    assert c_tiles == 1 or program.levels[-1].n_out // c_tiles >= 2, (
+    assert c_tiles == 1 or last.n_out // c_tiles >= 2, (
         "channel slices must keep >= 2 channels: the degenerate one-column"
         " dot reassociates the Cin contraction (see"
         " TileProgram.c_tile_options) and would break bitwise parity"
@@ -636,206 +743,99 @@ def fused_pyramid_pallas(
     assert x_padded.shape[1] == x_padded.shape[2] == program.padded_input, (
         "x_padded spatial dims must equal the program's padded input"
     )
+    weights = list(weights)
+    biases = [b.reshape(1, -1) for b in biases]
+    ct = last.n_out // c_tiles
     if c_tiles > 1:
-        return _launch_ktiled(
-            x_padded,
-            weights,
-            biases,
-            program=program,
-            relu=relu,
-            end_skip=end_skip,
-            interpret=interpret,
-            stream_weights=stream_weights,
-            w_slots=w_slots,
-            x_slots=x_slots,
-            c_tiles=c_tiles,
-            weights_flat=weights_flat,
+        weights[-1] = weights[-1].reshape(
+            last.K, last.K, last.n_in, c_tiles, ct
+        ).transpose(3, 0, 1, 2, 4)
+        biases[-1] = biases[-1].reshape(c_tiles, 1, ct)
+    weights = [
+        jnp.pad(w, [(0, 0)] * (w.ndim - 1) + [(0, padded_lanes(n) - n)])
+        for w, n in ((w, w.shape[-1]) for w in weights)
+    ]
+    extra_cols = program.input_cols() - program.padded_input
+    extra_lanes = program.input_lanes() - x_padded.shape[3]
+    if extra_cols or extra_lanes:
+        # the halo DMA moves whole (8, 128) tiles: align W and the lanes
+        x_padded = jnp.pad(
+            x_padded, ((0, 0), (0, 0), (0, extra_cols), (0, extra_lanes))
         )
-    c0 = program.levels[0].n_in
-    alpha, out_region = program.alpha, program.out_region
-    m_out = program.n_out
-    kernel = functools.partial(
-        _pyramid_kernel,
-        progs=program.levels,
-        tile0=program.tile0,
-        stride0=program.stride0,
-        alpha=alpha,
+
+    alpha, region = program.alpha, program.out_region
+    tiled = c_tiles > 1
+    grid = (B, alpha, alpha, c_tiles) if tiled else (B, alpha, alpha)
+    bufs = program.vmem_buffers(
+        x_slots, c_tiles, streamed=stream_weights, w_slots=w_slots
+    )
+    scratch = [(n, s, d) for n, s, d in bufs if n in _SCRATCH]
+    scratch_shapes = [pltpu.VMEM(s, jnp_dtype(d)) for _, s, d in scratch]
+    scratch_shapes.append(pltpu.SemaphoreType.DMA((x_slots,)))
+    if stream_weights:
+        if tiled and q > 1:
+            scratch_shapes.append(pltpu.SemaphoreType.DMA(()))
+        scratch_shapes.append(pltpu.SemaphoreType.DMA((w_slots,)))
+    if tiled:
+        scratch_shapes.append(pltpu.SMEM((1,), jnp.float32))
+
+    def whole(a):
+        return pl.BlockSpec(a.shape, lambda *g, n=a.ndim: (0,) * n)
+
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+    operands: list[jnp.ndarray] = [x_padded]
+    for w, b in zip(weights, biases):
+        in_specs += [
+            pl.BlockSpec(memory_space=pl.ANY) if stream_weights else whole(w),
+            whole(b),
+        ]
+        operands += [w, b]
+    common = dict(
+        program=program,
+        names=tuple(n for n, _, _ in scratch),
         relu=relu,
         end_skip=end_skip,
         stream=stream_weights,
         w_slots=w_slots,
         x_slots=x_slots,
-        cnts=program.level_weight_counts(),
-        out_dtype=cdt,
     )
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY)]
-    operands: list[jnp.ndarray] = [x_padded]
-    scratch_shapes: list = [
-        pltpu.VMEM((x_slots, program.tile0, program.tile0, c0), cdt),
-        pltpu.SemaphoreType.DMA((x_slots,)),
-    ]
-    if stream_weights:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
-        if weights_flat is None:
-            weights_flat = jnp.concatenate([w.reshape(-1) for w in weights])
-        operands.append(weights_flat)
-        for bias in biases:
-            in_specs.append(pl.BlockSpec(bias.shape, lambda b, i, j: (0,)))
-            operands.append(bias)
-        scratch_shapes += [
-            pltpu.VMEM((w_slots, max(program.level_weight_counts())), cdt),
-            pltpu.SemaphoreType.DMA((w_slots,)),
-        ]
+    if tiled:
+        kernel = functools.partial(_ktiled_kernel, c_tiles=c_tiles, **common)
+        out_index = lambda b, i, j, k: (b, i, j, k, 0, 0, 0)  # noqa: E731
     else:
-        for w, bias in zip(weights, biases):
-            in_specs.append(pl.BlockSpec(w.shape, lambda b, i, j: (0,) * 4))
-            in_specs.append(pl.BlockSpec(bias.shape, lambda b, i, j: (0,)))
-            operands += [w, bias]
+        kernel = functools.partial(_pyramid_kernel, **common)
+        out_index = lambda b, i, j: (b, i, j, 0, 0, 0, 0)  # noqa: E731
     out, skip = pl.pallas_call(
         kernel,
-        grid=(B, alpha, alpha),
+        grid=grid,
         in_specs=in_specs,
         out_specs=[
+            # one whole (region, region, ct) block per cell and channel tile:
+            # its last two dims are the array's, as Mosaic's (8, 128) block
+            # rule requires for any region and channel width
+            pl.BlockSpec((1, 1, 1, 1, region, region, ct), out_index),
             pl.BlockSpec(
-                (1, out_region, out_region, m_out), lambda b, i, j: (b, i, j, 0)
+                (1, 1, 1, 1, q), lambda b, i, j, *k: (b, i, j, 0, 0)
             ),
-            pl.BlockSpec((1, 1, 1, q), lambda b, i, j: (b, i, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(
-                (B, alpha * out_region, alpha * out_region, m_out), cdt
+                (B, alpha, alpha, c_tiles, region, region, ct), cdt
             ),
-            jax.ShapeDtypeStruct((B, alpha, alpha, q), jnp.int32),
+            jax.ShapeDtypeStruct((B, alpha, alpha, 1, q), jnp.int32),
         ],
         scratch_shapes=scratch_shapes,
         # the batch axis is embarrassingly parallel: every cross-cell chain
         # (input prefetch) is confined to one batch element, so the compiler
         # may partition dim 0 across cores; the movement grid dims stay
-        # sequential (the revolving landing buffer is carried cell to cell)
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")
+        # sequential (the revolving landing buffer is carried cell to cell,
+        # the mid tiles k to k)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) + ("arbitrary",) * (len(grid) - 1),
+            vmem_limit_bytes=vmem_limit_bytes,
         ),
         interpret=resolve_interpret(interpret),
     )(*operands)
-    return out, skip
-
-
-def _launch_ktiled(
-    x_padded: jnp.ndarray,
-    weights: list[jnp.ndarray] | None,
-    biases: list[jnp.ndarray],
-    *,
-    program: TileProgram,
-    relu: bool,
-    end_skip: bool,
-    interpret: bool | None,
-    stream_weights: bool,
-    w_slots: int,
-    x_slots: int,
-    c_tiles: int,
-    weights_flat: jnp.ndarray | None,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Launch the channel-tiled ``(B, alpha, alpha, c_tiles)`` grid.
-
-    Streamed launches keep the flat concatenated weight array for the mid
-    levels (its last-level tail is simply never read) and additionally need
-    the last level's tensor in its natural 4D shape for the strided per-k
-    slice DMA — taken from ``weights`` when available, else sliced and
-    reshaped out of ``weights_flat`` (a one-off device-side copy per call,
-    tiny next to the per-cell streamed traffic)."""
-    B = x_padded.shape[0]
-    q = program.q_convs
-    cnts = program.level_weight_counts()
-    last = program.levels[-1]
-    ct_out = last.n_out // c_tiles
-    c0 = program.levels[0].n_in
-    alpha, out_region = program.alpha, program.out_region
-    m_out = program.n_out
-    cdt = jnp_dtype(program.compute_dtype)
-    kernel = functools.partial(
-        _ktiled_kernel,
-        progs=program.levels,
-        tile0=program.tile0,
-        stride0=program.stride0,
-        alpha=alpha,
-        relu=relu,
-        end_skip=end_skip,
-        stream=stream_weights,
-        w_slots=w_slots,
-        x_slots=x_slots,
-        c_tiles=c_tiles,
-        cnts=cnts,
-        out_dtype=cdt,
-    )
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY)]
-    operands: list[jnp.ndarray] = [x_padded]
-    scratch_shapes: list = [
-        pltpu.VMEM((x_slots, program.tile0, program.tile0, c0), cdt),
-        pltpu.SemaphoreType.DMA((x_slots,)),
-    ]
-    if q > 1:
-        scratch_shapes.append(
-            pltpu.VMEM((last.in_size, last.in_size, last.n_in), cdt)
-        )
-    if stream_weights:
-        if weights_flat is None:
-            weights_flat = jnp.concatenate([w.reshape(-1) for w in weights])
-        if weights is not None:
-            w_last = weights[-1]
-        else:
-            w_last = jax.lax.dynamic_slice_in_dim(
-                weights_flat, sum(cnts[:-1]), cnts[-1], 0
-            ).reshape(last.K, last.K, last.n_in, last.n_out)
-        in_specs += [
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ]
-        operands += [weights_flat, w_last]
-        for bias in biases:
-            in_specs.append(pl.BlockSpec(bias.shape, lambda b, i, j, k: (0,)))
-            operands.append(bias)
-        if q > 1:
-            scratch_shapes += [
-                pltpu.VMEM((1, max(cnts[:-1])), cdt),
-                pltpu.SemaphoreType.DMA(()),
-            ]
-        scratch_shapes += [
-            pltpu.VMEM((w_slots, last.K, last.K, last.n_in, ct_out), cdt),
-            pltpu.SemaphoreType.DMA((w_slots,)),
-        ]
-    else:
-        for w, bias in zip(weights, biases):
-            in_specs.append(
-                pl.BlockSpec(w.shape, lambda b, i, j, k: (0,) * 4)
-            )
-            in_specs.append(pl.BlockSpec(bias.shape, lambda b, i, j, k: (0,)))
-            operands += [w, bias]
-    out, skip = pl.pallas_call(
-        kernel,
-        grid=(B, alpha, alpha, c_tiles),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec(
-                (1, out_region, out_region, ct_out),
-                lambda b, i, j, k: (b, i, j, k),
-            ),
-            pl.BlockSpec((1, 1, 1, q), lambda b, i, j, k: (b, i, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(
-                (B, alpha * out_region, alpha * out_region, m_out), cdt
-            ),
-            jax.ShapeDtypeStruct((B, alpha, alpha, q), jnp.int32),
-        ],
-        scratch_shapes=scratch_shapes,
-        # batch stays embarrassingly parallel; the movement grid AND the
-        # channel axis are sequential — mid_scratch is carried k to k, and
-        # the revolving landing/slice buffers are carried cell to cell
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=(
-                "parallel", "arbitrary", "arbitrary", "arbitrary",
-            )
-        ),
-        interpret=resolve_interpret(interpret),
-    )(*operands)
-    return out, skip
+    side = alpha * region
+    out = out.transpose(0, 1, 4, 2, 5, 3, 6).reshape(B, side, side, last.n_out)
+    return out, skip.reshape(B, alpha, alpha, q)

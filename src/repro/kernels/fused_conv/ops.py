@@ -27,22 +27,13 @@ import jax.numpy as jnp
 from repro.core.dtypes import canonical_dtype, jnp_dtype
 from repro.core.fusion import FusionSpec
 from repro.core.program import (
+    MOSAIC_HEADROOM_BYTES,
     VMEM_BUDGET_BYTES,
     compile_program,
     pick_out_region,
     plan_launch,
 )
 from .fused_conv import fused_pyramid_pallas
-
-
-def flatten_weights(weights: list, dtype="float32") -> jnp.ndarray:
-    """Concatenate per-level weight tensors into the flat compute-dtype
-    array the streamed-weight kernel DMAs from.  Plan-driven callers (the
-    network runner) call this once per model instead of once per launch;
-    ``dtype`` must match the launch's compute dtype so each streamed byte is
-    exactly as wide as the byte model charges."""
-    dt = jnp_dtype(dtype)
-    return jnp.concatenate([jnp.asarray(w, dt).reshape(-1) for w in weights])
 
 
 @partial(
@@ -54,7 +45,7 @@ def flatten_weights(weights: list, dtype="float32") -> jnp.ndarray:
 )
 def fused_pyramid(
     x: jnp.ndarray,
-    weights: list | None,
+    weights: list,
     biases: list,
     *,
     spec: FusionSpec,
@@ -67,7 +58,6 @@ def fused_pyramid(
     end_skip: bool = True,
     interpret: bool | None = None,
     vmem_budget: int = VMEM_BUDGET_BYTES,
-    weights_flat: jnp.ndarray | None = None,
     compute_dtype: str = "float32",
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Fused Q-conv pyramid forward as a single kernel launch.
@@ -84,11 +74,7 @@ def fused_pyramid(
     (double-buffered weight streaming preferred over channel-tiled double
     buffering over the blocking single slot; the revolving cross-cell input
     prefetch preferred over the serial fetch whenever the grid has a
-    successor cell and the extra landing slot fits).  ``weights_flat``
-    optionally supplies the pre-flattened streamed weights
-    (:func:`flatten_weights`) to keep the concatenation out of the per-call
-    path — streamed callers holding only the flat form may pass
-    ``weights=None`` (its dtype must match ``compute_dtype``).
+    successor cell and the extra landing slot fits).
     ``compute_dtype`` (name string or jnp dtype; static) selects the value
     width of every tile/weight moved by the launch — activations and weights
     are cast on entry, accumulation stays f32 inside the kernel (DESIGN.md
@@ -174,7 +160,7 @@ def fused_pyramid(
     )
     return fused_pyramid_pallas(
         xp,
-        None if weights is None else [w.astype(cdt) for w in weights],
+        [w.astype(cdt) for w in weights],
         [b.astype(cdt) for b in biases],
         program=prog,
         relu=relu,
@@ -184,7 +170,7 @@ def fused_pyramid(
         w_slots=w_slots,
         x_slots=x_slots,
         c_tiles=c_tiles,
-        weights_flat=weights_flat,
+        vmem_limit_bytes=vmem_budget + MOSAIC_HEADROOM_BYTES,
     )
 
 

@@ -35,7 +35,7 @@ import numpy as np
 
 from repro.core.cycle_model import DEFAULT_PARAMS
 from repro.core.dtypes import canonical_dtype, jnp_dtype
-from repro.kernels.fused_conv.ops import flatten_weights, fused_pyramid
+from repro.kernels.fused_conv.ops import fused_pyramid
 from repro.obs.trace import LaunchSpan, get_tracer
 from repro.robust.guard import get_guard
 
@@ -43,9 +43,6 @@ from .graph import Graph, Node, infer_shapes
 from .partition import PartitionPlan, auto_partition
 
 Params = dict[str, tuple[jnp.ndarray, jnp.ndarray]]
-
-# key prefix of pre-flattened streamed-weight arrays in a params dict
-_FLAT = "_flat/"
 
 # Documented end-to-end bf16 logit tolerance vs the f32 reference.  bf16
 # keeps f32's exponent range but only 8 mantissa bits: each layer's
@@ -90,11 +87,13 @@ def init_network_params(graph: Graph, key: jax.Array, scale: float = 1.0) -> Par
 def _conv_node(x, n: Node, w, b):
     # f32 accumulation at any operand dtype, cast back to the network's
     # compute dtype — the plain-op mirror of the kernel's §11 contract
-    # (identity for f32 inputs, so the reference oracle is unchanged)
+    # (identity for f32 inputs, so the reference oracle is unchanged); f32
+    # operands take full-precision MXU passes on a TPU, as in the kernel
     out = jax.lax.conv_general_dilated(
         x, w, window_strides=(n.S, n.S),
         padding=[(n.pad, n.pad), (n.pad, n.pad)],
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     ) + b
     out = jax.nn.relu(out) if n.relu else out
@@ -128,7 +127,8 @@ def _head_op(values, n: Node, params: Params, graph: Graph | None = None):
         w, b = params[n.name]
         # operands at the network dtype, accumulation in f32 (§11)
         out = jnp.dot(
-            x, w.astype(x.dtype), preferred_element_type=jnp.float32
+            x, w.astype(x.dtype), precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
         ) + b
         out = jax.nn.relu(out) if n.relu else out
         return out.astype(x.dtype)
@@ -146,50 +146,34 @@ def _head_op(values, n: Node, params: Params, graph: Graph | None = None):
 def reference_network(x: jnp.ndarray, graph: Graph, params: Params) -> jnp.ndarray:
     """Monolithic node-by-node forward: full intermediate maps, no fusion.
     Ground truth for ``run_network`` and the baseline dataflow whose off-chip
-    traffic the partitioner minimizes."""
+    traffic the partitioner minimizes.  Runs in f32 at ``highest`` matmul
+    precision, so on a TPU it is an f32 oracle, not a bf16-pass one."""
     values = {graph.nodes[0].name: x.astype(jnp.float32)}
-    for n in graph.nodes[1:]:
-        if n.op == "conv":
-            w, b = params[n.name]
-            values[n.name] = _conv_node(values[n.inputs[0]], n, w, b)
-        elif n.op == "pool":
-            values[n.name] = _pool_node(values[n.inputs[0]], n)
-        else:
-            values[n.name] = _head_op(values, n, params, graph)
+    with jax.default_matmul_precision("highest"):
+        for n in graph.nodes[1:]:
+            if n.op == "conv":
+                w, b = params[n.name]
+                values[n.name] = _conv_node(values[n.inputs[0]], n, w, b)
+            elif n.op == "pool":
+                values[n.name] = _pool_node(values[n.inputs[0]], n)
+            else:
+                values[n.name] = _head_op(values, n, params, graph)
     return values[graph.output.name]
 
 
 def prepare_network_params(
     plan: PartitionPlan, params: Params, dtype: str | None = None
 ) -> Params:
-    """Cast params to the plan's compute dtype and pre-flatten streamed
-    weights, once per model.
+    """Cast params to the plan's compute dtype, once per model.
 
     ``dtype`` (``None`` = ``plan.compute_dtype``) is the value width the
     launches move: every conv/dense weight and bias is cast once here
-    instead of per ``run_network`` call inside the jit graph, and each
-    streamed pyramid gets one ``"_flat/<pyramid>"`` concatenated weight
-    array at that width (consumed by :func:`run_network`).  Master params
-    stay f32 in the caller's dict — this returns a new dict.  Stale
-    ``"_flat/"`` entries from a previous preparation are dropped and
-    rebuilt, so re-preparing at another dtype is safe.
+    instead of per ``run_network`` call inside the jit graph.  Master params
+    stay f32 in the caller's dict — this returns a new dict, so
+    re-preparing at another dtype is safe.
     """
-    cdt = canonical_dtype(plan.compute_dtype if dtype is None else dtype)
-    jdt = jnp_dtype(cdt)
-    out: Params = {
-        k: (w.astype(jdt), b.astype(jdt))
-        for k, (w, b) in params.items()
-        if not k.startswith(_FLAT)
-    }
-    graph = plan.graph
-    for pyr in plan.pyramids:
-        if not pyr.launch.streamed:
-            continue
-        conv_names = [m for m in pyr.node_names if graph.node(m).op == "conv"]
-        out[_FLAT + pyr.name] = flatten_weights(
-            [out[m][0] for m in conv_names], cdt
-        )
-    return out
+    jdt = jnp_dtype(plan.compute_dtype if dtype is None else dtype)
+    return {k: (w.astype(jdt), b.astype(jdt)) for k, (w, b) in params.items()}
 
 
 def _forward(
@@ -222,11 +206,9 @@ def _forward(
                 continue  # interior pyramid node: computed with its launch
             conv_names = [m for m in pyr.node_names
                           if graph.node(m).op == "conv"]
-            flat = params.get(_FLAT + pyr.name)
             x_in = values[n.inputs[0]]
 
-            def call(pyr=pyr, x_in=x_in, conv_names=conv_names, flat=flat,
-                     **overrides):
+            def call(pyr=pyr, x_in=x_in, conv_names=conv_names, **overrides):
                 kwargs = dict(
                     spec=pyr.spec,
                     out_region=pyr.launch.out_region,
@@ -240,7 +222,6 @@ def _forward(
                     end_skip=end_skip,
                     interpret=interpret,
                     vmem_budget=plan.vmem_budget,
-                    weights_flat=flat,
                     compute_dtype=cdt,
                 )
                 # wrapper retries may override launch knobs, e.g.
@@ -248,11 +229,7 @@ def _forward(
                 kwargs.update(overrides)
                 return fused_pyramid(
                     x_in,
-                    # streamed launches with pre-flattened weights don't
-                    # need the per-level tensors threaded through the jit
-                    # graph
-                    None if kwargs["weights_flat"] is not None
-                    else [params[m][0] for m in conv_names],
+                    [params[m][0] for m in conv_names],
                     [params[m][1] for m in conv_names],
                     **kwargs,
                 )
@@ -410,8 +387,8 @@ def run_network(
     (``auto_partition(..., compute_dtype=...)``) or narrowing.
 
     ``interpret=None`` resolves per backend (compiled on TPU).  Params may
-    come through :func:`prepare_network_params` so streamed launches reuse
-    the pre-flattened weight arrays (which must match the run dtype).
+    come through :func:`prepare_network_params` so the cast to the run
+    dtype happens once per model.
     Returns ``(logits, skips)``: ``skips[pyramid.name]`` is that launch's
     ``(B, alpha, alpha, Q)`` int32 END-cascade flag map (level 0 of each
     pyramid never skips).  Aggregate with :func:`skip_fractions`.

@@ -1132,6 +1132,9 @@ def main(argv=None) -> int:
                     help="write the summary (with per-wave cache deltas)"
                     " as JSON")
     args = ap.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     kwargs = {"input_size": args.input} if args.input else {}
     graph = MODELS[args.model](**kwargs)

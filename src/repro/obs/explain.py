@@ -24,7 +24,7 @@ Examples::
     PYTHONPATH=src python -m repro.obs.explain --model resnet18 \\
         --dtype bfloat16 --run --trace t.json
     PYTHONPATH=src python -m repro.obs.explain --model lenet \\
-        --guard --squeeze 0.002
+        --guard --squeeze 0.08
 
 Big models default to the same reduced interpret-friendly input sizes as
 ``examples/fused_cnn_inference.py`` when run; the *plan table* is always

@@ -45,8 +45,6 @@ import numpy as np
 from .errors import BudgetError, NumericError
 from .guard import sentinel_stats, sentinel_trips
 
-_FLAT = "_flat/"
-
 
 @dataclass(frozen=True)
 class FallbackEvent:
@@ -134,8 +132,7 @@ def _reference_walk(x_in, pyr, graph, params, jdt, magnitude_limit=None):
 def _run_subplan(x_in, subs, params, graph, cdt, *, end_skip, interpret,
                  vmem_budget):
     """Execute a replanned pyramid chain: each sub-pyramid as its own fused
-    launch, per-level weight tensors (the pre-flattened arrays belong to the
-    original plan's pyramids, not these)."""
+    launch."""
     from repro.kernels.fused_conv.ops import fused_pyramid
 
     y = x_in
@@ -156,7 +153,6 @@ def _run_subplan(x_in, subs, params, graph, cdt, *, end_skip, interpret,
             end_skip=end_skip,
             interpret=interpret,
             vmem_budget=vmem_budget,
-            weights_flat=None,
             compute_dtype=cdt,
         )
         sub_skips[sp.name] = sk
@@ -379,10 +375,7 @@ def run_network_guarded(
         if trip is not None:
             from repro.net.runner import reference_network
 
-            logits = reference_network(
-                x.astype(jdt), graph,
-                {k: v for k, v in params.items() if not k.startswith(_FLAT)},
-            )
+            logits = reference_network(x.astype(jdt), graph, params)
             record(FallbackEvent(
                 launch="<head>", rung="reference_full",
                 reason=f"logits sentinel tripped: {trip}",

@@ -75,11 +75,6 @@ def corrupt_params(
     """A new params dict with ``node``'s weight tensor corrupted at seeded
     positions (``max(1, fraction * size)`` of them) — NaN or Inf per
     ``kind``.  The input dict is not mutated; every other entry is shared.
-
-    Flattened streamed-weight entries (``"_flat/..."``) are rebuilt by
-    :func:`repro.net.runner.prepare_network_params`, not here — corrupt the
-    master params and re-prepare, or corrupt the prepared dict directly to
-    model staging-copy corruption.
     """
     import jax.numpy as jnp
 

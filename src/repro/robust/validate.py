@@ -10,10 +10,8 @@ or launch:
 * **structure** — input rank/spatial/channel agreement with the graph, the
   plan covering real conv/pool nodes of its own graph (channel chaining
   inside each pyramid was already proven at ``FusionSpec`` construction);
-* **params** — every conv/dense node has a ``(w, b)`` pair of the right
-  shape; pre-flattened streamed-weight arrays (``"_flat/..."``) match their
-  pyramid's level weight counts and the run dtype, and are absent for
-  non-streamed pyramids (the resident kernel would reject them);
+* **params** — every conv/dense node has a floating ``(w, b)`` pair of
+  the right shape;
 * **dtype** — the requested compute dtype is known *and* executable
   (``EXEC_DTYPES``: int8 is modeled-only and must fail here, not as a
   kernel ``NotImplementedError``);
@@ -36,12 +34,9 @@ import warnings
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.dtypes import EXEC_DTYPES, canonical_dtype, jnp_dtype
+from repro.core.dtypes import EXEC_DTYPES, canonical_dtype
 
 from .errors import BudgetError, NumericError, PreflightError
-
-# key prefix of pre-flattened streamed-weight arrays (mirrors net/runner)
-_FLAT = "_flat/"
 
 
 def _resolve_dtype(plan, dtype) -> str:
@@ -170,12 +165,11 @@ def _check_plan_structure(plan) -> None:
                 )
 
 
-def _check_params(params, plan, cdt: str) -> None:
+def _check_params(params, plan) -> None:
     from repro.net.graph import infer_shapes
 
     graph = plan.graph
     shapes = infer_shapes(graph)
-    jdt = jnp_dtype(cdt)
     for n in graph.nodes:
         if n.op not in ("conv", "dense"):
             continue
@@ -207,55 +201,14 @@ def _check_params(params, plan, cdt: str) -> None:
                 " quantized path",
                 node=n.name,
             )
-    covered_flats = set()
-    for pyr in plan.pyramids:
-        key = _FLAT + pyr.name
-        covered_flats.add(key)
-        flat = params.get(key)
-        if flat is None:
-            continue  # runner falls back to per-level tensors
-        if not pyr.launch.streamed:
-            raise PreflightError(
-                f"pre-flattened weights {key!r} present but pyramid"
-                f" {pyr.name} is not streamed — the resident kernel reads"
-                " per-level tensors; re-prepare with the current plan",
-                launch=pyr.name,
-            )
-        if flat.dtype != jdt:
-            raise PreflightError(
-                f"pre-flattened weights {key!r} are {flat.dtype} but the run"
-                f" computes {cdt}; params were prepared at a different dtype"
-                " — re-run prepare_network_params at the run dtype",
-                launch=pyr.name, dtype=cdt,
-            )
-        want = sum(pyr.launch.program.level_weight_counts())
-        if flat.size != want:
-            raise PreflightError(
-                f"pre-flattened weights {key!r} hold {flat.size} values,"
-                f" launch program expects {want}; params were prepared for a"
-                " different plan",
-                launch=pyr.name,
-            )
-    stale = [
-        k for k in params
-        if k.startswith(_FLAT) and k not in covered_flats
-    ]
-    if stale:
-        raise PreflightError(
-            f"params carry pre-flattened weights for pyramids not in this"
-            f" plan: {sorted(stale)}; re-prepare with the current plan",
-            launch=stale[0][len(_FLAT):],
-        )
 
 
 def nonfinite_param_nodes(params) -> list[str]:
-    """Names of param entries (nodes and ``"_flat/..."`` arrays) carrying
-    any non-finite value — the preflight numeric check, exposed so the
-    healing rung can name what it reloads."""
+    """Names of param entries carrying any non-finite value — the preflight
+    numeric check, exposed so the healing rung can name what it reloads."""
     bad = []
     for key, val in params.items():
-        arrs = (val,) if key.startswith(_FLAT) else val
-        for arr in arrs:
+        for arr in val:
             if not bool(jnp.all(jnp.isfinite(arr.astype(jnp.float32)))):
                 bad.append(key)
                 break
@@ -298,7 +251,7 @@ def preflight(
     cdt = _resolve_dtype(plan, dtype)
     _check_input(x, plan.graph)
     _check_plan_structure(plan)
-    _check_params(params, plan, cdt)
+    _check_params(params, plan)
     bad = nonfinite_param_nodes(params)
     if bad:
         raise NumericError(

@@ -3,8 +3,8 @@
 * bitwise parity — the channel-tiled ``(B, alpha, alpha, c_tiles)`` grid
   must be bit-identical to the untiled ``c_tiles=1`` path across Q=1/3/4,
   resident and streamed weights, both ``w_slots`` regimes, both ``x_slots``
-  regimes, the END cascade (all-dead and mixed live/dead tiles), ``alpha ==
-  1`` grids, and the ``weights=None`` pre-flattened streamed API;
+  regimes, the END cascade (all-dead and mixed live/dead tiles), and
+  ``alpha == 1`` grids;
 * the planner ladder — ResNet-18 b7 (whose two 9.4 MB weight levels bust
   double-buffered streaming untiled) now lands on the channel-tiled
   ``streamed w_slots=2`` rung with ``pipeline_cycles_saved > 0`` at ``alpha
@@ -18,8 +18,7 @@
 * the hypothesis regime sweep — random Q in 1..4 pyramids, random
   ``(x_slots, w_slots, c_tiles)``, bitwise equal to the resident untiled
   serial path;
-* the ``weights_flat`` + ``stream_weights=False`` ValueError (previously
-  silently ignored).
+* the launch's argument checks (tile count, landing slots, bias count).
 """
 
 import dataclasses
@@ -44,11 +43,13 @@ from repro.core.cycle_model import (
 from repro.core.executor import init_pyramid_params
 from repro.core.fusion import FusedLevel, FusionSpec
 from repro.core.program import (
+    LANES,
     VMEM_BUDGET_BYTES,
+    channel_blocks,
     compile_program,
     plan_launch,
 )
-from repro.kernels.fused_conv.ops import flatten_weights, fused_pyramid
+from repro.kernels.fused_conv.ops import fused_pyramid
 from repro.net.graph import lenet5
 from repro.net.partition import auto_partition
 from repro.net.runner import (
@@ -196,21 +197,10 @@ class TestChannelTiledParity:
         frac = float(np.asarray(s0)[..., 1].mean())
         assert 0.0 < frac < 1.0, "test needs mixed live/dead tiles"
 
-    def test_weights_none_preflattened(self):
-        """Streamed channel-tiled launches recover the last level's 4D
-        tensor from the flat array when only weights_flat is supplied."""
-        spec = Q3_CHAIN
-        p = init_pyramid_params(spec, KEY)
-        x = _inputs(spec)
-        y0, s0 = fused_pyramid(
-            x, p.weights, p.biases, spec=spec, out_region=4, x_slots=1
-        )
-        y1, s1 = fused_pyramid(
-            x, None, p.biases, spec=spec, out_region=4, streamed=True,
-            w_slots=2, c_tiles=2, weights_flat=flatten_weights(p.weights),
-        )
-        np.testing.assert_array_equal(np.asarray(y1), np.asarray(y0))
-        np.testing.assert_array_equal(np.asarray(s1), np.asarray(s0))
+    def test_c_tiles_must_divide_cout(self):
+        with pytest.raises(AssertionError, match="must divide"):
+            _run(Q3_CHAIN, _inputs(Q3_CHAIN), 4, streamed=True, w_slots=2,
+                 c_tiles=3)
 
     def test_run_network_with_channel_tiled_plan(self):
         """The runner threads c_tiles from the plan: a LeNet plan pinned to
@@ -352,22 +342,20 @@ class TestPlannerLadder:
         assert prog.resolve_stream_regime(VMEM_BUDGET_BYTES, 1, 1, 8) == (1, 8)
 
     def test_vmem_model_counts_mid_scratch(self):
-        """The channel-tiled kernel carries a persistent mid-pyramid scratch
-        for Q > 1 (live alongside the transient mid tile at k == 0); the
-        byte models must charge it so a near-budget plan cannot overflow
-        real VMEM."""
+        """The channel-tiled kernel re-reads the last level's input (mid)
+        tile at every k for Q > 1; the byte models must charge it so a
+        near-budget plan cannot overflow real VMEM."""
         prog = plan_launch(resnet18_fusions()[7]).program
         last = prog.levels[-1]
-        carry = 4 * last.in_size ** 2 * last.n_in
-        untiled_tiles = prog.vmem_bytes(1) - 4 * prog.weight_floats()
-        tiled_tiles = prog.vmem_bytes(1, 2) - 4 * prog.weight_floats()
-        shrunk_out = 4 * (
-            last.out_size ** 2 * (last.n_out - last.n_out // 2)
-        )
-        assert tiled_tiles == untiled_tiles - shrunk_out + carry
+        cb, cl = channel_blocks(last.n_in)
+        for ct in (1, 2):
+            mids = [s for n, s, _ in prog.vmem_buffers(1, ct) if n == "mid"]
+            assert mids == [(cb, last.in_size, last.in_size, cl)]
+        # tiling shrinks only the last level's working tile and output block
+        assert prog.vmem_bytes(1, 2) < prog.vmem_bytes(1)
         # Q=1 chains have no mid pyramid to carry
         prog1 = compile_program(Q1_CHAIN, 3)
-        assert prog1.vmem_bytes(1, 2) < prog1.vmem_bytes(1)
+        assert not [n for n, _, _ in prog1.vmem_buffers(1, 2) if n == "mid"]
 
     def test_untiled_double_buffer_still_preferred_when_it_fits(self):
         """The channel-tiled rung sits below plain w_slots=2: chains whose
@@ -457,16 +445,21 @@ class TestChannelTiledCostModel:
         assert lp.hbm_bytes(4) == untiled.hbm_bytes(4)
 
     def test_vmem_slice_accounting(self):
-        """Among channel-tiled options vmem_stream_bytes shrinks
-        monotonically in c_tiles (smaller slice slots + smaller last-level
-        working tile; the mid-scratch carry is c_tiles-invariant), and
+        """Among channel-tiled options with vreg-wide slices
+        vmem_stream_bytes shrinks monotonically in c_tiles (smaller slice
+        slots + smaller last-level working tile; the mid tile is
+        c_tiles-invariant), and
         slice_bytes is the per-k DMA granule.  (No monotonicity across the
         1 -> 2 boundary: tiling swaps the shared revolving slots for a
         blocking mid slot + sliced slots + the carry, which can exceed the
         untiled set when the mid level rivals the last — the ladder relies
         on feasibility only.)"""
         prog = plan_launch(resnet18_fusions()[7]).program
-        opts = prog.c_tile_options()
+        # slices narrower than one 128-lane vreg occupy a whole one
+        opts = [
+            ct for ct in prog.c_tile_options()
+            if prog.levels[-1].n_out // ct >= LANES
+        ]
         sizes = [prog.vmem_stream_bytes(2, 1, ct) for ct in opts]
         assert all(a >= b for a, b in zip(sizes, sizes[1:]))
         lp = plan_launch(resnet18_fusions()[7])
@@ -488,28 +481,16 @@ class TestChannelTiledCostModel:
         assert "streamed_w2_c" in plan.summary()
 
 
-class TestWeightsFlatValueError:
-    def test_resident_launch_rejects_weights_flat(self):
-        """stream_weights=False used to silently drop weights_flat; it now
-        raises so plan/caller disagreements surface immediately."""
-        spec = LENET5_FUSION
-        p = init_pyramid_params(spec, KEY)
-        x = _inputs(spec)
-        with pytest.raises(ValueError, match="stream_weights=False"):
-            fused_pyramid(
-                x, p.weights, p.biases, spec=spec, out_region=1,
-                streamed=False, weights_flat=flatten_weights(p.weights),
-            )
+class TestLaunchArgChecks:
+    def test_x_slots_must_be_one_or_two(self):
+        with pytest.raises(AssertionError, match="x_slots"):
+            _run(LENET5_FUSION, _inputs(LENET5_FUSION), 1, x_slots=3)
 
-    def test_streamed_launch_still_accepts_weights_flat(self):
+    def test_one_bias_per_level(self):
         spec = LENET5_FUSION
         p = init_pyramid_params(spec, KEY)
-        x = _inputs(spec)
-        y0, _ = fused_pyramid(
-            x, p.weights, p.biases, spec=spec, out_region=1, streamed=True
-        )
-        y1, _ = fused_pyramid(
-            x, p.weights, p.biases, spec=spec, out_region=1, streamed=True,
-            weights_flat=flatten_weights(p.weights),
-        )
-        np.testing.assert_array_equal(np.asarray(y1), np.asarray(y0))
+        with pytest.raises(AssertionError, match="one bias per conv level"):
+            fused_pyramid(
+                _inputs(spec), p.weights, p.biases[:1], spec=spec,
+                out_region=1,
+            )

@@ -9,7 +9,7 @@ import pytest
 from repro.core.cnn_models import ALEXNET_FUSION, LENET5_FUSION
 from repro.core.executor import init_pyramid_params
 from repro.core.fusion import FusedLevel, FusionSpec
-from repro.kernels.fused_conv.ops import fused_conv2
+from repro.kernels.fused_conv.ops import fused_conv2, fused_pyramid
 from repro.kernels.fused_conv.ref import fused_conv2_ref
 from repro.kernels.online_sop.ops import online_sop_end
 from repro.kernels.online_sop.ref import online_sop_end_ref
@@ -60,13 +60,17 @@ class TestOnlineSopKernel:
             assert int(np.asarray(cyc).max()) <= nd
 
 
-def _run_fused(spec, region, batch=1, end_skip=True, key=KEY, bias_shift=0.0):
-    p = init_pyramid_params(spec, key)
-    b1 = p.biases[0] + bias_shift
-    x = jax.random.normal(
+def _input(spec, batch=1):
+    return jax.random.normal(
         jax.random.PRNGKey(1),
         (batch, spec.input_size, spec.input_size, spec.levels[0].n_in),
     )
+
+
+def _run_fused(spec, region, batch=1, end_skip=True, key=KEY, bias_shift=0.0):
+    p = init_pyramid_params(spec, key)
+    b1 = p.biases[0] + bias_shift
+    x = _input(spec, batch)
     out, skip = fused_conv2(
         x, p.weights[0], b1, p.weights[1], p.biases[1],
         spec=spec, out_region=region, end_skip=end_skip,
@@ -82,8 +86,18 @@ class TestFusedConvKernel:
 
     @pytest.mark.parametrize("region", [1, 13])
     def test_alexnet_regions(self, region):
-        out, ref, _ = _run_fused(ALEXNET_FUSION, region)
-        np.testing.assert_allclose(out, ref, atol=1e-4)
+        # the whole-image region lands all 259 padded rows of the 3-channel
+        # input, lane-padded to 128: more than the default 16 MiB budget
+        spec = ALEXNET_FUSION
+        p = init_pyramid_params(spec, KEY)
+        x = _input(spec)
+        out, _ = fused_pyramid(
+            x, p.weights, p.biases, spec=spec, out_region=region,
+            vmem_budget=64 << 20,
+        )
+        ref = fused_conv2_ref(x, spec, p.weights[0], p.biases[0],
+                              p.weights[1], p.biases[1])
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
 
     @pytest.mark.parametrize(
         "k1,s1,p1,k2,s2,p2,size,region",

@@ -24,8 +24,8 @@ def test_dryrun_cell_subprocess(arch, shape):
             [sys.executable, "-m", "repro.launch.dryrun",
              "--arch", arch, "--shape", shape, "--mesh", "single",
              "--out", str(out)],
-            # JAX_PLATFORMS=cpu: the dry-run compiles on forced host devices;
-            # without it jax probes for TPU hardware and hangs on TPU images
+            # JAX_PLATFORMS=cpu: the dry-run compiles on forced host devices,
+            # and a child must not take the TPU the test process may hold
             env={"PYTHONPATH": str(REPO / "src"), "PATH": "/usr/bin:/bin",
                  "JAX_PLATFORMS": "cpu"},
             capture_output=True, text=True, timeout=420, cwd=str(REPO),

@@ -10,7 +10,7 @@
   workload, equality at ``alpha == 1``, VMEM accounting of the extra landing
   slot, and the ``plan_launch`` ladder pinning ``x_slots``;
 * the memoized ``auto_partition`` (same plan object back, distinct keys
-  distinct) and the ``weights=None`` streamed-flat API cleanup.
+  distinct) and the launch's one-tensor-per-level weight contract.
 """
 
 import dataclasses
@@ -29,8 +29,8 @@ from repro.core.cnn_models import (
 from repro.core.cycle_model import grid_pipeline_cycles
 from repro.core.executor import init_pyramid_params
 from repro.core.fusion import FusedLevel, FusionSpec
-from repro.core.program import compile_program, plan_launch
-from repro.kernels.fused_conv.ops import flatten_weights, fused_pyramid
+from repro.core.program import compile_program, padded_bytes, plan_launch
+from repro.kernels.fused_conv.ops import fused_pyramid
 from repro.net.graph import MODELS, lenet5
 from repro.net.partition import (
     auto_partition,
@@ -208,8 +208,9 @@ class TestPipelineCycleModel:
 
     def test_vmem_accounts_extra_landing_slot(self):
         prog = plan_launch(VGG_FUSION).program
-        c0 = prog.levels[0].n_in
-        extra = 4 * prog.tile0 ** 2 * c0
+        extra = padded_bytes(
+            (prog.tile0, prog.input_window(), prog.input_lanes()), "float32"
+        )
         assert prog.vmem_bytes(2) - prog.vmem_bytes(1) == extra
         assert (
             prog.vmem_stream_bytes(1, 2) - prog.vmem_stream_bytes(1, 1) == extra
@@ -317,35 +318,33 @@ class TestPartitionMemoization:
         g = lenet5()
         p1 = auto_partition(g)
         p2 = auto_partition(g, batch=4)
-        p3 = auto_partition(g, vmem_budget=40_000)
+        p3 = auto_partition(g, vmem_budget=8 << 20)
         assert p1 is not p2 and p1 is not p3
-        assert p2.batch == 4 and p3.vmem_budget == 40_000
+        assert p2.batch == 4 and p3.vmem_budget == 8 << 20
 
 
-class TestWeightsNoneAPI:
-    def test_streamed_flat_only(self):
+class TestWeightsAPI:
+    def test_streamed_matches_resident(self):
+        """The same per-level weight tensors feed both regimes, bitwise."""
         spec = LENET5_FUSION
         p = init_pyramid_params(spec, KEY)
         x = _inputs(spec)
         y0, s0 = fused_pyramid(
-            x, p.weights, p.biases, spec=spec, out_region=1, streamed=True
+            x, p.weights, p.biases, spec=spec, out_region=1, streamed=False
         )
         y1, s1 = fused_pyramid(
-            x, None, p.biases, spec=spec, out_region=1, streamed=True,
-            weights_flat=flatten_weights(p.weights),
+            x, p.weights, p.biases, spec=spec, out_region=1, streamed=True
         )
         np.testing.assert_array_equal(np.asarray(y1), np.asarray(y0))
         np.testing.assert_array_equal(np.asarray(s1), np.asarray(s0))
 
-    def test_weights_none_requires_streamed_flat(self):
+    @pytest.mark.parametrize("streamed", [False, True])
+    def test_one_weight_tensor_per_level(self, streamed):
         spec = LENET5_FUSION
         p = init_pyramid_params(spec, KEY)
         x = _inputs(spec)
-        with pytest.raises(AssertionError, match="weights=None"):
+        with pytest.raises(AssertionError, match="one weight tensor"):
             fused_pyramid(
-                x, None, p.biases, spec=spec, out_region=1, streamed=False
-            )
-        with pytest.raises(AssertionError, match="weights=None"):
-            fused_pyramid(
-                x, None, p.biases, spec=spec, out_region=1, streamed=True
+                x, p.weights[:1], p.biases, spec=spec, out_region=1,
+                streamed=streamed,
             )

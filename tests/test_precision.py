@@ -1,7 +1,7 @@
 """Low-precision fused dataflow (DESIGN.md §11): bf16 end-to-end.
 
 * bf16 parity — every dataflow regime (resident / streamed x1 / x2 /
-  channel-tiled / ``weights=None`` pre-flattened) produces **bit-identical**
+  channel-tiled) produces **bit-identical**
   bf16 outputs: the f32-accumulate-then-cast contract makes the movement
   schedule invisible at any dtype, exactly as at f32;
 * bf16 accuracy — each regime is bit-close to the f32 reference (operand
@@ -42,7 +42,7 @@ from repro.core.executor import init_pyramid_params
 from repro.core.fusion import FusedLevel, FusionSpec
 from repro.core.intensity import launch_dataflow
 from repro.core.program import compile_program, plan_launch
-from repro.kernels.fused_conv.ops import flatten_weights, fused_pyramid
+from repro.kernels.fused_conv.ops import fused_pyramid
 from repro.net.graph import MODELS, lenet5
 from repro.net.partition import auto_partition
 from repro.net.runner import (
@@ -106,8 +106,9 @@ def _random_spec(rng: random.Random) -> FusionSpec:
 
 
 def _assert_byte_scaling(spec: FusionSpec) -> None:
-    """Every byte model scales exactly with bytes_per_val (int32 END flags
-    excepted, which stay 4 bytes at any compute dtype)."""
+    """Every HBM byte model scales exactly with bytes_per_val (int32 END
+    flags excepted, which stay 4 bytes at any compute dtype); VMEM prices the
+    same buffers at their own dtypes."""
     region = spec.feature_sizes()[-1]
     progs = {
         d: compile_program(spec, region, compute_dtype=d)
@@ -119,8 +120,19 @@ def _assert_byte_scaling(spec: FusionSpec) -> None:
         r = DTYPE_BYTES[d] / DTYPE_BYTES["float32"]
         assert prog.bytes_per_val == DTYPE_BYTES[d]
         assert prog.input_hbm_bytes(1) == base.input_hbm_bytes(1) * r
-        assert prog.vmem_bytes(2, 1) == base.vmem_bytes(2, 1) * r
-        assert prog.vmem_stream_bytes(2, 2) == base.vmem_stream_bytes(2, 2) * r
+        # VMEM is priced per buffer as the chip pads it: the same buffers
+        # at every dtype, tiles and weights at the compute dtype, the row
+        # stage and pooled conv outputs at f32, the skip flags at int32
+        for streamed in (False, True):
+            bufs = prog.vmem_buffers(2, 1, streamed=streamed, w_slots=2)
+            ref = base.vmem_buffers(2, 1, streamed=streamed, w_slots=2)
+            assert [(n, s) for n, s, _ in bufs] == [(n, s) for n, s, _ in ref]
+            assert all(
+                dt == {"stage": "float32", "conv_out": "float32",
+                       "skip_block": "int32"}.get(n, d)
+                for n, _, dt in bufs
+            )
+        assert prog.vmem_bytes(2, 1) <= base.vmem_bytes(2, 1)
         for streamed in (False, True):
             assert (
                 prog.hbm_bytes(1, streamed=streamed) - flags
@@ -142,8 +154,6 @@ class TestBF16KernelParity:
     bit-close to the f32 reference."""
 
     def _all_regimes(self, spec, x, region, c_tiles):
-        p = init_pyramid_params(spec, KEY)
-        flat = flatten_weights(p.weights, "bfloat16")
         runs = {
             "resident": _run(spec, x, region, compute_dtype="bfloat16"),
             "stream_x1": _run(
@@ -158,9 +168,8 @@ class TestBF16KernelParity:
                 spec, x, region, streamed=True, w_slots=2, c_tiles=c_tiles,
                 compute_dtype="bfloat16",
             ),
-            "flat": fused_pyramid(
-                x, None, p.biases, spec=spec, out_region=region,
-                streamed=True, w_slots=2, weights_flat=flat,
+            "stream_w1_x2": _run(
+                spec, x, region, streamed=True, w_slots=1, x_slots=2,
                 compute_dtype="bfloat16",
             ),
         }
@@ -205,15 +214,19 @@ class TestBF16KernelParity:
             )
             assert np.asarray(skip)[..., 1:].all(), kw
 
-    def test_weights_flat_dtype_mismatch_rejected(self):
+    def test_f32_weights_cast_on_entry(self):
+        """f32 master weights and pre-cast bf16 weights give the same bf16
+        launch: the wrapper casts every operand once, on entry."""
         p = init_pyramid_params(Q2_CHAIN, KEY)
-        flat32 = flatten_weights(p.weights, "float32")
-        with pytest.raises(AssertionError, match="weights_flat dtype"):
-            fused_pyramid(
-                _inputs(Q2_CHAIN), None, p.biases, spec=Q2_CHAIN,
-                out_region=5, streamed=True, w_slots=2, weights_flat=flat32,
-                compute_dtype="bfloat16",
-            )
+        x = _inputs(Q2_CHAIN)
+        kw = dict(spec=Q2_CHAIN, out_region=5, streamed=True, w_slots=2,
+                  compute_dtype="bfloat16")
+        y32, _ = fused_pyramid(x, p.weights, p.biases, **kw)
+        y16, _ = fused_pyramid(
+            x, [w.astype(jnp.bfloat16) for w in p.weights],
+            [b.astype(jnp.bfloat16) for b in p.biases], **kw,
+        )
+        np.testing.assert_array_equal(np.asarray(y32), np.asarray(y16))
 
     def test_int8_is_model_only(self):
         with pytest.raises(NotImplementedError, match="int8"):

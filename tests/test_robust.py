@@ -9,6 +9,8 @@ compatible with the historical ``ValueError`` call sites, and — critically
 path.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -118,48 +120,37 @@ class TestPreflight:
             preflight(x, bad, plan=plan)
         assert ei.value.context["nodes"] == ["CL2"]
 
-    def test_flat_dtype_mismatch(self, lenet_setup):
-        """Params prepared at one dtype, run requested at another: the
-        pre-flattened streamed arrays give it away at preflight.  A tight
-        budget forces a streamed launch even on LeNet."""
+    def test_wrong_bias_shape(self, lenet_setup):
         g, params, plan, prepped, x = lenet_setup
-        tight = auto_partition(g, batch=2, vmem_budget=10_000)
-        assert any(p.launch.streamed for p in tight.pyramids)
-        t_prepped = prepare_network_params(tight, params)  # f32 flats
-        with pytest.raises(PreflightError, match="different dtype"):
-            preflight(x, t_prepped, plan=tight, dtype="bfloat16")
-
-    def test_flat_size_mismatch(self, lenet_setup):
-        """Params prepared for a different plan: the flat array length does
-        not match the launch program's weight counts."""
-        g, params, plan, prepped, x = lenet_setup
-        tight = auto_partition(g, batch=2, vmem_budget=10_000)
-        t_prepped = prepare_network_params(tight, params)
-        streamed = next(p for p in tight.pyramids if p.launch.streamed)
-        key = "_flat/" + streamed.name
-        t_prepped = dict(t_prepped)
-        t_prepped[key] = t_prepped[key][:-3]
-        with pytest.raises(PreflightError, match="different plan"):
-            preflight(x, t_prepped, plan=tight)
-
-    def test_stale_flat_entries(self, lenet_setup):
-        g, params, plan, prepped, x = lenet_setup
-        stale = dict(prepped)
-        stale["_flat/NOPE..NADA"] = jnp.zeros((8,), jnp.float32)
-        with pytest.raises(PreflightError, match="not in this plan"):
-            preflight(x, stale, plan=plan)
-
-    def test_flat_for_resident_pyramid_conflicts(self, lenet_setup):
-        """weights_flat belongs to streamed launches; a flat entry for a
-        resident pyramid means params and plan disagree."""
-        g, params, plan, prepped, x = lenet_setup
-        resident = [p for p in plan.pyramids if not p.launch.streamed]
-        if not resident:
-            pytest.skip("no resident pyramid in this plan")
+        w, b = prepped["CL2"]
         bad = dict(prepped)
-        bad["_flat/" + resident[0].name] = jnp.zeros((8,), jnp.float32)
-        with pytest.raises(PreflightError, match="not streamed"):
+        bad["CL2"] = (w, b[:-1])
+        with pytest.raises(PreflightError, match="bias shape") as ei:
             preflight(x, bad, plan=plan)
+        assert ei.value.context["node"] == "CL2"
+
+    def test_integer_params_rejected(self, lenet_setup):
+        g, params, plan, prepped, x = lenet_setup
+        w, b = prepped["CL1"]
+        bad = dict(prepped)
+        bad["CL1"] = (w.astype(jnp.int32), b)
+        with pytest.raises(PreflightError, match="must be floating"):
+            preflight(x, bad, plan=plan)
+
+    def test_plan_covering_foreign_node(self, lenet_setup):
+        g, params, plan, prepped, x = lenet_setup
+        pyr = plan.pyramids[0]
+        bad = dataclasses.replace(plan, pyramids=(
+            dataclasses.replace(pyr, node_names=pyr.node_names + ("NOPE",)),
+        ) + plan.pyramids[1:])
+        with pytest.raises(PreflightError, match="not in graph"):
+            preflight(x, prepped, plan=bad)
+
+    def test_params_dtype_independent_of_run_dtype(self, lenet_setup):
+        """Params are cast per launch, so f32-prepared params preflight
+        clean for a bf16 run."""
+        g, params, plan, prepped, x = lenet_setup
+        assert preflight(x, prepped, plan=plan, dtype="bfloat16") == "bfloat16"
 
     def test_budget_headroom(self, lenet_setup):
         g, params, plan, prepped, x = lenet_setup

@@ -532,6 +532,20 @@ class TestDefaultConfigEquivalence:
         assert summary["resilience"]["breakers"] == {}
         assert eng_a._breakers == {}
 
+    def test_default_engine_surfaces_kernel_failure(self, monkeypatch):
+        """A kernel that fails to compile fails the run: the default engine
+        has no interpret or reference rung to fall to."""
+        import repro.net.runner as runner
+
+        def broken(*args, **kwargs):
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+        monkeypatch.setattr(runner, "fused_pyramid", broken)
+        eng = _engine(buckets=(3,))  # a batch shape no other test traced
+        with pytest.raises(RuntimeError, match="Mosaic failed"):
+            eng.serve([_images(3, seed=9)])
+        assert not any(eng.resilience.values())
+
     def test_config_validation(self):
         with pytest.raises(PreflightError):
             ServeConfig(shed_margin=0.0)

@@ -1,0 +1,145 @@
+"""Compile rehearsal for a TPU v5e without one attached.
+
+Interpret mode accepts kernels Mosaic refuses: unaligned DMA windows, value
+reshapes and strided value slices, blocks that break the (8, 128) rule, more
+VMEM than the scoped limit.  These tests compile for a *described* v5e chip
+(``jax.experimental.topologies``), so what the chip's compiler would refuse
+fails here, at no chip time:
+
+* one fused-pyramid launch of every distinct kind (weight regime, input
+  slots, level strides, pools, compute dtype) the zoo's plans use at their
+  published input sizes, buckets 1 and 8, each compiled with the VMEM
+  limit its plan's budget sets (budget plus Mosaic's headroom);
+* the whole ResNet-18 224x224 forward, whose HLO must hold one
+  ``tpu_custom_call`` per planned pyramid (no launch left to interpret mode).
+
+The topology is described inside a fixture, never at import: only one process
+may load the TPU library, and the workers of a multi-process test run import
+every test file.  Keep these tests in this one file.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.dtypes import jnp_dtype
+from repro.kernels.fused_conv.ops import fused_pyramid
+from repro.net.graph import MODELS
+from repro.net.partition import auto_partition
+from repro.net.runner import (
+    _run_network_jit,
+    init_network_params,
+    prepare_network_params,
+)
+
+ZOO = [
+    (model, dtype)
+    for model in ("lenet", "alexnet", "vgg16", "resnet18")
+    for dtype in ("float32", "bfloat16")
+]
+CUSTOM_CALL = 'custom_call_target="tpu_custom_call"'
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one: keep the cache off meanwhile."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _kind(launch, dtype):
+    prog = launch.program
+    return (
+        launch.regime,
+        launch.x_slots,
+        prog.alpha > 1,
+        tuple(p.S for p in prog.levels),
+        tuple(p.pool for p in prog.levels),
+        dtype,
+    )
+
+
+def _compile_launch(pyr, batch, budget, dtype, sharding):
+    lp, spec = pyr.launch, pyr.spec
+    prog = lp.program
+    cdt = jnp_dtype(dtype)
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, cdt, sharding=sharding)
+
+    x = shape(batch, spec.input_size, spec.input_size, spec.levels[0].n_in)
+    weights = [shape(p.K, p.K, p.n_in, p.n_out) for p in prog.levels]
+    biases = [shape(p.n_out) for p in prog.levels]
+    return fused_pyramid.lower(
+        x, weights, biases, spec=spec, out_region=lp.out_region,
+        streamed=lp.streamed, w_slots=lp.w_slots if lp.streamed else None,
+        x_slots=lp.x_slots, c_tiles=lp.c_tiles, relu=pyr.relu,
+        interpret=False, vmem_budget=budget,
+        compute_dtype=dtype,
+    ).compile().as_text()
+
+
+@pytest.mark.parametrize("model,dtype", ZOO)
+def test_zoo_launch_kinds_compile(model, dtype, one_chip, no_compile_cache):
+    kinds = {}
+    for bucket in (1, 8):
+        plan = auto_partition(MODELS[model](), batch=bucket, compute_dtype=dtype)
+        for pyr in plan.pyramids:
+            kinds.setdefault(
+                _kind(pyr.launch, dtype), (pyr, bucket, plan.vmem_budget)
+            )
+    for kind, (pyr, bucket, budget) in kinds.items():
+        text = _compile_launch(pyr, bucket, budget, dtype, one_chip)
+        assert text.count(CUSTOM_CALL) == 1, (model, pyr.name, kind)
+
+
+def test_resnet18_forward_is_all_kernels(one_chip, no_compile_cache):
+    graph = MODELS["resnet18"]()
+    plan = auto_partition(graph, batch=1)
+    params = jax.eval_shape(
+        lambda: prepare_network_params(
+            plan, init_network_params(graph, jax.random.PRNGKey(0))
+        )
+    )
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        params,
+    )
+    x = jax.ShapeDtypeStruct(
+        (1, graph.input_size, graph.input_size, graph.in_channels),
+        jnp.float32, sharding=one_chip,
+    )
+    text = _run_network_jit.lower(
+        x, params, plan=plan, interpret=False
+    ).compile().as_text()
+    assert text.count(CUSTOM_CALL) == len(plan.pyramids)
