@@ -1,0 +1,10 @@
+"""The benchmark's self-tests run on the CPU at small sizes:
+``JAX_PLATFORMS=cpu python -m pytest bench/tests`` from the repository root."""
+
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for path in (ROOT, ROOT / "src"):
+    if str(path) not in sys.path:
+        sys.path.insert(0, str(path))
