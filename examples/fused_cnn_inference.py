@@ -29,9 +29,10 @@ from repro.net.runner import (
     init_network_params,
     reference_network,
     run_network,
+    run_network_per_launch,
     skip_fractions,
 )
-from repro.obs import tracing
+from repro.obs import TraceCollector
 
 # interpret-friendly default scales off the chip (paper scale for LeNet only)
 INTERPRET_SIZE = {"lenet": 32, "alexnet": 67, "vgg16": 32, "resnet18": 32}
@@ -101,10 +102,12 @@ def main() -> None:
         k: (w, b - 0.3) if graph.node(k).op == "conv" else (w, b)
         for k, (w, b) in params.items()
     }
-    # run the sparse forward traced (DESIGN.md #12): one measured+modeled
-    # span per fused launch, recorded launch-by-launch
-    with tracing() as collector:
-        logits_s, skips_s = run_network(xs, sparse_params, plan=tight)
+    # run the sparse forward launch by launch (DESIGN.md #12): one
+    # measured+modeled span per fused launch
+    collector = TraceCollector()
+    logits_s, skips_s = run_network_per_launch(
+        xs, sparse_params, plan=tight, collector=collector
+    )
     ref_s = reference_network(xs, graph, sparse_params)
     print("sparse input: max |err|", float(jnp.abs(logits_s - ref_s).max()))
     for name, frac in skip_fractions(skips_s).items():

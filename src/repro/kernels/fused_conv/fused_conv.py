@@ -665,6 +665,7 @@ def fused_pyramid_pallas(
     x_slots: int = 2,
     c_tiles: int = 1,
     vmem_limit_bytes: int | None = None,
+    name: str | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Launch the variadic fused pyramid over the (B, alpha, alpha) grid.
 
@@ -689,7 +690,8 @@ def fused_pyramid_pallas(
     ``interpret=None`` auto-resolves to compiled on TPU, interpreted
     elsewhere.  ``vmem_limit_bytes`` is the scoped-VMEM limit the compiled
     kernel gets: the budget its plan was made under plus
-    :data:`~repro.core.program.MOSAIC_HEADROOM_BYTES`.
+    :data:`~repro.core.program.MOSAIC_HEADROOM_BYTES`.  ``name`` is the
+    kernel's name (the ``pallas_call``'s, hence the HLO custom call's).
 
     With ``c_tiles > 1`` the launch runs the channel-tiled grid
     ``(B, alpha, alpha, c_tiles)``: a fourth sequential axis over
@@ -835,6 +837,7 @@ def fused_pyramid_pallas(
             vmem_limit_bytes=vmem_limit_bytes,
         ),
         interpret=resolve_interpret(interpret),
+        name=name,
     )(*operands)
     side = alpha * region
     out = out.transpose(0, 1, 4, 2, 5, 3, 6).reshape(B, side, side, last.n_out)

@@ -41,6 +41,7 @@ from .fused_conv import fused_pyramid_pallas
     static_argnames=(
         "spec", "out_region", "streamed", "w_slots", "x_slots", "c_tiles",
         "relu", "end_skip", "interpret", "vmem_budget", "compute_dtype",
+        "name",
     ),
 )
 def fused_pyramid(
@@ -59,6 +60,7 @@ def fused_pyramid(
     interpret: bool | None = None,
     vmem_budget: int = VMEM_BUDGET_BYTES,
     compute_dtype: str = "float32",
+    name: str | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Fused Q-conv pyramid forward as a single kernel launch.
 
@@ -81,6 +83,10 @@ def fused_pyramid(
     §11) — and re-tiers the regime ladder, since halved bytes let plans that
     streamed at f32 go resident or double-buffered at bf16.
     ``interpret=None`` resolves to compiled on TPU, interpreted on CPU/GPU.
+    ``name`` (static) names the kernel: the runner passes the plan's
+    pyramid name (``conv1..maxpool``), which becomes the compiled custom
+    call's HLO instruction name and so the kernel's name in a profiler
+    trace.
     Returns ``(out, skip)`` with ``skip``: (B, alpha, alpha, Q) int32
     END-cascade flags (level 0 never skips, and skip flags are
     dtype-invariant).
@@ -171,6 +177,7 @@ def fused_pyramid(
         x_slots=x_slots,
         c_tiles=c_tiles,
         vmem_limit_bytes=vmem_budget + MOSAIC_HEADROOM_BYTES,
+        name=name,
     )
 
 

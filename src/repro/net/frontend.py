@@ -26,6 +26,11 @@ races the submit return path), so results with no handle yet are parked
 and claimed at registration — no result is ever lost to the race, which
 is exactly what the multi-threaded hammer test asserts.
 
+Two host spans go to the process's tracer (``repro.obs.get_tracer()``):
+``frontend.idle`` while the drain thread waits for work, and
+``frontend.deliver`` per request, from the listener resolving its handle to
+:meth:`RequestHandle.result` returning in the caller's thread.
+
 Use::
 
     frontend = ServingFrontend(engine)
@@ -37,8 +42,14 @@ Use::
 from __future__ import annotations
 
 import threading
+import time
+
+from repro.obs.trace import get_tracer, span_code
 
 from .serve import RequestResult, ServingEngine
+
+_IDLE = span_code("frontend.idle")
+_DELIVER = span_code("frontend.deliver")
 
 
 class RequestHandle:
@@ -48,8 +59,10 @@ class RequestHandle:
         self.id = rid
         self._event = threading.Event()
         self._result: RequestResult | None = None
+        self._resolved_s: float | None = None  # None again once delivered
 
     def _resolve(self, result: RequestResult) -> None:
+        self._resolved_s = time.perf_counter()
         self._result = result
         self._event.set()
 
@@ -65,6 +78,9 @@ class RequestHandle:
             raise TimeoutError(
                 f"request {self.id} not terminal after {timeout}s"
             )
+        t0, self._resolved_s = self._resolved_s, None
+        if t0 is not None:
+            get_tracer().span(_DELIVER, t0, time.perf_counter(), self.id)
         return self._result
 
 
@@ -132,7 +148,9 @@ class ServingFrontend:
 
     def _loop(self) -> None:
         while not self._stopping.is_set():
+            t0 = time.perf_counter()
             self._work.wait(timeout=0.05)
+            get_tracer().span(_IDLE, t0, time.perf_counter())
             self._work.clear()
             self.engine.drain()
         self.engine.drain()  # final sweep: nothing submitted is abandoned

@@ -187,9 +187,9 @@ def _forward(
     launch_wrapper=None,
 ) -> tuple[jnp.ndarray, dict[str, jnp.ndarray]]:
     """The plan-driven forward loop, shared by the jit fast path, the
-    traced eager path, and the guarded eager path.
+    per-launch timed path, and the guarded eager path.
     ``launch_wrapper(pyr, call, x_in)``, when given, wraps each
-    fused-pyramid launch — the traced path times it there, the guarded path
+    fused-pyramid launch — the per-launch path times it there, the guarded path
     (``repro.robust.degrade``) runs its degradation ladder there, using
     ``x_in`` (the launch input) for replans and reference quarantines and
     ``call(interpret=True)``-style keyword overrides for retries.  The jit
@@ -223,6 +223,7 @@ def _forward(
                     interpret=interpret,
                     vmem_budget=plan.vmem_budget,
                     compute_dtype=cdt,
+                    name=pyr.name,
                 )
                 # wrapper retries may override launch knobs, e.g.
                 # call(interpret=True) on the degradation ladder
@@ -294,16 +295,26 @@ def _run_network_jit(
     )
 
 
-def _run_network_traced(
-    x, params, tracer, *, plan, end_skip, interpret, dtype
-):
-    """The observed forward: the same plan executed launch-by-launch outside
-    the whole-graph jit (each ``fused_pyramid`` call is still jit itself),
-    every launch blocked-until-ready and recorded as a :class:`LaunchSpan`
-    whose modeled fields come straight from the plan — plus per-launch
-    END-skip count events and one ``run_network`` summary event.  Slower
-    than the fused jit path by construction (that is what it measures); the
-    fast path is byte-for-byte unaffected when tracing is off."""
+def run_network_per_launch(
+    x: jnp.ndarray,
+    params: Params,
+    *,
+    plan: PartitionPlan,
+    collector,
+    end_skip: bool = True,
+    interpret: bool | None = None,
+    dtype: str | None = None,
+) -> tuple[jnp.ndarray, dict[str, jnp.ndarray]]:
+    """The launch-by-launch timed forward (DESIGN.md §12): the same plan
+    executed outside the whole-graph jit (each ``fused_pyramid`` call is
+    still jit itself), every launch blocked-until-ready and recorded into
+    ``collector`` (a :class:`~repro.obs.trace.TraceCollector`) as a
+    :class:`LaunchSpan` whose modeled fields come straight from the plan —
+    plus per-launch END-skip count events and one ``run_network`` summary
+    event.  Slower than :func:`run_network` by construction (that is what
+    it measures), so only callers that want per-launch times call it:
+    ``repro.obs.explain --run`` and the example script.  Same arguments and
+    results as :func:`run_network`."""
     cdt = canonical_dtype(plan.compute_dtype if dtype is None else dtype)
     model = plan.graph.name
     batch = int(x.shape[0])
@@ -314,7 +325,7 @@ def _run_network_traced(
         jax.block_until_ready((y, skip))
         dur_ms = (time.perf_counter() - t0) * 1e3
         d = pyr.launch.describe(batch, plan.vmem_budget)
-        tracer.record_span(LaunchSpan(
+        collector.record_span(LaunchSpan(
             name=pyr.name,
             model=model,
             regime=d["regime"],
@@ -348,14 +359,14 @@ def _run_network_traced(
         # per-level count of grid cells the END cascade skipped, plus the
         # cell total — the runtime twin of the paper's skipped-convolution
         # accounting (level 0 never skips by construction)
-        tracer.record_event(
+        collector.record_event(
             "end_skip_counts",
             model=model,
             launch=name,
             per_level=[int(c) for c in arr.sum(axis=(0, 1, 2))],
             cells=int(arr[..., 0].size),
         )
-    tracer.record_event(
+    collector.record_event(
         "run_network",
         model=model,
         batch=batch,
@@ -393,19 +404,16 @@ def run_network(
     ``(B, alpha, alpha, Q)`` int32 END-cascade flag map (level 0 of each
     pyramid never skips).  Aggregate with :func:`skip_fractions`.
 
-    Observability (DESIGN.md §12): with a tracer installed
-    (``repro.obs.tracing()``) the forward runs launch-by-launch and records
-    one measured+modeled span per fused launch plus END-skip count events.
-    With the default no-op tracer the whole forward goes through the
-    unchanged jit fast path — the only extra work is this one ``enabled``
-    check, *outside* jit, so tracing-off costs nothing per call.
+    The forward is one jit-compiled program whatever tracer is installed;
+    per-launch times come from :func:`run_network_per_launch` (DESIGN.md
+    §12).
 
     Guarded execution (DESIGN.md §13): with a guard installed
     (``repro.robust.guarding()``) the forward instead runs the preflighted,
     sentinel-checked degradation-ladder path of
-    :func:`repro.robust.degrade.run_network_guarded`.  Like tracing, the
-    guard is one static ``enabled`` check outside jit — guards off leaves
-    the jit fast path byte-identical.
+    :func:`repro.robust.degrade.run_network_guarded`.  The guard is one
+    static ``enabled`` check outside jit — guards off leaves the jit fast
+    path byte-identical.
     """
     guard = get_guard()
     if guard.enabled:
@@ -415,15 +423,9 @@ def run_network(
             x, params, plan=plan, end_skip=end_skip, interpret=interpret,
             dtype=dtype, guard=guard,
         )
-    tracer = get_tracer()
-    if not tracer.enabled:
-        return _run_network_jit(
-            x, params, plan=plan, end_skip=end_skip, interpret=interpret,
-            dtype=dtype,
-        )
-    return _run_network_traced(
-        x, params, tracer, plan=plan, end_skip=end_skip,
-        interpret=interpret, dtype=dtype,
+    return _run_network_jit(
+        x, params, plan=plan, end_skip=end_skip, interpret=interpret,
+        dtype=dtype,
     )
 
 

@@ -66,10 +66,16 @@ the runner into a service (ROADMAP's continuous-batching item):
 * **SLO + measurement** — each bucket publishes ``slo_us`` (modeled
   cold latency: host staging + the plan's ``modeled_us()``), ``steady_us``
   (the double-buffered steady state, ``max(compute, staging)``), and
-  measured p50/p95 request latency + imgs/s; with a tracer installed
-  (``repro.obs.tracing``) every batch records a ``serve_batch`` event, the
-  cache bumps ``serve_cache_{hit,miss,eviction}`` counters, and every
-  shed/expiry/watchdog/breaker/sentinel action records its own event.
+  measured p50/p95 request latency + imgs/s.  The process's tracer
+  (``repro.obs.get_tracer()``, an always-on bounded recorder by default)
+  gets host spans where the work happens: ``engine.admit`` per request
+  (linked to its batch at staging), and ``engine.stage`` /
+  ``engine.dispatch`` (with its route) / ``engine.block`` /
+  ``engine.record`` per batch; the cache bumps
+  ``serve_cache_{hit,miss,eviction}`` counters (a miss also records an
+  event), and every shed/expiry/watchdog/breaker/sentinel action records
+  its own event.  Installing a tracer never changes the forward path:
+  batches always run the jit-compiled ``run_network``.
 * **Degradation, not drops** — ``ServeConfig(guarded=True)`` runs each
   bucket under the PR 8 ladder (``repro.robust.guarding``): a VMEM miss
   replans, a numeric fault quarantines the launch to the reference path,
@@ -104,7 +110,7 @@ from repro.core.cycle_model import (
 from repro.core.dtypes import DTYPE_BYTES, canonical_dtype
 from repro.core.program import VMEM_BUDGET_BYTES
 from repro.obs.stats import percentile
-from repro.obs.trace import get_tracer
+from repro.obs.trace import get_tracer, span_code
 from repro.robust.breaker import CircuitBreaker
 from repro.robust.errors import (
     DeadlineExceeded,
@@ -123,6 +129,14 @@ from .runner import (
     reference_network,
     run_network,
 )
+
+
+_ADMIT = span_code("engine.admit")
+_STAGE = span_code("engine.stage")
+_DISPATCH = span_code("engine.dispatch")
+_BLOCK = span_code("engine.block")
+_RECORD = span_code("engine.record")
+_ROUTES = {r: span_code(r) for r in ("fused", "interpret", "reference")}
 
 
 def bucket_for(rows: int, buckets: tuple[int, ...]) -> int:
@@ -244,6 +258,7 @@ class Request:
     deadline_us: float | None = None
     deadline_s: float | None = None
     priority: int = 0
+    span: int = -1  # ring slot of its engine.admit span
 
 
 @dataclass(frozen=True)
@@ -352,6 +367,7 @@ class ServingEngine:
         self.cache_counters = {"hits": 0, "misses": 0, "evictions": 0}
         self._stats: dict[int, _BucketStats] = {}
         self._next_id = 0
+        self._next_batch = 0
         self.rejected = 0
         self.resilience = {
             "shed": 0, "expired": 0, "failed": 0,
@@ -390,6 +406,8 @@ class ServingEngine:
         — is *rejected*, not raised: its :class:`RequestResult` carries the
         typed error and the queue keeps moving.  Callers poll
         :attr:`results` (or register a listener / use the frontend)."""
+        t0 = time.perf_counter()
+        tracer = get_tracer()
         with self._lock:
             rid = self._next_id
             self._next_id += 1
@@ -426,7 +444,6 @@ class ServingEngine:
                     self.resilience["shed"] += 1
                 result = RequestResult(id=rid, rows=rows, error=err)
                 self.results[rid] = result
-                tracer = get_tracer()
                 if tracer.enabled:
                     tracer.bump("serve_shed" if shed else "serve_reject")
                     tracer.record_event(
@@ -435,7 +452,13 @@ class ServingEngine:
                         error=type(err).__name__, message=str(err),
                     )
                 self._notify(result)
+                tracer.span(_ADMIT, t0, time.perf_counter(), rid, rows=rows)
                 return rid
+            # recorded before the request is queued, so staging can link
+            # its slot to the batch without a race
+            slot = tracer.span(
+                _ADMIT, t0, time.perf_counter(), rid, rows=rows
+            )
             self.queue.append(Request(
                 id=rid, x=x, rows=rows, enqueue_s=now,
                 deadline_us=deadline_us,
@@ -444,6 +467,7 @@ class ServingEngine:
                     if deadline_us is not None else None
                 ),
                 priority=priority,
+                span=slot,
             ))
             return rid
 
@@ -533,14 +557,14 @@ class ServingEngine:
             entry = self._cache[key]
         if tracer.enabled:
             tracer.bump("serve_cache_hit" if hit else "serve_cache_miss")
-            tracer.record_event(
-                "serve_plan_cache",
-                model=self.graph.name, bucket=bucket,
-                cache="hit" if hit else "miss",
-                compute_dtype=self.compute_dtype,
-                launches=entry.plan.n_launches(),
-                slo_us=entry.slo_us,
-            )
+            if not hit:
+                tracer.record_event(
+                    "serve_plan_cache",
+                    model=self.graph.name, bucket=bucket, cache="miss",
+                    compute_dtype=self.compute_dtype,
+                    launches=entry.plan.n_launches(),
+                    slo_us=entry.slo_us,
+                )
         return entry
 
     # -- circuit breaker ----------------------------------------------------
@@ -685,31 +709,30 @@ class ServingEngine:
 
     def _next_staged(self):
         """Form and stage the next batch, failing staging-faulted batches
-        typed and moving on — a poisoned batch never wedges the loop."""
+        typed and moving on — a poisoned batch never wedges the loop.
+        Returns ``(batch, bucket, entry, x_dev, batch_id)`` or ``None``; a
+        staged batch records its ``engine.stage`` span and links each
+        request's ``engine.admit`` span to the batch id."""
         while True:
+            t0 = time.perf_counter()
             batch = self._form_batch()
             if batch is None:
                 return None
             try:
-                return self._stage(batch)
+                staged = self._stage(batch)
             except RobustError as err:
                 rows = sum(r.rows for r in batch)
                 bucket = bucket_for(rows, self.config.buckets)
                 self._fail_batch(batch, bucket, err)
-
-    def _dispatch(self, entry: _PlanEntry, x_dev):
-        if self.config.guarded:
-            with guarding(GuardConfig(), source_params=self.master_params):
-                return run_network(
-                    x_dev, entry.prepared, plan=entry.plan,
-                    end_skip=self.config.end_skip,
-                    interpret=self.config.interpret,
-                )
-        return run_network(
-            x_dev, entry.prepared, plan=entry.plan,
-            end_skip=self.config.end_skip,
-            interpret=self.config.interpret,
-        )
+                continue
+            bid = self._next_batch
+            self._next_batch += 1
+            tracer = get_tracer()
+            tracer.span(_STAGE, t0, time.perf_counter(), bid,
+                        bucket=staged[1], rows=sum(r.rows for r in batch))
+            for r in batch:
+                tracer.link(r.span, r.id, bid)
+            return staged + (bid,)
 
     def _run_route(self, route: str, entry: _PlanEntry, x_dev):
         """Execute one staged bucket along ``route``; returns
@@ -790,8 +813,7 @@ class ServingEngine:
             )
 
     def _record(
-        self, batch, bucket, entry, logits, wall_ms, *,
-        route: str = "fused", calibrate: bool = True,
+        self, batch, bucket, logits, wall_ms, *, calibrate: bool = True,
     ) -> None:
         done_s = time.perf_counter()
         host_logits = np.asarray(logits)
@@ -817,15 +839,6 @@ class ServingEngine:
                 stats.images += req.rows
                 stats.latencies_ms.append(lat_ms)
                 self._notify(result)
-        tracer = get_tracer()
-        if tracer.enabled:
-            tracer.record_event(
-                "serve_batch",
-                model=self.graph.name, bucket=bucket,
-                requests=len(batch), rows=row,
-                wall_ms=wall_ms, slo_us=entry.slo_us,
-                route=route,
-            )
 
     def drain(self) -> list[RequestResult]:
         """Execute the queue to empty; returns the drained batches' results
@@ -855,11 +868,12 @@ class ServingEngine:
                         )
                     time.sleep(0.001)
                     continue
-                batch, bucket, entry, x_dev = staged
+                batch, bucket, entry, x_dev, bid = staged
                 breaker = self._breaker(bucket)
                 route = "fused"
                 if breaker is not None and not breaker.allow():
                     route = breaker.pinned_rung or "reference"
+                tracer = get_tracer()
                 t0 = time.perf_counter()
                 err: RobustError | None = None
                 logits = report = None
@@ -867,10 +881,15 @@ class ServingEngine:
                     logits, report = self._run_route(route, entry, x_dev)
                 except RobustError as e:
                     err = e
+                tracer.span(_DISPATCH, t0, time.perf_counter(), bid,
+                            bucket=bucket, route=_ROUTES[route])
                 staged_next = self._next_staged()
                 sentinel_tripped = False
                 if err is None:
+                    t_block = time.perf_counter()
                     jax.block_until_ready(logits)
+                    tracer.span(_BLOCK, t_block, time.perf_counter(), bid,
+                                bucket=bucket)
                     if inj.enabled:
                         delay = inj.launch_delay(self._launch_name(bucket))
                         if delay:
@@ -932,11 +951,14 @@ class ServingEngine:
                 if err is not None:
                     self._fail_batch(batch, bucket, err, wall_ms)
                 else:
+                    t_rec = time.perf_counter()
                     self._record(
-                        batch, bucket, entry, logits, wall_ms,
-                        route=route,
+                        batch, bucket, logits, wall_ms,
                         calibrate=not (wd_tripped or sentinel_tripped),
                     )
+                    tracer.span(_RECORD, t_rec, time.perf_counter(), bid,
+                                bucket=bucket,
+                                rows=sum(r.rows for r in batch))
                 completed.extend(self.results[r.id] for r in batch)
                 staged = staged_next
         return completed

@@ -5,14 +5,16 @@ streamed vs channel-tiled) is chosen by *modeled* cycles; this package is
 the substrate that records what each launch planned and what it measurably
 did, so the model-vs-hardware loop can be closed (ROADMAP).  Pieces:
 
-* :mod:`repro.obs.trace` — the :class:`TraceCollector` span/event store and
-  the process-global tracer hook (:func:`get_tracer` / :func:`tracing`).
-  The default tracer is a no-op whose only cost on the hot path is one
-  attribute check *outside* jit (see ``net/runner.run_network``).
+* :mod:`repro.obs.trace` — the :class:`TraceCollector` host-span ring,
+  launch-span, event and counter store, and the process-global tracer hook
+  (:func:`get_tracer` / :func:`tracing`).  The default tracer is an
+  always-on bounded recorder that the serving engine times its host path
+  into; installing any tracer leaves the forward path unchanged.
 * :mod:`repro.obs.timeline` — Chrome-trace (``chrome://tracing`` /
   Perfetto) JSON export: each launch's modeled fill/steady/drain
   DMA-vs-MXU timeline from the cycle model rendered alongside measured
-  spans, plus the schema validator the CI smoke job runs.
+  launch spans and host spans (one track per thread), plus the schema
+  validator the CI smoke job runs.
 * :mod:`repro.obs.report` — the model-vs-measured drift report joining
   modeled cycles against measured medians per launch.
 * :mod:`repro.obs.explain` — the ``python -m repro.obs.explain`` CLI: the
@@ -24,6 +26,8 @@ See DESIGN.md §12 for the span schema and the timeline format.
 from .stats import percentile, timed_stats_ms
 from .timeline import chrome_trace, validate_chrome_trace, write_chrome_trace
 from .trace import (
+    NULL_TRACER,
+    HostSpan,
     LaunchSpan,
     TraceCollector,
     TraceEvent,
@@ -47,6 +51,8 @@ def __getattr__(name: str):
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 __all__ = [
+    "NULL_TRACER",
+    "HostSpan",
     "LaunchSpan",
     "TraceCollector",
     "TraceEvent",

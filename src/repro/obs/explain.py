@@ -6,10 +6,11 @@ headroom, modeled cycles), and optionally:
 
 * ``--trace out.json`` — export a Chrome-trace / Perfetto JSON of every
   launch's modeled fill/steady/drain DMA-vs-MXU timeline
-  (:mod:`repro.obs.timeline`); with ``--run`` the measured spans of a traced
-  ``run_network`` ride alongside.
-* ``--run`` — execute the plan with tracing enabled (one warm-up then
-  ``--reps`` traced forwards) and print the model-vs-measured drift table
+  (:mod:`repro.obs.timeline`); with ``--run`` the measured launch spans
+  ride alongside.
+* ``--run`` — execute the plan launch by launch
+  (:func:`~repro.net.runner.run_network_per_launch`: one warm-up then
+  ``--reps`` timed forwards) and print the model-vs-measured drift table
   (:mod:`repro.obs.report`).
 * ``--guard`` — execute the plan under the guarded runtime
   (:mod:`repro.robust`, DESIGN.md §13) and print the fallback table: which
@@ -196,8 +197,8 @@ def main(argv: list[str] | None = None) -> int:
                     help="write a Perfetto/chrome://tracing JSON of the "
                          "modeled (and, with --run, measured) timelines")
     ap.add_argument("--run", action="store_true",
-                    help="execute the plan with tracing enabled and report "
-                         "model-vs-measured drift")
+                    help="execute the plan launch by launch, timing each "
+                         "launch, and report model-vs-measured drift")
     ap.add_argument("--reps", type=int, default=3,
                     help="traced forwards after the warm-up (with --run)")
     ap.add_argument("--guard", action="store_true",
@@ -272,7 +273,7 @@ def main(argv: list[str] | None = None) -> int:
         from repro.net.runner import (
             init_network_params,
             prepare_network_params,
-            run_network,
+            run_network_per_launch,
             skip_fractions,
         )
         from repro.obs.report import (
@@ -280,7 +281,7 @@ def main(argv: list[str] | None = None) -> int:
             drift_rows_from_spans,
             format_report,
         )
-        from repro.obs.trace import tracing
+        from repro.obs.trace import TraceCollector
 
         params = prepare_network_params(
             plan, init_network_params(graph, jax.random.PRNGKey(0))
@@ -290,13 +291,18 @@ def main(argv: list[str] | None = None) -> int:
             (args.batch, graph.input_size, graph.input_size,
              graph.in_channels),
         )
-        logits, _ = run_network(x, params, plan=plan)  # untraced warm-up
+        # warm-up: compiles every launch the timed forwards will run
+        logits, _ = run_network_per_launch(
+            x, params, plan=plan, collector=TraceCollector()
+        )
         jax.block_until_ready(logits)
-        print(f"\nrunning {args.reps} traced forwards "
+        print(f"\nrunning {args.reps} launch-by-launch forwards "
               f"(interpret={jax.default_backend() != 'tpu'}) ...")
-        with tracing() as collector:
-            for _ in range(args.reps):
-                _, skips = run_network(x, params, plan=plan)
+        collector = TraceCollector()
+        for _ in range(args.reps):
+            _, skips = run_network_per_launch(
+                x, params, plan=plan, collector=collector
+            )
         frac = skip_fractions(skips)
         for name, f in frac.items():
             if any(v > 0 for v in f):

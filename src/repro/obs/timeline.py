@@ -11,10 +11,15 @@ Two kinds of track are rendered into one JSON Event Trace:
   converted to microseconds at the cycle model's clock (100 MHz default), so
   pipeline-overlap claims — "the halo DMA hides behind the MXU cascade" —
   become visually inspectable bars.
-* **Measured** — one thread of wall-clock spans from a
-  :class:`~repro.obs.trace.TraceCollector` (a traced ``run_network``), with
-  every planned knob and modeled cost attached as event ``args``, plus the
-  collector's point events (cache hits/misses, skip stats) as instants.
+* **Measured** — one thread of wall-clock launch spans from a
+  :class:`~repro.obs.trace.TraceCollector` (filled by
+  ``run_network_per_launch``), with every planned knob and modeled cost
+  attached as event ``args``, plus the collector's point events (cache
+  hits/misses, skip stats) as instants.
+* **Host** — the collector's host spans (admission, stage, dispatch,
+  device wait, record, hand-offs), one track per thread, so
+  ``chrome_trace(get_tracer())`` dumps what a live server did over the
+  seconds its ring still holds.
 
 The trace loads directly in Perfetto (https://ui.perfetto.dev) or
 ``chrome://tracing``.  :func:`validate_chrome_trace` checks the subset of
@@ -26,6 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import threading
 
 from repro.core.cycle_model import DEFAULT_PARAMS
 
@@ -33,6 +39,7 @@ from repro.core.cycle_model import DEFAULT_PARAMS
 # modeled launch gets its own process starting here (one per launch keeps
 # Perfetto's per-process track grouping readable for deep plans)
 MEASURED_PID = 1
+HOST_PID = 2
 MODELED_PID0 = 1000
 
 _LANE_TID = {"mxu": 0, "dma": 1}
@@ -121,19 +128,25 @@ def modeled_launch_events(
 
 
 def measured_events(collector) -> list[dict]:
-    """Wall-clock spans + instant events of a collector, on one process.
+    """Wall-clock spans + instant events of a collector, on one process,
+    and its host spans on another, one thread track per host thread.
 
     Timestamps are rebased to the earliest span/event so the trace starts at
     ~0; span ``args`` carry the full span schema, so every modeled quantity
     is clickable next to its measured bar."""
     spans = list(collector.spans)
     events = list(collector.events)
-    if not spans and not events:
+    host = collector.spans_between()
+    if not spans and not events and not host:
         return []
     t0 = min(
         [s.start_s for s in spans] + [e.ts_s for e in events]
+        + [h.start_s for h in host[:1]]
     )
-    out = _meta(
+    out = host_events(host, t0)
+    if not spans and not events:
+        return out
+    out += _meta(
         MEASURED_PID,
         "measured (wall clock)",
         {0: "launch spans", 1: "events"},
@@ -162,6 +175,38 @@ def measured_events(collector) -> list[dict]:
                 "ts": (e.ts_s - t0) * 1e6,
                 "s": "p",
                 "args": dict(e.args),
+            }
+        )
+    return out
+
+
+def host_events(host, t0: float) -> list[dict]:
+    """Complete events of host spans (:class:`~repro.obs.trace.HostSpan`,
+    in start order) on :data:`HOST_PID`, one track per thread, named after
+    the thread where it is still alive; ``t0`` is the time origin."""
+    if not host:
+        return []
+    names = {t.ident: t.name for t in threading.enumerate()}
+    tids: dict[int, int] = {}
+    for h in host:
+        tids.setdefault(h.thread, len(tids))
+    out = _meta(HOST_PID, "host spans (wall clock)", {
+        tid: names.get(ident, f"thread {ident}")
+        for ident, tid in tids.items()
+    })
+    for h in host:
+        out.append(
+            {
+                "ph": "X",
+                "name": h.name,
+                "cat": "host",
+                "pid": HOST_PID,
+                "tid": tids[h.thread],
+                "ts": (h.start_s - t0) * 1e6,
+                "dur": max(h.end_s - h.start_s, 0.0) * 1e6,
+                "args": {"id": h.id, "parent": h.parent,
+                         "bucket": h.bucket, "rows": h.rows,
+                         "route": h.route},
             }
         )
     return out

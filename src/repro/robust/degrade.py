@@ -154,6 +154,7 @@ def _run_subplan(x_in, subs, params, graph, cdt, *, end_skip, interpret,
             interpret=interpret,
             vmem_budget=vmem_budget,
             compute_dtype=cdt,
+            name=sp.name,
         )
         sub_skips[sp.name] = sk
     return y, sub_skips

@@ -1,5 +1,6 @@
-"""Observability subsystem (DESIGN.md §12): tracer dispatch and overhead,
-traced-run span schema, END-skip count events vs reference dead tiles,
+"""Observability subsystem (DESIGN.md §12): the always-on recorder (host
+span ring, wrap, window reads), a tracer never switching the forward path,
+the per-launch timed run's span schema, END-skip count events vs reference dead tiles,
 timeline/cycle-model consistency, Chrome-trace export across the zoo, the
 drift report, partition-cache counters, and the benchmark satellites
 (p50/p95 stats, regression diff table)."""
@@ -7,6 +8,7 @@ drift report, partition-cache counters, and the benchmark satellites
 import json
 import pathlib
 import sys
+import threading
 
 import jax
 import numpy as np
@@ -24,8 +26,10 @@ from repro.net.partition import (
 from repro.net import runner
 from repro.net.runner import (
     init_network_params,
+    jit_trace_count,
     prepare_network_params,
     run_network,
+    run_network_per_launch,
 )
 from repro.obs.report import (
     drift_report,
@@ -33,7 +37,17 @@ from repro.obs.report import (
     drift_rows_from_spans,
 )
 from repro.obs.timeline import chrome_trace, validate_chrome_trace
-from repro.obs.trace import NULL_TRACER, get_tracer, tracing
+from repro.obs.trace import (
+    DEFAULT_TRACER,
+    EVENT_CAPACITY,
+    NULL_TRACER,
+    SPAN_CAPACITY,
+    TraceCollector,
+    get_tracer,
+    set_tracer,
+    span_code,
+    tracing,
+)
 
 from test_pyramid_kernel import _expected_skip_maps
 
@@ -47,8 +61,8 @@ KEY = jax.random.PRNGKey(0)
 
 
 def _traced_lenet(batch=2, reps=1, bias_shift=0.0, sparse=False):
-    """One traced LeNet forward (plus optional extra reps) returning
-    (collector, plan, skips, raw_params, x)."""
+    """One launch-by-launch timed LeNet forward (plus optional extra reps)
+    returning (collector, plan, skips, raw_params, x)."""
     import jax.numpy as jnp
 
     graph = lenet5()
@@ -66,55 +80,167 @@ def _traced_lenet(batch=2, reps=1, bias_shift=0.0, sparse=False):
         )
     plan = auto_partition(graph, batch=batch)
     params = prepare_network_params(plan, raw)
-    with tracing() as collector:
-        for _ in range(reps):
-            _, skips = run_network(x, params, plan=plan)
+    collector = TraceCollector()
+    for _ in range(reps):
+        _, skips = run_network_per_launch(
+            x, params, plan=plan, collector=collector
+        )
     return collector, plan, skips, raw, x
+
+
+def _lenet_inputs(batch):
+    graph = lenet5()
+    raw = init_network_params(graph, KEY)
+    plan = auto_partition(graph, batch=batch)
+    params = prepare_network_params(plan, raw)
+    x = jax.random.normal(jax.random.PRNGKey(1), (batch, 32, 32, 1))
+    return x, params, plan
 
 
 class TestTracerDispatch:
     def test_default_tracer_is_noop(self):
+        """The default tracer is the always-on bounded recorder; recording
+        is turned off by installing NULL_TRACER, which keeps nothing."""
         t = get_tracer()
-        assert t is NULL_TRACER and not t.enabled
+        assert t is DEFAULT_TRACER and t.enabled
+        assert t.capacity == SPAN_CAPACITY
+        assert t.events.maxlen == EVENT_CAPACITY
+        set_tracer(NULL_TRACER)
+        try:
+            off = get_tracer()
+            assert not off.enabled
+            assert off.span(span_code("engine.stage"), 1.0, 2.0, 7) == -1
+            assert off.spans_between() == [] and not off.holds(0.0)
+        finally:
+            set_tracer(None)
+        assert get_tracer() is DEFAULT_TRACER
 
     def test_disabled_tracing_uses_unchanged_jit_path(self, monkeypatch):
-        """With the no-op tracer the public run_network must hit the jit
-        fast path without even touching the traced implementation — the
-        dispatch check is the *only* tracing cost when disabled."""
+        """With recording off, on (the default) or scoped by tracing(), the
+        public run_network hits the jit fast path without touching the
+        launch-by-launch timed implementation."""
 
         def boom(*a, **k):
-            raise AssertionError("traced path must not run")
+            raise AssertionError("per-launch path must not run")
 
-        monkeypatch.setattr(runner, "_run_network_traced", boom)
-        graph = lenet5()
-        raw = init_network_params(graph, KEY)
-        plan = auto_partition(graph, batch=1)
-        params = prepare_network_params(plan, raw)
-        x = jax.random.normal(jax.random.PRNGKey(1), (1, 32, 32, 1))
+        monkeypatch.setattr(runner, "run_network_per_launch", boom)
+        x, params, plan = _lenet_inputs(1)
         logits, _ = run_network(x, params, plan=plan)
+        assert logits.shape == (1, 10)
+        with tracing():
+            logits, _ = run_network(x, params, plan=plan)
+        set_tracer(NULL_TRACER)
+        try:
+            logits, _ = run_network(x, params, plan=plan)
+        finally:
+            set_tracer(None)
         assert logits.shape == (1, 10)
 
     def test_traced_path_matches_jit_path(self):
-        """Tracing changes scheduling (eager launch-by-launch), never
-        numerics: same logits either way."""
-        graph = lenet5()
-        raw = init_network_params(graph, KEY)
-        plan = auto_partition(graph, batch=2)
-        params = prepare_network_params(plan, raw)
-        x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32, 1))
+        """The launch-by-launch timed forward changes scheduling, never
+        numerics: same logits as the jit forward."""
+        x, params, plan = _lenet_inputs(2)
         fast, _ = run_network(x, params, plan=plan)
-        with tracing():
-            traced, _ = run_network(x, params, plan=plan)
-        np.testing.assert_allclose(
-            np.asarray(fast), np.asarray(traced), atol=1e-6
+        timed, _ = run_network_per_launch(
+            x, params, plan=plan, collector=TraceCollector()
         )
+        np.testing.assert_allclose(
+            np.asarray(fast), np.asarray(timed), atol=1e-6
+        )
+
+    def test_tracing_keeps_the_jit_forward(self):
+        """A tracer installed with tracing() leaves run_network on the jit
+        path: no new trace of the compiled forward, identical logits, and
+        no launch spans recorded."""
+        x, params, plan = _lenet_inputs(2)
+        fast, _ = run_network(x, params, plan=plan)
+        traces = jit_trace_count()
+        with tracing() as col:
+            traced, _ = run_network(x, params, plan=plan)
+        assert jit_trace_count() == traces
+        assert col.spans == []
+        np.testing.assert_array_equal(np.asarray(fast), np.asarray(traced))
 
     def test_tracing_context_restores_previous(self):
         with tracing() as outer:
             with tracing() as inner:
                 assert get_tracer() is inner
             assert get_tracer() is outer
-        assert get_tracer() is NULL_TRACER
+        assert get_tracer() is DEFAULT_TRACER
+
+
+class TestHostSpanRing:
+    CODE = span_code("test.span")
+
+    def test_spans_read_back_in_start_order(self):
+        col = TraceCollector(capacity=8)
+        route = span_code("fused")
+        col.span(self.CODE, 2.0, 3.0, 5, 9, bucket=8, rows=7, route=route)
+        col.span(self.CODE, 1.0, 4.0, 6)
+        spans = col.spans_between()
+        assert [s.start_s for s in spans] == [1.0, 2.0]
+        a = spans[1]
+        assert (a.name, a.end_s, a.id, a.parent) == ("test.span", 3.0, 5, 9)
+        assert (a.bucket, a.rows, a.route) == (8, 7, "fused")
+        assert spans[0].parent == -1 and spans[0].route == ""
+        assert a.thread == threading.get_ident()
+        assert tuple(a[:3]) == ("test.span", 2.0, 3.0)
+        assert [s.id for s in col.spans_between(1.5, 3.0)] == [5]
+
+    def test_link_sets_parent_only_while_the_slot_holds_the_id(self):
+        col = TraceCollector(capacity=2)
+        slot = col.span(self.CODE, 1.0, 2.0, 11)
+        col.link(slot, 11, 42)
+        assert col.spans_between()[0].parent == 42
+        col.span(self.CODE, 3.0, 4.0, 12)
+        col.span(self.CODE, 5.0, 6.0, 13)  # overwrites the slot of id 11
+        col.link(slot, 11, 99)
+        assert [s.parent for s in col.spans_between()] == [-1, -1]
+
+    def test_ring_wraps_at_capacity(self):
+        """The ring keeps the newest ``capacity`` spans in fixed memory,
+        and reports that it no longer holds a start time that was lost."""
+        col = TraceCollector(capacity=16)
+        before = {k: (c.nbytes, c.ctypes.data) for k, c in col._cols.items()}
+        for i in range(16):
+            col.span(self.CODE, float(i), i + 0.5, i)
+        assert col.holds(0.0)  # full but never wrapped
+        for i in range(16, 100):
+            col.span(self.CODE, float(i), i + 0.5, i)
+        kept = col.spans_between()
+        assert len(kept) <= 16 and kept[-1].id == 99
+        assert [s.id for s in kept] == list(range(kept[0].id, 100))
+        assert not col.holds(0.0)
+        assert col.holds(float(kept[0].start_s) + 1.0)
+        after = {k: (c.nbytes, c.ctypes.data) for k, c in col._cols.items()}
+        assert after == before
+
+    def test_capacity_must_be_a_power_of_two(self):
+        with pytest.raises(ValueError):
+            TraceCollector(capacity=12)
+
+    def test_concurrent_writers_lose_no_span(self):
+        """Slots come from one itertools.count: threads writing at once
+        never share a slot."""
+        col = TraceCollector(capacity=1 << 12)
+        switch = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            def write(base):
+                for i in range(500):
+                    col.span(self.CODE, float(i), i + 1.0, base + i)
+
+            threads = [threading.Thread(target=write, args=(k * 1000,))
+                       for k in range(6)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=30)
+            assert not any(t.is_alive() for t in threads)
+        finally:
+            sys.setswitchinterval(switch)
+        ids = sorted(s.id for s in col.spans_between())
+        assert ids == sorted(k * 1000 + i for k in range(6) for i in range(500))
 
 
 class TestTracedSpans:
@@ -169,8 +295,10 @@ class TestEndSkipEvents:
         assert pyr.launch.out_region == 1
         plan = dataclasses.replace(plan, pyramids=(pyr,))
         params = prepare_network_params(plan, raw)
-        with tracing() as collector:
-            _, skips = run_network(x, params, plan=plan)
+        collector = TraceCollector()
+        _, skips = run_network_per_launch(
+            x, params, plan=plan, collector=collector
+        )
         conv_names = [
             m for m in pyr.node_names if plan.graph.node(m).op == "conv"
         ]
@@ -255,6 +383,33 @@ class TestChromeTrace:
         cats = {e.get("cat") for e in trace["traceEvents"]}
         assert {"modeled", "measured", "event"} <= cats
         path = tmp_path / "trace.json"
+        write_chrome_trace(str(path), trace)
+        assert validate_chrome_trace(json.loads(path.read_text())) == []
+
+    def test_host_spans_render_one_track_per_thread(self, tmp_path):
+        """A live recorder's host spans export as Perfetto tracks, one per
+        thread, named after the thread."""
+        from repro.obs.timeline import HOST_PID, write_chrome_trace
+
+        col = TraceCollector(capacity=64)
+        code = span_code("engine.stage")
+        col.span(code, 1.0, 1.5, 3, bucket=8, rows=8)
+        worker = threading.Thread(
+            target=lambda: col.span(code, 1.2, 1.4, 4), name="serve-drain"
+        )
+        worker.start()
+        worker.join(timeout=10)
+        trace = chrome_trace(col)
+        assert validate_chrome_trace(trace) == []
+        host = [e for e in trace["traceEvents"] if e.get("cat") == "host"]
+        assert [e["args"]["id"] for e in host] == [3, 4]
+        assert host[0]["ts"] == 0.0 and host[0]["dur"] == pytest.approx(5e5)
+        assert {e["pid"] for e in host} == {HOST_PID}
+        assert host[0]["tid"] != host[1]["tid"]
+        names = {e["args"]["name"] for e in trace["traceEvents"]
+                 if e["ph"] == "M" and e["name"] == "thread_name"}
+        assert threading.current_thread().name in names
+        path = tmp_path / "live.json"
         write_chrome_trace(str(path), trace)
         assert validate_chrome_trace(json.loads(path.read_text())) == []
 

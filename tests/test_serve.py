@@ -1,8 +1,10 @@
 """Serving engine (DESIGN.md §14): pad-to-bucket bitwise parity, FIFO
 admission/fairness, plan+jit cache accounting (hits/misses/evictions and
 zero replans/retraces on a repeated wave), typed admission rejections that
-never stall the queue, the batch-aware costing knobs, and the host-staging
-serving cost model."""
+never stall the queue, the host spans the engine and frontend record, the
+batch-aware costing knobs, and the host-staging serving cost model."""
+
+import time
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +37,8 @@ from repro.net.serve import (
     bucket_for,
     pad_to_bucket,
 )
+from repro.net.frontend import ServingFrontend
+from repro.obs.trace import tracing
 from repro.robust.errors import NumericError, PreflightError
 
 KEY = jax.random.PRNGKey(0)
@@ -383,6 +387,74 @@ class TestSummary:
         np.testing.assert_allclose(
             res[0].logits, ref[0].logits, atol=1e-4
         )
+
+
+# ---------------------------------------------------------------------------
+# host spans
+# ---------------------------------------------------------------------------
+
+BATCH_SPANS = ("engine.stage", "engine.dispatch", "engine.block",
+               "engine.record")
+
+
+class TestHostSpans:
+    def test_frontend_run_spans_every_batch_and_request(self):
+        """Per batch one each of stage/dispatch/block/record under one
+        batch id, whose requests are the admit spans linked to it; per
+        request one engine.admit and one frontend.deliver with its id."""
+        eng = _engine(buckets=(4,))
+        eng.serve([_images(1, seed=0)])  # compiles outside the collection
+        with tracing() as col:
+            with ServingFrontend(eng) as fe:
+                handles = [fe.submit(_images(1, seed=s)) for s in range(10)]
+                results = [h.result(timeout=60.0) for h in handles]
+        assert all(r.ok for r in results)
+        spans = col.spans_between()
+        by_name: dict = {}
+        for sp in spans:
+            by_name.setdefault(sp.name, []).append(sp)
+        ids = sorted(h.id for h in handles)
+        for name in ("engine.admit", "frontend.deliver"):
+            assert sorted(sp.id for sp in by_name[name]) == ids, name
+        batches = sorted({sp.id for sp in by_name["engine.stage"]})
+        for name in BATCH_SPANS:
+            assert sorted(sp.id for sp in by_name[name]) == batches, name
+        assert {sp.route for sp in by_name["engine.dispatch"]} == {"fused"}
+        members: dict = {}
+        for sp in by_name["engine.admit"]:
+            members.setdefault(sp.parent, []).append(sp.id)
+        assert sorted(members) == batches
+        for st in by_name["engine.stage"]:
+            assert st.rows == len(members[st.id]) and st.bucket == 4
+            # a request's queue wait ends where its batch's stage starts
+            admits = [a for a in by_name["engine.admit"] if a.parent == st.id]
+            assert all(a.end_s <= st.start_s for a in admits)
+        for sp in spans:
+            assert sp.end_s >= sp.start_s
+        deliver = {sp.id: sp for sp in by_name["frontend.deliver"]}
+        record = {sp.id: sp for sp in by_name["engine.record"]}
+        admit = {sp.id: sp for sp in by_name["engine.admit"]}
+        for rid in ids:
+            # the hand-back starts inside its batch's record span
+            rec = record[admit[rid].parent]
+            assert rec.start_s <= deliver[rid].start_s <= rec.end_s
+        assert "frontend.idle" in by_name
+
+    def test_rejected_request_has_an_admit_span_and_no_batch(self):
+        eng = _engine()
+        with tracing() as col:
+            rid = eng.submit(np.zeros((1, 8, 8, 1), np.float32))
+        (sp,) = col.spans_between()
+        assert (sp.name, sp.id, sp.parent) == ("engine.admit", rid, -1)
+
+    def test_spans_go_to_the_default_recorder(self):
+        from repro.obs.trace import get_tracer
+
+        eng = _engine()
+        t0 = time.perf_counter()
+        eng.serve([_images(2, seed=4)])
+        names = {sp.name for sp in get_tracer().spans_between(t0)}
+        assert {"engine.admit", *BATCH_SPANS} <= names
 
 
 # ---------------------------------------------------------------------------

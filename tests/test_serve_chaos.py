@@ -384,7 +384,8 @@ class TestRepeatedKernelFailure:
         ]
         assert len(opens) == 1 and opens[0].args["bucket"] == 4
         # the third batch rode the pinned rung, not another fused attempt
-        routes = [e.args["route"] for e in _events(col, "serve_batch")]
+        routes = [s.route for s in col.spans_between()
+                  if s.name == "engine.dispatch"]
         assert routes[-1] == "interpret"
         np.testing.assert_allclose(r3[0].logits, ref, atol=1e-4)
 
@@ -415,7 +416,8 @@ class TestPoisonedOutput:
         with tracing() as col2:
             res2 = eng.serve([x.copy()])
         assert res2[0].ok
-        routes = [e.args["route"] for e in _events(col2, "serve_batch")]
+        routes = [s.route for s in col2.spans_between()
+                  if s.name == "engine.dispatch"]
         assert routes == ["reference"]
         np.testing.assert_allclose(res2[0].logits, ref, atol=1e-4)
 

@@ -11,7 +11,8 @@ fails here, at no chip time:
   published input sizes, buckets 1 and 8, each compiled with the VMEM
   limit its plan's budget sets (budget plus Mosaic's headroom);
 * the whole ResNet-18 224x224 forward, whose HLO must hold one
-  ``tpu_custom_call`` per planned pyramid (no launch left to interpret mode).
+  ``tpu_custom_call`` per planned pyramid (no launch left to interpret mode),
+  each named after its pyramid (the name a profiler trace shows).
 
 The topology is described inside a fixture, never at import: only one process
 may load the TPU library, and the workers of a multi-process test run import
@@ -21,6 +22,7 @@ every test file.  Keep these tests in this one file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -123,23 +125,45 @@ def test_zoo_launch_kinds_compile(model, dtype, one_chip, no_compile_cache):
         assert text.count(CUSTOM_CALL) == 1, (model, pyr.name, kind)
 
 
-def test_resnet18_forward_is_all_kernels(one_chip, no_compile_cache):
+def _compile_resnet18(dtype, sharding):
     graph = MODELS["resnet18"]()
-    plan = auto_partition(graph, batch=1)
+    plan = auto_partition(graph, batch=1, compute_dtype=dtype)
     params = jax.eval_shape(
         lambda: prepare_network_params(
             plan, init_network_params(graph, jax.random.PRNGKey(0))
         )
     )
     params = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
         params,
     )
     x = jax.ShapeDtypeStruct(
         (1, graph.input_size, graph.input_size, graph.in_channels),
-        jnp.float32, sharding=one_chip,
+        jnp.float32, sharding=sharding,
     )
     text = _run_network_jit.lower(
         x, params, plan=plan, interpret=False
     ).compile().as_text()
+    return plan, text
+
+
+def test_resnet18_forward_is_all_kernels(one_chip, no_compile_cache):
+    plan, text = _compile_resnet18("float32", one_chip)
     assert text.count(CUSTOM_CALL) == len(plan.pyramids)
+
+
+def test_kernels_are_named_by_pyramid(one_chip, no_compile_cache):
+    """Every kernel's HLO instruction name begins with its pyramid's plan
+    name, so a device trace names it ``conv1..maxpool.<n>`` and not
+    ``fused_pyramid.<n>``, whatever else changes in the graph."""
+    plan, text = _compile_resnet18("bfloat16", one_chip)
+    names = [
+        m.group(1) for line in text.splitlines() if CUSTOM_CALL in line
+        for m in [re.match(r"\s*(?:ROOT\s+)?%?([^\s=]+)\s*=", line)] if m
+    ]
+    assert len(names) == len(plan.pyramids)
+    pyramids = sorted((p.name for p in plan.pyramids), key=len, reverse=True)
+    owners = [next((p for p in pyramids if n.startswith(p)), None)
+              for n in names]
+    assert None not in owners, names
+    assert sorted(owners) == sorted(p.name for p in plan.pyramids), names
