@@ -19,6 +19,9 @@ the variadic Pallas kernel.
 * **Pool epilogues** — each pool level is folded into the preceding conv
   level's program (the paper's Fig. 4 pooling block is slaved to the conv
   tile; see DESIGN.md §3).
+* **Patch form of level 0** — a narrow-input first conv (the image's 3
+  channels) runs as a 1x1 conv over its patch tensor, built by XLA ahead
+  of the kernel (:func:`patch_spec`, DESIGN.md §8).
 * **VMEM-budget accounting** — :meth:`TileProgram.vmem_bytes` models the
   kernel's resident working set; :func:`pick_out_region` scans output regions
   against the budget and :meth:`TileProgram.hbm_bytes` models the per-launch
@@ -52,6 +55,14 @@ LANES = 128
 # HBM arrays are tiled the same way, so a DMA window over the sublane (W) dim
 # must start and end on a multiple of 8.
 DMA_ALIGN = 8
+
+# Level 0 runs in patch form when its patch (patch_lanes) fits this many
+# lanes: one MXU dot per output row contracting the whole patch replaces K*K
+# dots that each contract Cin lanes.  The image's HBM copy is lane-padded to
+# 128 anyway, so up to 128 patch lanes move no more bytes than the padded
+# image; four lane blocks take every image-input conv (AlexNet's 11x11
+# stride-4 conv1: 432) and no activation-input 3x3 conv (64 channels: 576).
+PATCH_MAX_LANES = 4 * LANES
 
 
 def channel_blocks(c: int) -> tuple[int, int]:
@@ -146,6 +157,37 @@ class WindowProgram:
         return [w.at(start) for w in self.windows]
 
 
+def patch_lanes(level) -> int:
+    """Input channels of conv ``level`` in patch form.  A stride-``S``
+    level is split into its ``S*S`` phases first (space to depth), so that
+    every tap is a unit-stride slice: the patch holds the
+    ``ceil(K/S)**2`` taps of the phase image's ``S*S*Cin`` channels, and
+    taps past ``K`` carry zero weights.  ``K*K*Cin`` at stride 1."""
+    kq = -(-level.K // level.S)
+    return kq * kq * level.S * level.S * level.n_in
+
+
+def patch_spec(spec: FusionSpec) -> FusionSpec:
+    """The spec the kernel runs: ``spec`` itself, or, when level 0 is a
+    ``K > 1`` conv whose patch (:func:`patch_lanes`) fits
+    :data:`PATCH_MAX_LANES`, ``spec`` with level 0 in **patch form** — a
+    1x1, stride-1, pad-0 conv over the patch tensor, an input of level 0's
+    output size (``kernels/fused_conv/ops.patch_tensor``).  Later levels,
+    pools included, are unchanged.  Reads only shapes."""
+    first = spec.levels[0]
+    if not (
+        first.kind == "conv"
+        and first.K > 1
+        and patch_lanes(first) <= PATCH_MAX_LANES
+    ):
+        return spec
+    patch = replace(first, K=1, S=1, pad=0, n_in=patch_lanes(first))
+    return FusionSpec(
+        levels=(patch, *spec.levels[1:]),
+        input_size=first.out_size(spec.input_size),
+    )
+
+
 def chain_channels(spec: FusionSpec) -> int:
     """Channel count leaving the chain (pools are channel-preserving)."""
     c = spec.levels[0].n_in
@@ -214,7 +256,10 @@ class TileProgram:
 
     ``levels`` holds one :class:`ConvLevelProg` per conv level (any Q >= 1),
     pools folded in.  ``tile0``/``stride0`` cut level-0 tiles out of the
-    pre-padded input; the grid is ``(batch, alpha, alpha)``.
+    pre-padded input; the grid is ``(batch, alpha, alpha)``.  ``spec`` is
+    the spec the caller compiled; with ``patch`` set, every geometry and
+    byte figure is that of :attr:`kernel_spec`, its patch form, whose input
+    is the patch tensor (:func:`patch_spec`).
     """
 
     spec: FusionSpec
@@ -231,6 +276,13 @@ class TileProgram:
     # (a string keeps the program hashable for jit); mid-level dot products
     # always accumulate float32 regardless — see DESIGN.md §11
     compute_dtype: str = "float32"
+    patch: bool = False
+
+    @property
+    def kernel_spec(self) -> FusionSpec:
+        """The spec the kernel runs: :attr:`spec`, level 0 in patch form
+        when :attr:`patch`."""
+        return patch_spec(self.spec) if self.patch else self.spec
 
     @property
     def q_convs(self) -> int:
@@ -244,7 +296,7 @@ class TileProgram:
 
     @property
     def padded_input(self) -> int:
-        return self.pad_lo + self.spec.input_size + self.pad_hi
+        return self.pad_lo + self.kernel_spec.input_size + self.pad_hi
 
     def weight_floats(self) -> int:
         return sum(p.K * p.K * p.n_in * p.n_out + p.n_out for p in self.levels)
@@ -522,6 +574,8 @@ def compile_program(
     conv program to fold into.  ``compute_dtype`` (name string or jnp dtype)
     sets the byte width of every activation/weight the program accounts —
     window math is dtype-invariant, the byte and cycle models are not.
+    The program is built from :func:`patch_spec` of ``spec``, so a
+    narrow-input level 0 is priced and launched in patch form alike.
     """
     from repro.robust.errors import PlanError
 
@@ -537,6 +591,9 @@ def compile_program(
                 "each pool level must directly follow a conv level",
                 level=l, node=lvl.name,
             )
+    source = spec
+    spec = patch_spec(spec)
+    levels = spec.levels
     sizes = spec.feature_sizes()
     out_size = sizes[-1]
     if out_size % out_region != 0:
@@ -593,7 +650,7 @@ def compile_program(
     last_end = lo0 + (alpha - 1) * stride0 + tile0
     pad_hi = max(0, last_end - spec.input_size)
     return TileProgram(
-        spec=spec,
+        spec=source,
         out_region=out_region,
         alpha=alpha,
         levels=tuple(progs),
@@ -604,6 +661,7 @@ def compile_program(
         out_size=out_size,
         n_out=chain_channels(spec),
         compute_dtype=canonical_dtype(compute_dtype),
+        patch=spec is not source,
     )
 
 
@@ -759,12 +817,13 @@ class LaunchPlan:
 
         bpv = self.program.bytes_per_val
         cdt = self.program.compute_dtype
-        compute = mxu_scaled_cycles(ds1_cycles_per_movement(self.spec), cdt)
+        spec = self.program.kernel_spec
+        compute = mxu_scaled_cycles(ds1_cycles_per_movement(spec), cdt)
         if not self.streamed:
             return compute, None
         cnts = self.program.level_weight_counts()
         if self.c_tiles > 1:
-            compute_mid, compute_last = ds1_split_cycles_per_movement(self.spec)
+            compute_mid, compute_last = ds1_split_cycles_per_movement(spec)
             return compute, {
                 "kind": "channel_tiled",
                 "compute_mid": mxu_scaled_cycles(compute_mid, cdt),
@@ -837,14 +896,16 @@ class LaunchPlan:
         """The launch as one observability row: every plan knob plus the
         modeled byte/cycle quantities the planner optimized, in one flat
         JSON-safe dict (the span schema of DESIGN.md §12 and the row format
-        of ``repro.obs.explain``).  ``vmem_budget`` adds the headroom column
-        (budget minus modeled working set)."""
+        of ``repro.obs.explain``).  ``patch`` says whether level 0 runs in
+        patch form (:func:`patch_spec`).  ``vmem_budget`` adds the headroom
+        column (budget minus modeled working set)."""
         prog = self.program
         row = {
             "q_convs": prog.q_convs,
             "out_region": self.out_region,
             "alpha": prog.alpha,
             "regime": self.regime,
+            "patch": prog.patch,
             "streamed": self.streamed,
             "x_slots": self.x_slots,
             "w_slots": self.w_slots,

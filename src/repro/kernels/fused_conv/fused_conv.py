@@ -40,7 +40,9 @@ Per grid cell (b, i, j):
   * conv levels run one output row at a time from VMEM refs: K*K
     ``(W, Cin) @ (Cin, Cout)`` MXU dots per row, f32-accumulated, each
     reading its input window with a (possibly strided) ref read — the WPU
-    array of Fig. 5 maps onto MXU tiles.  Tiles are channel-blocked
+    array of Fig. 5 maps onto MXU tiles (a narrow-input level 0 arrives in
+    patch form, :func:`~repro.core.program.patch_spec`: K = 1 over its patch
+    tensor, one dot per row).  Tiles are channel-blocked
     ``(blocks, H, W, lanes)`` (:func:`~repro.core.program.channel_blocks`);
     strided and per-cell-offset reads go through an f32 row stage, the only
     form Mosaic reads them from;

@@ -1,8 +1,9 @@
 """Public wrappers for the variadic fused conv-pyramid Pallas kernel.
 
 All window/offset math comes from the tile-program compiler
-(:mod:`repro.core.program`); this module only pads inputs, checks the VMEM
-budget, and launches:
+(:mod:`repro.core.program`); this module only pads inputs (or builds the
+patch tensor of a patch-form level 0), checks the VMEM budget, and
+launches:
 
 * :func:`fused_pyramid` — any Q >= 1 conv levels (odd Q and conv-only pairs
   included) as **one** kernel launch; LeNet's Q=2, VGG blocks 1-2's Q=4, and
@@ -25,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.dtypes import canonical_dtype, jnp_dtype
-from repro.core.fusion import FusionSpec
+from repro.core.fusion import FusedLevel, FusionSpec
 from repro.core.program import (
     MOSAIC_HEADROOM_BYTES,
     VMEM_BUDGET_BYTES,
@@ -87,6 +88,10 @@ def fused_pyramid(
     pyramid name (``conv1..maxpool``), which becomes the compiled custom
     call's HLO instruction name and so the kernel's name in a profiler
     trace.
+    A level 0 that :func:`~repro.core.program.patch_spec` puts in patch
+    form runs as a 1x1 conv over :func:`patch_tensor` of ``x`` with
+    :func:`patch_weights`; the caller passes the same ``x`` and
+    ``(K, K, Cin, Cout)`` weights either way.
     Returns ``(out, skip)`` with ``skip``: (B, alpha, alpha, Q) int32
     END-cascade flags (level 0 never skips, and skip flags are
     dtype-invariant).
@@ -160,13 +165,18 @@ def fused_pyramid(
             + " chunk via fused_pyramid_chain",
             vmem_bytes=vmem, vmem_budget=vmem_budget,
         )
+    x = x.astype(cdt)
+    weights = [w.astype(cdt) for w in weights]
+    if prog.patch:
+        x = patch_tensor(x, spec.levels[0])
+        weights[0] = patch_weights(weights[0], spec.levels[0])
     xp = jnp.pad(
-        x.astype(cdt),
+        x,
         ((0, 0), (prog.pad_lo, prog.pad_hi), (prog.pad_lo, prog.pad_hi), (0, 0)),
     )
     return fused_pyramid_pallas(
         xp,
-        [w.astype(cdt) for w in weights],
+        weights,
         [b.astype(cdt) for b in biases],
         program=prog,
         relu=relu,
@@ -178,6 +188,55 @@ def fused_pyramid(
         c_tiles=c_tiles,
         vmem_limit_bytes=vmem_budget + MOSAIC_HEADROOM_BYTES,
         name=name,
+    )
+
+
+def patch_tensor(x: jnp.ndarray, level: FusedLevel) -> jnp.ndarray:
+    """Conv ``level``'s patch tensor of ``x``: ``(B, M, M, patch_lanes)``
+    for its ``M x M`` output, the input of the level's patch form
+    (:func:`~repro.core.program.patch_spec`).  The padded input is split
+    into its ``S*S`` stride phases (space to depth: a reshape and a
+    transpose, channels in ``(a, b, c)`` order); the ``ceil(K/S)**2``
+    taps are unit-stride slices of that phase image, stacked on channels
+    in ``(di, dj)`` order.  At stride 1 the phase image is the padded input
+    and the channels run ``(ki, kj, c)``.  XLA on a TPU lowers a strided
+    slice to a gather, and concatenates narrow pieces one lane-padded copy
+    each; this build has neither, and no convolution."""
+    K, S, p = level.K, level.S, level.pad
+    b, n, c = x.shape[0], x.shape[1], x.shape[3]
+    m = level.out_size(n)
+    kq = -(-K // S)
+    side = S * (m - 1 + kq)  # the rows the taps read; the rest is unread
+    hi = max(0, side - n - p)
+    xp = jnp.pad(x, ((0, 0), (p, hi), (p, hi), (0, 0)))[:, :side, :side]
+    q = side // S
+    phases = (
+        xp.reshape(b, q, S, q, S, c)
+        .transpose(0, 1, 3, 2, 4, 5)
+        .reshape(b, q, q, S * S * c)
+    )
+    taps = [
+        phases[:, di : di + m, dj : dj + m, :]
+        for di in range(kq)
+        for dj in range(kq)
+    ]
+    return jnp.stack(taps, axis=3).reshape(b, m, m, -1)
+
+
+def patch_weights(w: jnp.ndarray, level: FusedLevel) -> jnp.ndarray:
+    """Conv ``level``'s ``(K, K, Cin, Cout)`` weights as the 1x1 weights of
+    its patch form, ``(1, 1, patch_lanes, Cout)``, rows in the order of
+    :func:`patch_tensor`'s channels: the taps are zero-padded to
+    ``S * ceil(K/S)`` a side and each tap index ``k`` split into
+    ``(k // S, k % S)``.  A reshape alone at stride 1."""
+    K, S = level.K, level.S
+    kq = -(-K // S)
+    cin, cout = w.shape[2], w.shape[3]
+    w = jnp.pad(w, ((0, S * kq - K), (0, S * kq - K), (0, 0), (0, 0)))
+    return (
+        w.reshape(kq, S, kq, S, cin, cout)
+        .transpose(0, 2, 1, 3, 4, 5)
+        .reshape(1, 1, -1, cout)
     )
 
 

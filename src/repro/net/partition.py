@@ -45,6 +45,9 @@ from .graph import Graph, Segment, fusable_segments, infer_shapes
 
 INFEASIBLE = (float("inf"), float("inf"))
 
+# tracer counter: pyramids planned with level 0 in patch form
+PATCH_LEVELS = "fused.patch_levels"
+
 
 @dataclass(frozen=True)
 class PyramidPlan:
@@ -265,9 +268,14 @@ def brute_force_segment(
 def _segment_pyramids(
     segment: Segment, launches: list[LaunchPlan]
 ) -> list[PyramidPlan]:
-    """Attach covered node names to each launch, walking the chain."""
+    """Attach covered node names to each launch, walking the chain.  Each
+    pyramid whose level 0 runs in patch form bumps the tracer's
+    :data:`PATCH_LEVELS` counter once, as its plan is built."""
     out, li = [], 0
+    tracer = get_tracer()
     for lp in launches:
+        if lp.program.patch:
+            tracer.bump(PATCH_LEVELS)
         n_levels = len(lp.spec.levels)
         names = tuple(n.name for n in segment.nodes[li : li + n_levels])
         out.append(PyramidPlan(launch=lp, node_names=names, relu=segment.relu))

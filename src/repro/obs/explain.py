@@ -1,8 +1,9 @@
 """``python -m repro.obs.explain`` — show what the planner decided and why.
 
 Prints the auto-partition of a zoo model as a per-launch table (covered
-nodes, Q, grid, regime, plan knobs, modeled HBM/VMEM bytes with budget
-headroom, modeled cycles), and optionally:
+nodes, Q, grid, level-0 form, regime, plan knobs, modeled HBM/VMEM bytes
+with budget headroom, modeled cycles) and the pyramids whose level 0 runs
+in patch form, and optionally:
 
 * ``--trace out.json`` — export a Chrome-trace / Perfetto JSON of every
   launch's modeled fill/steady/drain DMA-vs-MXU timeline
@@ -53,7 +54,7 @@ def plan_table(plan, vmem_budget: int, out=print) -> None:
     trace's span schema)."""
     out(
         f"{'launch':<26} {'nodes':>5} {'Q':>2} {'grid':>6} {'region':>6} "
-        f"{'regime':<16} {'x/w/c':>6} {'hbm':>9} {'vmem':>9} "
+        f"{'L0':<6} {'regime':<16} {'x/w/c':>6} {'hbm':>9} {'vmem':>9} "
         f"{'headroom':>9} {'cycles':>10} {'us':>9}"
     )
     for p in plan.pyramids:
@@ -61,7 +62,7 @@ def plan_table(plan, vmem_budget: int, out=print) -> None:
         out(
             f"{p.name:<26} {len(p.node_names):>5} {d['q_convs']:>2} "
             f"{d['alpha']}x{d['alpha']:<4} {d['out_region']:>6} "
-            f"{d['regime']:<16} "
+            f"{'patch' if d['patch'] else 'direct':<6} {d['regime']:<16} "
             f"{d['x_slots']}/{d['w_slots']}/{d['c_tiles']:<2} "
             f"{_fmt_bytes(d['hbm_bytes']):>9} "
             f"{_fmt_bytes(d['vmem_bytes']):>9} "
@@ -179,7 +180,12 @@ def fallback_table(report, out=print) -> None:
 def main(argv: list[str] | None = None) -> int:
     from repro.core.program import VMEM_BUDGET_BYTES
     from repro.net.graph import MODELS
-    from repro.net.partition import auto_partition, partition_cache_info
+    from repro.net.partition import (
+        PATCH_LEVELS,
+        auto_partition,
+        partition_cache_info,
+    )
+    from repro.obs.trace import get_tracer
 
     ap = argparse.ArgumentParser(
         description=__doc__,
@@ -218,15 +224,23 @@ def main(argv: list[str] | None = None) -> int:
         kwargs["input_size"] = size
     graph = MODELS[args.model](**kwargs)
 
+    tracer = get_tracer()
+    built = tracer.counters.get(PATCH_LEVELS, 0)
     plan = auto_partition(
         graph, batch=args.batch, vmem_budget=args.vmem_budget
     )
+    built = tracer.counters.get(PATCH_LEVELS, 0) - built
     print(
         f"{graph.name}: input {graph.input_size}x{graph.input_size}, "
         f"batch {args.batch}, dtype {plan.compute_dtype}, "
         f"VMEM budget {_fmt_bytes(args.vmem_budget)}"
     )
     plan_table(plan, args.vmem_budget)
+    patch = [p.name for p in plan.pyramids if p.launch.program.patch]
+    print(
+        f"level 0 in patch form: {', '.join(patch) or 'none'} "
+        f"({PATCH_LEVELS} +{built} for this plan's build)"
+    )
     info = partition_cache_info()
     print(
         f"partition cache: {info.hits} hits / {info.misses} misses "

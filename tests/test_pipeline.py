@@ -254,18 +254,29 @@ class TestPipelineCycleModel:
         under a budget where resident+x2 busts but streamed+x2 fits, the
         launch must stream instead of dying on the VMEM assert."""
         region = 4
-        prog = compile_program(Q3_CHAIN, region)
+        # level 0 in direct form (9 x 64 input lanes > PATCH_MAX_LANES): its
+        # weights set the streamed ring slot, so streaming frees more VMEM
+        # than it takes, as the regime ladder assumes
+        spec = dataclasses.replace(
+            Q3_CHAIN,
+            levels=(
+                dataclasses.replace(Q3_CHAIN.levels[0], n_in=64),
+                *Q3_CHAIN.levels[1:],
+            ),
+        )
+        prog = compile_program(spec, region)
+        assert not prog.patch
         budget = prog.vmem_bytes(2) - 4
         assert prog.vmem_bytes(1) <= budget  # x1 accounting says resident
         assert prog.vmem_stream_bytes(1, 2) <= budget  # streamed+x2 fits
-        p = init_pyramid_params(Q3_CHAIN, KEY)
-        x = _inputs(Q3_CHAIN)
+        p = init_pyramid_params(spec, KEY)
+        x = _inputs(spec)
         y, s = fused_pyramid(
-            x, p.weights, p.biases, spec=Q3_CHAIN, out_region=region,
+            x, p.weights, p.biases, spec=spec, out_region=region,
             x_slots=2, vmem_budget=budget,
         )
         y_ref, s_ref = fused_pyramid(
-            x, p.weights, p.biases, spec=Q3_CHAIN, out_region=region,
+            x, p.weights, p.biases, spec=spec, out_region=region,
         )
         np.testing.assert_array_equal(np.asarray(y), np.asarray(y_ref))
         np.testing.assert_array_equal(np.asarray(s), np.asarray(s_ref))

@@ -271,10 +271,18 @@ class TestChainChunking:
         single = plan_chunks(spec)
         assert len(single) == 1
         out_size = spec.feature_sizes()[-1]
+        # one byte under the smallest working set of any single launch:
+        # with level 0 in patch form the streamed ring slot (sized for the
+        # largest K and the largest Cin) can outgrow the resident weights
         budget = min(
-            compile_program(spec, r).vmem_stream_bytes()
-            for r in range(1, out_size + 1)
-            if out_size % r == 0
+            min(prog.vmem_bytes(), prog.vmem_stream_bytes(),
+                *(prog.vmem_stream_bytes(1, 1, ct)
+                  for ct in prog.c_tile_options()))
+            for prog in (
+                compile_program(spec, r)
+                for r in range(1, out_size + 1)
+                if out_size % r == 0
+            )
         ) - 1
         forced = plan_chunks(spec, vmem_budget=budget)
         assert len(forced) > 1

@@ -83,6 +83,7 @@ def _kind(launch, dtype):
     prog = launch.program
     return (
         launch.regime,
+        prog.patch,
         launch.x_slots,
         prog.alpha > 1,
         tuple(p.S for p in prog.levels),
@@ -93,15 +94,15 @@ def _kind(launch, dtype):
 
 def _compile_launch(pyr, batch, budget, dtype, sharding):
     lp, spec = pyr.launch, pyr.spec
-    prog = lp.program
     cdt = jnp_dtype(dtype)
 
     def shape(*dims):
         return jax.ShapeDtypeStruct(dims, cdt, sharding=sharding)
 
+    convs = [lvl for lvl in spec.levels if lvl.kind == "conv"]
     x = shape(batch, spec.input_size, spec.input_size, spec.levels[0].n_in)
-    weights = [shape(p.K, p.K, p.n_in, p.n_out) for p in prog.levels]
-    biases = [shape(p.n_out) for p in prog.levels]
+    weights = [shape(c.K, c.K, c.n_in, c.n_out) for c in convs]
+    biases = [shape(c.n_out) for c in convs]
     return fused_pyramid.lower(
         x, weights, biases, spec=spec, out_region=lp.out_region,
         streamed=lp.streamed, w_slots=lp.w_slots if lp.streamed else None,
