@@ -13,8 +13,9 @@ Hardware note: USEFUSE *reuses* overlapping tile outputs from on-chip buffers
 recompute are identical, so the executor recomputes halos per tile while the
 intensity/cycle models charge the plan's actual buffer traffic.
 
-Layout: NHWC.  Conv weights: (K, K, Cin, Cout) + bias (Cout,).  Conv levels
-apply ReLU (the paper's pyramids are conv+ReLU[+pool] stacks).
+Layout: NHWC.  Conv weights: (K, K, Cin, Cout) + bias (Cout,).  Each conv
+level applies its own activation (``FusedLevel.relu``): ReLU, as in the
+paper's conv+ReLU[+pool] stacks, or none for a linear level.
 """
 
 from __future__ import annotations
@@ -79,7 +80,7 @@ def _maxpool(x: jnp.ndarray, k: int, s: int) -> jnp.ndarray:
 
 
 def reference_forward(
-    x: jnp.ndarray, spec: FusionSpec, params: PyramidParams, *, relu: bool = True
+    x: jnp.ndarray, spec: FusionSpec, params: PyramidParams
 ) -> jnp.ndarray:
     """Layer-by-layer execution with full intermediate maps (the baseline
     dataflow whose off-chip traffic fusion eliminates).  Convolutions run at
@@ -92,7 +93,7 @@ def reference_forward(
                 x = _conv2d(
                     x, params.weights[ci], params.biases[ci], lvl.S, lvl.pad
                 )
-                if relu:
+                if lvl.relu:
                     x = jax.nn.relu(x)
                 ci += 1
             else:
@@ -107,7 +108,6 @@ def fused_forward(
     plan: LockstepPlan | None = None,
     *,
     out_region: int | None = None,
-    relu: bool = True,
 ) -> jnp.ndarray:
     """Execute the fused pyramid tile-by-tile per the lockstep plan.
 
@@ -143,14 +143,14 @@ def fused_forward(
                 ),
             )
             tile = _tile_chain_2d(tile, (lo_i, lo_j), spec, params,
-                                  (wins_i, wins_j), relu)
+                                  (wins_i, wins_j))
             out = out.at[:, si : si + plan.out_region, sj : sj + plan.out_region, :].set(
                 tile
             )
     return out
 
 
-def _tile_chain_2d(tile, g_pad, spec, params, windows, relu):
+def _tile_chain_2d(tile, g_pad, spec, params, windows):
     """Run one tile through the fused chain using only tile-local buffers.
 
     ``tile`` holds a window of the level-0 *unpadded* input starting at
@@ -183,7 +183,7 @@ def _tile_chain_2d(tile, g_pad, spec, params, windows, relu):
         tile = tile[:, ai:bi, aj:bj, :]
         if lvl.kind == "conv":
             tile = _conv2d(tile, params.weights[ci], params.biases[ci], lvl.S, 0)
-            if relu:
+            if lvl.relu:
                 tile = jax.nn.relu(tile)
             ci += 1
         else:

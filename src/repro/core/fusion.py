@@ -43,6 +43,9 @@ class FusedLevel:
     is symmetric spatial padding (the paper's examples are pad-0; AlexNet /
     VGG need it).  ``kind`` is ``"conv"`` or ``"pool"``.  ``n_in``/``n_out``
     are channel counts (N and M in the paper) used by cycle/intensity models.
+    ``relu`` is a conv level's activation: ReLU, or linear when ``False``
+    (a ResNet bottleneck's last conv).  Each level applies its own, so one
+    pyramid may mix them; pools ignore it.
     """
 
     kind: str
@@ -52,6 +55,7 @@ class FusedLevel:
     n_in: int = 1
     n_out: int = 1
     name: str = ""
+    relu: bool = True
 
     def out_size(self, in_size: int) -> int:
         """Spatial output size for a (padded) input of ``in_size``."""

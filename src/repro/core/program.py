@@ -231,7 +231,8 @@ class ConvLevelProg:
     ``o_base + i * o_step`` is the global output coordinate of tile row 0 at
     grid index ``i``; rows outside ``[0, valid)`` are this level's padding and
     get masked to zero.  A trailing pool level is folded in as an epilogue
-    with its own offset/valid triple.
+    with its own offset/valid triple.  ``relu`` is the level's activation
+    (the :class:`~repro.core.fusion.FusedLevel`'s).
     """
 
     K: int
@@ -248,6 +249,7 @@ class ConvLevelProg:
     pool_o_base: int = 0
     pool_o_step: int = 0
     pool_valid: int = 0
+    relu: bool = True
 
 
 @dataclass(frozen=True)
@@ -571,7 +573,9 @@ def compile_program(
     uniform-stride grid — every level moves ``alpha`` times per dim).  Every
     pool level must directly follow a conv level: pools execute as epilogues
     of the preceding conv tile (Fig. 4), so a leading or doubled pool has no
-    conv program to fold into.  ``compute_dtype`` (name string or jnp dtype)
+    conv program to fold into, and that conv must apply ReLU: the kernel
+    pads and masks with zeros, which a max pool ignores only over
+    non-negative values.  ``compute_dtype`` (name string or jnp dtype)
     sets the byte width of every activation/weight the program accounts —
     window math is dtype-invariant, the byte and cycle models are not.
     The program is built from :func:`patch_spec` of ``spec``, so a
@@ -589,6 +593,12 @@ def compile_program(
         if lvl.kind == "pool" and levels[l - 1].kind != "conv":
             raise PlanError(
                 "each pool level must directly follow a conv level",
+                level=l, node=lvl.name,
+            )
+        if lvl.kind == "pool" and not levels[l - 1].relu:
+            raise PlanError(
+                "a pool level must follow a ReLU conv level (its zero"
+                " padding is neutral only for non-negative values)",
                 level=l, node=lvl.name,
             )
     source = spec
@@ -637,6 +647,7 @@ def compile_program(
                 pool_o_base=pool_ob,
                 pool_o_step=pool_os,
                 pool_valid=pool_valid,
+                relu=lvl.relu,
             )
         )
     for prev, cur in zip(progs, progs[1:]):

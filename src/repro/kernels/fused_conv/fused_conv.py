@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: variadic USEFUSE fusion pyramid (conv+ReLU[+pool] x Q).
+"""Pallas TPU kernel: variadic USEFUSE fusion pyramid (conv[+ReLU][+pool] x Q).
 
 The paper's fused-layer dataflow, adapted to the TPU memory hierarchy
 (DESIGN.md §2, §8): one grid cell computes one fusion-pyramid tile end to
@@ -50,13 +50,18 @@ Per grid cell (b, i, j):
     coordinate falls outside a level's valid output range are zeroed — zeros
     are exactly the next level's pad value, and post-ReLU zeros are neutral
     for maxpool (the executor's crop logic, branch-free for SIMD);
+  * each conv level applies its own activation (``ConvLevelProg.relu``):
+    ReLU, or none for a linear level such as a ResNet bottleneck's last
+    conv;
   * END tile-skip (the paper's §3.2 insight at TPU-feasible granularity)
-    generalizes to a **cascade**: at every level l >= 1, if the incoming
-    post-ReLU tile is all zero the level's K^2 MXU pass is skipped and its
-    output collapses to the closed form ``epilogue(relu(b_l))``; the constant
-    tile feeds the next level, which applies the same test — so a dead tile
-    with non-positive downstream biases short-circuits the whole remaining
-    pyramid.  Each level tracks the max of what it stored, which is the next
+    generalizes to a **cascade**: at every level l >= 1 whose input comes
+    from a ReLU level, if that post-ReLU tile is all zero the level's K^2
+    MXU pass is skipped and its output collapses to the closed form
+    ``epilogue(act(b_l))``; the constant tile feeds the next level, which
+    applies the same test — so a dead tile with non-positive downstream
+    biases short-circuits the remaining ReLU levels.  A level after a linear
+    level always computes: its input may be negative, so a zero max proves
+    nothing.  Each level tracks the max of what it stored, which is the next
     level's test.  A per-level skip flag is emitted for statistics.
 
 Weight regimes ("filters are loaded into the kernel buffers only once",
@@ -161,14 +166,14 @@ def _conv_level(
     idx,
     *,
     n_out: int,
-    relu: bool,
     dots: bool,
     stage,
     conv_out,
     emit,
     cdt,
 ):
-    """One conv level and its pool epilogue, one output row at a time.
+    """One conv level, its activation (``prog.relu``) and its pool
+    epilogue, one output row at a time.
 
     ``w_at(ki, kj, c, o)`` gives tap ``(ki, kj)``'s ``(lanes, 128)`` weights
     for input channel block ``c`` and 128-lane output block ``o``; ``bias``
@@ -218,7 +223,7 @@ def _conv_level(
             acc = accs[0] if n_blocks == 1 else jnp.concatenate(accs, axis=1)
             acc = acc[:, :n_out]
         acc = acc + bias
-        if relu:
+        if prog.relu:
             acc = jnp.maximum(acc, 0.0)
         acc = acc * (col_ok & _in_range(r + g0, prog.valid))
         for c in range(cb):
@@ -347,13 +352,18 @@ class _Launch:
         self.x_dma(bi, i, j, slot).wait()
         return slot
 
-    def level(self, l, src, w_at, bias, idx, emit, *, n_out, relu, dots):
+    def level(self, l, src, w_at, bias, idx, emit, *, n_out, dots):
         stage = self.stage if self.staged[l] else None
         return _conv_level(
-            src, w_at, bias, self.prog.levels[l], idx, n_out=n_out, relu=relu,
+            src, w_at, bias, self.prog.levels[l], idx, n_out=n_out,
             dots=dots, stage=stage, conv_out=self.conv_out[l], emit=emit,
             cdt=self.cdt,
         )
+
+    def skippable(self, l, end_skip: bool) -> bool:
+        """Whether level ``l`` may be END-skipped: its input is the output
+        of a ReLU level, so a zero max proves the input all zero."""
+        return end_skip and l > 0 and self.prog.levels[l - 1].relu
 
     def mid_emit(self, l):
         def emit(r, c, v):
@@ -402,7 +412,6 @@ def _pyramid_kernel(
     *refs,
     program: TileProgram,
     names: tuple[str, ...],
-    relu: bool,
     end_skip: bool,
     stream: bool,
     w_slots: int,
@@ -472,7 +481,7 @@ def _pyramid_kernel(
                 # the idle slot before this level's K^2 MXU pass
                 w_dma(l + 1).start()
             return ctx.level(l, src, w_at, bias, idx, emit,
-                             n_out=progs[l].n_out, relu=relu, dots=True)
+                             n_out=progs[l].n_out, dots=True)
 
         def skip_level(l=l, src=src, emit=emit, bias=bias, prev_live=prev_live):
             if stream and w_slots > 1:
@@ -485,11 +494,11 @@ def _pyramid_kernel(
                     def _():
                         w_dma(l).wait()
             return ctx.level(l, src, None, bias, idx, emit,
-                             n_out=progs[l].n_out, relu=relu, dots=False)
+                             n_out=progs[l].n_out, dots=False)
 
-        if l == 0 or not (end_skip and relu):
-            # level 0 always computes; without ReLU the all-zero test is not
-            # a sound skip predicate (negatives would survive).
+        if not ctx.skippable(l, end_skip):
+            # level 0 always computes; after a linear level the all-zero
+            # test is not a sound skip predicate (negatives would survive).
             live_flags.append(None)
             flags.append(jnp.int32(0))
             m = run_level()
@@ -512,7 +521,6 @@ def _ktiled_kernel(
     *refs,
     program: TileProgram,
     names: tuple[str, ...],
-    relu: bool,
     end_skip: bool,
     stream: bool,
     w_slots: int,
@@ -578,7 +586,7 @@ def _ktiled_kernel(
     k = pl.program_id(3)
     idx = (i, j)
     slot = jax.lax.rem(i * program.alpha + j, x_slots) if x_slots > 1 else 0
-    last_live = q == 1 or not (end_skip and relu)
+    last_live = not ctx.skippable(q - 1, end_skip)
 
     # ---- k == 0: input halo fetch (+ cross-cell prefetch chain) and the
     # mid pyramid, kept in the level tile buffers for k > 0 ----
@@ -604,13 +612,13 @@ def _ktiled_kernel(
                 else:
                     w_at = _ref_reader(w_refs[l])
                 return ctx.level(l, src, w_at, bias, idx, emit,
-                                 n_out=progs[l].n_out, relu=relu, dots=True)
+                                 n_out=progs[l].n_out, dots=True)
 
             def skip_level(l=l, src=src, bias=bias, emit=emit):
                 return ctx.level(l, src, None, bias, idx, emit,
-                                 n_out=progs[l].n_out, relu=relu, dots=False)
+                                 n_out=progs[l].n_out, dots=False)
 
-            if l == 0 or not (end_skip and relu):
+            if not ctx.skippable(l, end_skip):
                 flags.append(jnp.int32(0))
                 m = run_level()
             else:
@@ -643,7 +651,7 @@ def _ktiled_kernel(
 
     def run_last(dots):
         return ctx.level(q - 1, src, w_at, bias, idx, emit,
-                         n_out=ct, relu=relu, dots=dots)
+                         n_out=ct, dots=dots)
 
     if last_live:
         run_last(True)
@@ -659,7 +667,6 @@ def fused_pyramid_pallas(
     biases: list[jnp.ndarray],
     *,
     program: TileProgram,
-    relu: bool = True,
     end_skip: bool = True,
     interpret: bool | None = None,
     stream_weights: bool = False,
@@ -797,7 +804,6 @@ def fused_pyramid_pallas(
     common = dict(
         program=program,
         names=tuple(n for n, _, _ in scratch),
-        relu=relu,
         end_skip=end_skip,
         stream=stream_weights,
         w_slots=w_slots,

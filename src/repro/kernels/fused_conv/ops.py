@@ -41,8 +41,7 @@ from .fused_conv import fused_pyramid_pallas
     jax.jit,
     static_argnames=(
         "spec", "out_region", "streamed", "w_slots", "x_slots", "c_tiles",
-        "relu", "end_skip", "interpret", "vmem_budget", "compute_dtype",
-        "name",
+        "end_skip", "interpret", "vmem_budget", "compute_dtype", "name",
     ),
 )
 def fused_pyramid(
@@ -56,7 +55,6 @@ def fused_pyramid(
     w_slots: int | None = None,
     x_slots: int | None = None,
     c_tiles: int | None = None,
-    relu: bool = True,
     end_skip: bool = True,
     interpret: bool | None = None,
     vmem_budget: int = VMEM_BUDGET_BYTES,
@@ -66,7 +64,8 @@ def fused_pyramid(
     """Fused Q-conv pyramid forward as a single kernel launch.
 
     ``x``: (B, H, W, C) NHWC; ``weights[l]``: (K, K, Cin, Cout) and
-    ``biases[l]``: (Cout,) per conv level, in chain order.  ``out_region``
+    ``biases[l]``: (Cout,) per conv level, in chain order.  Each conv level
+    applies its own activation (``spec.levels[l].relu``).  ``out_region``
     must tile the final output exactly; ``None`` picks the largest region
     fitting the VMEM budget.  ``streamed`` / ``w_slots`` / ``x_slots`` /
     ``c_tiles`` pin the weight regime, the input landing-buffer depth, and
@@ -179,7 +178,6 @@ def fused_pyramid(
         weights,
         [b.astype(cdt) for b in biases],
         program=prog,
-        relu=relu,
         end_skip=end_skip,
         interpret=interpret,
         stream_weights=stream,
@@ -249,7 +247,6 @@ def fused_conv2(
     *,
     spec: FusionSpec,
     out_region: int,
-    relu: bool = True,
     end_skip: bool = True,
     interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -265,7 +262,6 @@ def fused_conv2(
         [b1, b2],
         spec=spec,
         out_region=out_region,
-        relu=relu,
         end_skip=end_skip,
         interpret=interpret,
     )
@@ -351,7 +347,6 @@ def fused_pyramid_chain(
     *,
     spec: FusionSpec,
     out_regions: list[int] | None = None,
-    relu: bool = True,
     end_skip: bool = True,
     interpret: bool | None = None,
     vmem_budget: int = VMEM_BUDGET_BYTES,
@@ -394,7 +389,6 @@ def fused_pyramid_chain(
             list(biases[wi : wi + q]),
             spec=sub,
             out_region=out_regions[ci] if out_regions is not None else None,
-            relu=relu,
             end_skip=end_skip,
             interpret=interpret,
             vmem_budget=vmem_budget,

@@ -14,12 +14,10 @@ def fused_pyramid_ref(
     spec: FusionSpec,
     weights: list,
     biases: list,
-    *,
-    relu: bool = True,
 ) -> jnp.ndarray:
     """Oracle for :func:`~repro.kernels.fused_conv.ops.fused_pyramid`."""
     params = PyramidParams(weights=list(weights), biases=list(biases))
-    return reference_forward(x, spec, params, relu=relu)
+    return reference_forward(x, spec, params)
 
 
 def fused_conv2_ref(
@@ -29,7 +27,5 @@ def fused_conv2_ref(
     b1: jnp.ndarray,
     w2: jnp.ndarray,
     b2: jnp.ndarray,
-    *,
-    relu: bool = True,
 ) -> jnp.ndarray:
-    return fused_pyramid_ref(x, spec, [w1, w2], [b1, b2], relu=relu)
+    return fused_pyramid_ref(x, spec, [w1, w2], [b1, b2])

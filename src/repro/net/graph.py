@@ -7,9 +7,10 @@ forward references only, shape/channel chaining) so malformed networks fail
 here with a named node, not deep inside a kernel.
 
 The IR is the single source of the model zoo: :func:`lenet5`,
-:func:`alexnet`, :func:`vgg16` and :func:`resnet18` replace the raw tuple
-tables that used to live in ``core/cnn_models.py`` (which now *derives* its
-paper fusion specs from these graphs).  All builders take ``input_size`` so
+:func:`alexnet`, :func:`vgg16`, :func:`resnet18` and :func:`resnet50`
+replace the raw tuple tables that used to live in ``core/cnn_models.py``
+(which now *derives* its paper fusion specs from these graphs).  All
+builders take ``input_size`` so
 tests and interpret-mode demos can run reduced-scale variants of the same
 topology.
 
@@ -21,10 +22,12 @@ classifier head — are exactly the IR nodes that force a feature map to
 materialize, i.e. the partitioner's legal cut points.
 
 Activation convention: conv and dense nodes carry a fused ``relu`` flag (the
-paper's pyramids are conv+ReLU stacks; the Pallas kernel applies ReLU per
-conv level), while standalone ``relu`` nodes express post-residual-add
-activations.  A fusable chain must be relu-uniform across its convs because
-one pyramid launch applies a single activation mode.
+paper's pyramids are conv+ReLU stacks), while standalone ``relu`` nodes
+express post-residual-add activations.  The flag lowers to the pyramid
+level (:class:`~repro.core.fusion.FusedLevel`), so a chain may mix ReLU and
+linear convs — a ResNet bottleneck's ``1x1 → 3x3 → 1x1 (linear)`` is one
+chain.  Only a pool must follow a ReLU conv: the kernel pads and masks with
+zeros, which a max pool ignores only over non-negative values.
 """
 
 from __future__ import annotations
@@ -192,7 +195,6 @@ class Segment:
     nodes: tuple[Node, ...]
     input_size: int
     in_channels: int
-    relu: bool  # uniform fused activation of the chain's convs
 
     @property
     def node_names(self) -> tuple[str, ...]:
@@ -212,7 +214,7 @@ def _levels(nodes: tuple[Node, ...], in_channels: int) -> tuple[FusedLevel, ...]
         if n.op == "conv":
             levels.append(
                 FusedLevel("conv", K=n.K, S=n.S, pad=n.pad, n_in=c,
-                           n_out=n.n_out, name=n.name)
+                           n_out=n.n_out, name=n.name, relu=n.relu)
             )
             c = n.n_out
         else:
@@ -227,10 +229,11 @@ def fusable_segments(graph: Graph) -> tuple[Segment, ...]:
     """Maximal fusable chains, in topological order.
 
     A conv starts or extends a chain; a pool extends one.  A node extends the
-    current chain only when it consumes the chain tail, the tail has no other
-    consumer, and (for convs) its fused-relu mode matches the chain's — a
-    pyramid launch applies one activation mode.  Everything else (residual
-    add, fork, head op) terminates the chain: these are the cut points.
+    current chain only when it consumes the chain tail and the tail has no
+    other consumer; a pool also needs a ReLU conv before it (see the module
+    docstring).  Convs of either activation chain freely.  Everything else
+    (residual add, fork, head op) terminates the chain: these are the cut
+    points.
     """
     shapes = infer_shapes(graph)
     n_consumers = {k: len(v) for k, v in graph.consumers().items()}
@@ -246,7 +249,6 @@ def fusable_segments(graph: Graph) -> tuple[Segment, ...]:
                     nodes=tuple(cur),
                     input_size=s_in.size,
                     in_channels=s_in.channels,
-                    relu=cur[0].relu,
                 )
             )
             cur.clear()
@@ -257,7 +259,7 @@ def fusable_segments(graph: Graph) -> tuple[Segment, ...]:
                 cur
                 and n.inputs[0] == cur[-1].name
                 and n_consumers[cur[-1].name] == 1
-                and (n.op == "pool" or n.relu == cur[0].relu)
+                and (n.op == "conv" or cur[-1].relu)
             )
             if extends:
                 cur.append(n)
@@ -376,11 +378,12 @@ def resnet18(input_size: int = 224, num_classes: int = 1000, *,
     (1x1 projection shortcuts at the stride-2 / channel-change blocks),
     global average pool and the classifier.
 
-    Per the repro's block variant (and the repo's historical per-block
-    fusion), every conv applies fused ReLU — including convB before the add —
-    since a fusion pyramid applies one activation mode; the residual join is
-    a standalone ``add`` + ``relu`` pair.  Projection shortcuts are
-    relu-free 1x1 convs, which makes them their own Q=1 pyramids.
+    Per the repro's block variant, every body conv applies fused ReLU —
+    including convB before the add (the published block leaves convB
+    linear; the benchmark's ``resnet18-bf16`` configuration states this
+    variant); the residual join is a standalone ``add`` + ``relu`` pair.
+    Projection shortcuts are relu-free 1x1 convs, forked from the block
+    input, which makes them their own Q=1 pyramids.
     """
     b = _Builder()
     b.conv("conv1", 7, 2, 3, 64)
@@ -403,11 +406,51 @@ def resnet18(input_size: int = 224, num_classes: int = 1000, *,
     return b.graph("resnet18", input_size, 3, compute_dtype)
 
 
+# (bottleneck width, blocks) per stage, conv2_x..conv5_x (He et al. 2016,
+# Table 1, 50-layer column); each block widens to 4x its width
+_RESNET50_PLAN = ((64, 3), (128, 4), (256, 6), (512, 3))
+
+
+def resnet50(input_size: int = 224, num_classes: int = 1000, *,
+             compute_dtype: str = "float32") -> Graph:
+    """ResNet-50 v1.5: the ResNet-18 stem, sixteen bottleneck blocks
+    ``1x1 (ReLU) → 3x3/s (ReLU) → 1x1 to 4x the width (linear)``, the
+    residual ``add`` and then ``relu``, global average pool and the
+    classifier.  v1.5 puts a stage's stride 2 on the 3x3 conv of its first
+    block (conv3_x..conv5_x), not on the 1x1.  The first block of every
+    stage, conv2_x's included (64 → 256), has a 1x1 projection shortcut at
+    that stride.  Batch norm is folded into the conv biases.  The body
+    ``convA..convC`` is one fusable chain with a linear last level."""
+    b = _Builder()
+    b.conv("conv1", 7, 2, 3, 64)
+    b.pool("maxpool", 3, 2, pad=1)
+    i = 0
+    for stage, (width, blocks) in enumerate(_RESNET50_PLAN):
+        for k in range(blocks):
+            blk, block_in = f"b{i}", b.tail
+            s = 2 if stage > 0 and k == 0 else 1
+            b.conv(f"{blk}_convA", 1, 1, 0, width, src=block_in)
+            b.conv(f"{blk}_convB", 3, s, 1, width)
+            body = b.conv(f"{blk}_convC", 1, 1, 0, 4 * width, relu=False)
+            if k == 0:
+                shortcut = b.conv(f"{blk}_proj", 1, s, 0, 4 * width,
+                                  src=block_in, relu=False)
+            else:
+                shortcut = block_in
+            b.op("add", f"{blk}_add", body, shortcut)
+            b.op("relu", f"{blk}_relu")
+            i += 1
+    b.op("global_pool", "gap")
+    b.op("dense", "FC", n_out=num_classes, relu=False)
+    return b.graph("resnet50", input_size, 3, compute_dtype)
+
+
 MODELS = {
     "lenet": lenet5,
     "alexnet": alexnet,
     "vgg16": vgg16,
     "resnet18": resnet18,
+    "resnet50": resnet50,
 }
 
 

@@ -45,18 +45,21 @@ from .graph import Graph, Segment, fusable_segments, infer_shapes
 
 INFEASIBLE = (float("inf"), float("inf"))
 
-# tracer counter: pyramids planned with level 0 in patch form
+# tracer counters bumped as each plan is built: pyramids planned with
+# level 0 in patch form; per-image MACs of every conv level planned; and
+# those in pyramids of two or more conv levels
 PATCH_LEVELS = "fused.patch_levels"
+CONV_MACS = "fused.conv_macs"
+CHAINED_CONV_MACS = "fused.chained_conv_macs"
 
 
 @dataclass(frozen=True)
 class PyramidPlan:
     """One chosen pyramid: the launch configuration plus the graph nodes it
-    covers.  ``relu`` is the chain's uniform fused activation."""
+    covers.  Each conv level's activation is on its spec's level."""
 
     launch: LaunchPlan
     node_names: tuple[str, ...]
-    relu: bool
 
     @property
     def spec(self) -> FusionSpec:
@@ -265,20 +268,36 @@ def brute_force_segment(
 # ---------------------------------------------------------------------------
 
 
+def conv_macs(spec: FusionSpec) -> int:
+    """Multiply-adds of the spec's conv levels for one image."""
+    sizes = spec.feature_sizes()
+    return sum(
+        lvl.K * lvl.K * lvl.n_in * lvl.n_out * sizes[l + 1] ** 2
+        for l, lvl in enumerate(spec.levels)
+        if lvl.kind == "conv"
+    )
+
+
 def _segment_pyramids(
     segment: Segment, launches: list[LaunchPlan]
 ) -> list[PyramidPlan]:
-    """Attach covered node names to each launch, walking the chain.  Each
-    pyramid whose level 0 runs in patch form bumps the tracer's
-    :data:`PATCH_LEVELS` counter once, as its plan is built."""
+    """Attach covered node names to each launch, walking the chain.  As the
+    plan is built, each pyramid whose level 0 runs in patch form bumps the
+    tracer's :data:`PATCH_LEVELS` counter once, every pyramid adds its conv
+    levels' per-image MACs to :data:`CONV_MACS`, and a pyramid of two or
+    more conv levels adds them to :data:`CHAINED_CONV_MACS` too."""
     out, li = [], 0
     tracer = get_tracer()
     for lp in launches:
         if lp.program.patch:
             tracer.bump(PATCH_LEVELS)
+        macs = conv_macs(lp.spec)
+        tracer.bump(CONV_MACS, macs)
+        if lp.spec.q_convs > 1:
+            tracer.bump(CHAINED_CONV_MACS, macs)
         n_levels = len(lp.spec.levels)
         names = tuple(n.name for n in segment.nodes[li : li + n_levels])
-        out.append(PyramidPlan(launch=lp, node_names=names, relu=segment.relu))
+        out.append(PyramidPlan(launch=lp, node_names=names))
         li += n_levels
     assert li == len(segment.nodes), "launches must tile the segment"
     return out
@@ -298,8 +317,9 @@ def replan_pyramid(
     working set no longer fits at run time, its covered chain is rebuilt as
     a :class:`~repro.net.graph.Segment` and re-run through the same DP —
     tighter cuts, a chain of smaller launches, each individually under the
-    new budget.  Raises :class:`repro.robust.errors.BudgetError` when even
-    single conv groups cannot fit, i.e. this rung is exhausted.
+    new budget; every level keeps its node's activation.  Raises
+    :class:`repro.robust.errors.BudgetError` when even single conv groups
+    cannot fit, i.e. this rung is exhausted.
     """
     shapes = infer_shapes(graph)
     src = graph.node(pyr.node_names[0]).inputs[0]
@@ -307,7 +327,6 @@ def replan_pyramid(
         nodes=tuple(graph.node(m) for m in pyr.node_names),
         input_size=shapes[src].size,
         in_channels=shapes[src].channels,
-        relu=pyr.relu,
     )
     launches = partition_segment(
         seg, vmem_budget=vmem_budget, batch=batch,

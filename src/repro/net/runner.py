@@ -218,7 +218,6 @@ def _forward(
                     ),
                     x_slots=pyr.launch.x_slots,
                     c_tiles=pyr.launch.c_tiles,
-                    relu=pyr.relu,
                     end_skip=end_skip,
                     interpret=interpret,
                     vmem_budget=plan.vmem_budget,

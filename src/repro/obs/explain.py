@@ -2,8 +2,10 @@
 
 Prints the auto-partition of a zoo model as a per-launch table (covered
 nodes, Q, grid, level-0 form, regime, plan knobs, modeled HBM/VMEM bytes
-with budget headroom, modeled cycles) and the pyramids whose level 0 runs
-in patch form, and optionally:
+with budget headroom, modeled cycles, each conv level's activation), the
+pyramids whose level 0 runs in patch form, and the plan build's conv-MAC
+counters (all conv MACs, and those in pyramids of two or more convs), and
+optionally:
 
 * ``--trace out.json`` — export a Chrome-trace / Perfetto JSON of every
   launch's modeled fill/steady/drain DMA-vs-MXU timeline
@@ -42,7 +44,8 @@ from repro.core.cycle_model import DEFAULT_PARAMS
 
 # interpret-friendly --run scales (paper scale for LeNet only); the table
 # itself defaults to paper scale via the graph builders
-RUN_SIZE = {"lenet": 32, "alexnet": 67, "vgg16": 32, "resnet18": 32}
+RUN_SIZE = {"lenet": 32, "alexnet": 67, "vgg16": 32, "resnet18": 32,
+            "resnet50": 32}
 
 
 def _fmt_bytes(n: int) -> str:
@@ -55,7 +58,7 @@ def plan_table(plan, vmem_budget: int, out=print) -> None:
     out(
         f"{'launch':<26} {'nodes':>5} {'Q':>2} {'grid':>6} {'region':>6} "
         f"{'L0':<6} {'regime':<16} {'x/w/c':>6} {'hbm':>9} {'vmem':>9} "
-        f"{'headroom':>9} {'cycles':>10} {'us':>9}"
+        f"{'headroom':>9} {'cycles':>10} {'us':>9}  activations"
     )
     for p in plan.pyramids:
         d = p.launch.describe(plan.batch, vmem_budget)
@@ -68,7 +71,9 @@ def plan_table(plan, vmem_budget: int, out=print) -> None:
             f"{_fmt_bytes(d['vmem_bytes']):>9} "
             f"{_fmt_bytes(d['vmem_headroom_bytes']):>9} "
             f"{d['modeled_cycles']:>10,} "
-            f"{d['modeled_cycles'] / DEFAULT_PARAMS.freq_mhz:>9,.1f}"
+            f"{d['modeled_cycles'] / DEFAULT_PARAMS.freq_mhz:>9,.1f}  "
+            + " ".join("relu" if lvl.relu else "linear"
+                       for lvl in p.spec.levels if lvl.kind == "conv")
         )
     out(
         f"total: {plan.n_launches()} launches, "
@@ -181,6 +186,8 @@ def main(argv: list[str] | None = None) -> int:
     from repro.core.program import VMEM_BUDGET_BYTES
     from repro.net.graph import MODELS
     from repro.net.partition import (
+        CHAINED_CONV_MACS,
+        CONV_MACS,
         PATCH_LEVELS,
         auto_partition,
         partition_cache_info,
@@ -225,11 +232,14 @@ def main(argv: list[str] | None = None) -> int:
     graph = MODELS[args.model](**kwargs)
 
     tracer = get_tracer()
-    built = tracer.counters.get(PATCH_LEVELS, 0)
+    counters = (PATCH_LEVELS, CONV_MACS, CHAINED_CONV_MACS)
+    before = [tracer.counters.get(c, 0) for c in counters]
     plan = auto_partition(
         graph, batch=args.batch, vmem_budget=args.vmem_budget
     )
-    built = tracer.counters.get(PATCH_LEVELS, 0) - built
+    built, macs, chained = (
+        tracer.counters.get(c, 0) - b for c, b in zip(counters, before)
+    )
     print(
         f"{graph.name}: input {graph.input_size}x{graph.input_size}, "
         f"batch {args.batch}, dtype {plan.compute_dtype}, "
@@ -240,6 +250,11 @@ def main(argv: list[str] | None = None) -> int:
     print(
         f"level 0 in patch form: {', '.join(patch) or 'none'} "
         f"({PATCH_LEVELS} +{built} for this plan's build)"
+    )
+    share = f" ({100 * chained / macs:.1f}% chained)" if macs else ""
+    print(
+        f"conv MACs per image: {CONV_MACS} +{macs:,}, "
+        f"{CHAINED_CONV_MACS} +{chained:,}{share}"
     )
     info = partition_cache_info()
     print(

@@ -149,7 +149,6 @@ def _run_subplan(x_in, subs, params, graph, cdt, *, end_skip, interpret,
             w_slots=sp.launch.w_slots if sp.launch.streamed else None,
             x_slots=sp.launch.x_slots,
             c_tiles=sp.launch.c_tiles,
-            relu=sp.relu,
             end_skip=end_skip,
             interpret=interpret,
             vmem_budget=vmem_budget,

@@ -1,5 +1,7 @@
 """Fused executor vs monolithic reference — exactness on all networks."""
 
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
@@ -61,11 +63,16 @@ class TestFusedEqualsReference:
         _check(spec, 3)
 
     def test_no_relu_mode(self):
-        spec = LENET5_FUSION
+        spec = FusionSpec(
+            levels=tuple(
+                dataclasses.replace(l, relu=False) for l in LENET5_FUSION.levels
+            ),
+            input_size=LENET5_FUSION.input_size,
+        )
         params = init_pyramid_params(spec, KEY)
         x = jax.random.normal(jax.random.PRNGKey(2), (1, 32, 32, 1))
-        ref = reference_forward(x, spec, params, relu=False)
-        fused = fused_forward(x, spec, params, lockstep_plan(spec, 1), relu=False)
+        ref = reference_forward(x, spec, params)
+        fused = fused_forward(x, spec, params, lockstep_plan(spec, 1))
         np.testing.assert_allclose(np.asarray(fused), np.asarray(ref), atol=1e-5)
 
 

@@ -160,16 +160,25 @@ class TestFusedConvKernel:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
 
     def test_no_relu_disables_skip(self):
-        spec = LENET5_FUSION
+        """A level after a linear level never skips: its input is negative
+        everywhere (bias shifted by -10), which a ReLU level would have
+        zeroed and skipped."""
+        spec = FusionSpec(
+            levels=(
+                FusedLevel("conv", 5, 1, 0, 1, 6, relu=False),
+                FusedLevel("conv", 5, 1, 0, 6, 16, relu=False),
+            ),
+            input_size=32,
+        )
         p = init_pyramid_params(spec, KEY)
+        b1 = p.biases[0] - 10.0
         x = jax.random.normal(jax.random.PRNGKey(1), (1, 32, 32, 1))
         out, skip = fused_conv2(
-            x, p.weights[0], p.biases[0], p.weights[1], p.biases[1],
-            spec=spec, out_region=1, relu=False,
+            x, p.weights[0], b1, p.weights[1], p.biases[1],
+            spec=spec, out_region=1,
         )
         ref = fused_conv2_ref(
-            x, spec, p.weights[0], p.biases[0], p.weights[1], p.biases[1],
-            relu=False,
+            x, spec, p.weights[0], b1, p.weights[1], p.biases[1],
         )
         assert skip.sum() == 0
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)

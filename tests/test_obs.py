@@ -19,8 +19,11 @@ from repro.core.cycle_model import timeline_end
 from repro.core.program import plan_launch
 from repro.net.graph import MODELS, lenet5
 from repro.net.partition import (
+    CHAINED_CONV_MACS,
+    CONV_MACS,
     auto_partition,
     clear_partition_cache,
+    conv_macs,
     partition_cache_info,
 )
 from repro.net import runner
@@ -499,6 +502,27 @@ class TestExplainCLI:
         text = capsys.readouterr().out
         assert "regime" in text and "partition cache" in text
         assert validate_chrome_trace(json.loads(out.read_text())) == []
+
+    def test_conv_mac_counters_and_activations(self, capsys):
+        """Plan build bumps ``fused.conv_macs`` by every planned conv
+        level's per-image MACs and ``fused.chained_conv_macs`` by those in
+        multi-conv pyramids; the table names each level's activation."""
+        from repro.obs.explain import main
+
+        clear_partition_cache()
+        with tracing() as collector:
+            plan = auto_partition(MODELS["resnet18"](), batch=1)
+        single = sum(conv_macs(p.spec) for p in plan.pyramids if p.q_convs == 1)
+        total = collector.counters[CONV_MACS]
+        assert total == 1_813_561_344  # ResNet-18's convs at 224x224
+        assert collector.counters[CHAINED_CONV_MACS] == total - single
+        clear_partition_cache()
+        argv = ["--model", "resnet50", "--dtype", "bfloat16", "--batch", "8"]
+        assert main(argv) == 0
+        text = capsys.readouterr().out
+        assert text.count("relu relu linear") == 16
+        assert "fused.conv_macs +4,087,136,256" in text
+        assert "fused.chained_conv_macs +3,609,460,736 (88.3% chained)" in text
 
     @pytest.mark.parametrize("model", sorted(MODELS))
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])

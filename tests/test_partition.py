@@ -35,7 +35,7 @@ def _chain_segment(channels, size, k=3, pad=1, pools=()):
         if i in pools:
             nodes.append(Node("pool", f"p{i}", (prev,), K=2, S=2))
             prev = f"p{i}"
-    return Segment(nodes=tuple(nodes), input_size=size, in_channels=2, relu=True)
+    return Segment(nodes=tuple(nodes), input_size=size, in_channels=2)
 
 
 class TestChannelChainValidation:
@@ -168,7 +168,7 @@ class TestWholeGraphPartitions:
         projs = [p for p in plan.pyramids if p.node_names[0].endswith("_proj")]
         assert len(projs) == 3
         for p in projs:
-            assert p.q_convs == 1 and p.relu is False
+            assert p.q_convs == 1 and p.spec.levels[0].relu is False
 
     def test_vgg16_acceptance_auto_beats_both_baselines(self):
         """The PR's acceptance comparison: modeled HBM of the auto plan <=

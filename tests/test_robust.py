@@ -285,15 +285,18 @@ class TestSegmentReluThreading:
         reintroduce the activation."""
         g = MODELS["resnet18"](input_size=32, num_classes=10)
         plan = auto_partition(g, batch=1)
-        no_relu = [p for p in plan.pyramids if not p.relu]
+        no_relu = [p for p in plan.pyramids
+                   if not any(l.relu for l in p.spec.levels)]
         assert no_relu, "expected relu-free shortcut pyramids"
         pyr = no_relu[0]
         subs = replan_pyramid(
             g, pyr, vmem_budget=plan.vmem_budget, batch=1
         )
-        assert all(not sp.relu for sp in subs)
+        assert all(not l.relu for sp in subs for l in sp.spec.levels)
 
     def test_segment_requires_relu_field(self):
+        """The activation rides on each level of the segment's spec."""
         g = MODELS["lenet"]()
         seg = fusable_segments(g)[0]
-        assert isinstance(seg, Segment) and seg.relu is True
+        assert isinstance(seg, Segment)
+        assert all(l.relu is True for l in seg.spec().levels if l.kind == "conv")

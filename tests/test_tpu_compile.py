@@ -43,7 +43,7 @@ ZOO = [
     (model, dtype)
     for model in ("lenet", "alexnet", "vgg16", "resnet18")
     for dtype in ("float32", "bfloat16")
-]
+] + [("resnet50", "bfloat16")]
 CUSTOM_CALL = 'custom_call_target="tpu_custom_call"'
 
 
@@ -88,6 +88,7 @@ def _kind(launch, dtype):
         prog.alpha > 1,
         tuple(p.S for p in prog.levels),
         tuple(p.pool for p in prog.levels),
+        tuple(p.relu for p in prog.levels),
         dtype,
     )
 
@@ -106,7 +107,7 @@ def _compile_launch(pyr, batch, budget, dtype, sharding):
     return fused_pyramid.lower(
         x, weights, biases, spec=spec, out_region=lp.out_region,
         streamed=lp.streamed, w_slots=lp.w_slots if lp.streamed else None,
-        x_slots=lp.x_slots, c_tiles=lp.c_tiles, relu=pyr.relu,
+        x_slots=lp.x_slots, c_tiles=lp.c_tiles,
         interpret=False, vmem_budget=budget,
         compute_dtype=dtype,
     ).compile().as_text()
