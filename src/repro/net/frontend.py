@@ -11,8 +11,9 @@ results dict.  This module is the bridge (DESIGN.md §15):
   engine's locked admission path — shape checks, shedding, typed
   rejection all still apply) and get back a :class:`RequestHandle`; a
   daemon thread wakes on every submit and runs ``engine.drain()``, so
-  batches keep the engine's double-buffered staging and all jax calls
-  stay on one thread.
+  batches keep the engine's pipeline (the next batch dispatched before
+  the current one is blocked on, whenever one is queued) and all jax
+  calls stay on one thread.
 * :class:`RequestHandle` is a minimal Future: :meth:`RequestHandle.result`
   blocks (with timeout) until the request is terminal and returns the
   :class:`~repro.net.serve.RequestResult` — completed, rejected, shed,
@@ -94,6 +95,14 @@ class ServingFrontend:
     frontend owns it — the engine's drain lock enforces serialization, but
     a foreign drain would steal completions the frontend expects to
     observe (it still would via the listener; it just wastes a wake-up).
+
+    While producers keep more than one batch queued, the drain thread
+    keeps two forwards in flight: it dispatches batch ``n+1`` before
+    blocking on ``n``, so delivering ``n``'s results and staging ``n+2``
+    run under ``n+1``'s device time.  With one request in flight it
+    blocks on each batch as it is dispatched (depth 1); a configured
+    breaker or guarded ladder also keeps depth 1 (``ServingEngine.drain``).
+    Handles still resolve in batch order.
     """
 
     def __init__(self, engine: ServingEngine) -> None:

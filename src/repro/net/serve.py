@@ -43,11 +43,16 @@ the runner into a service (ROADMAP's continuous-batching item):
   (``repro.net.runner.jit_trace_count`` is the regression hook).  The
   engine's own :class:`collections.OrderedDict` LRU bounds live entries and
   counts hits/misses/evictions next to ``partition_cache_info()``.
-* **Double-buffered input staging** — while bucket *n* computes on device,
-  bucket *n+1*'s padded host batch is already moving through
-  ``jax.device_put`` (jax dispatch is asynchronous, so the host copy
-  overlaps device compute).  The cost model twin is
-  :func:`repro.core.cycle_model.serve_stream_cycles`.
+* **Two forwards in flight** — the drain loop dispatches bucket *n+1*
+  before it blocks on bucket *n* (jax dispatch is asynchronous, so *n+1*
+  queues behind *n* on the device), then records *n* and stages *n+2*
+  (``jax.device_put`` of its padded host batch) while *n+1* computes: the
+  host's record, staging and dispatch all overlap device compute.  Where
+  no next batch is queued, or where *n*'s outcome could change *n+1*'s
+  route (a breaker, the guarded ladder, an armed fault injector), it
+  keeps depth 1: only the input staging overlaps.  The cost model twin
+  is :func:`repro.core.cycle_model.serve_stream_cycles` (which still
+  models depth 1).
 * **Failure containment** (DESIGN.md §15) — a launch that dies with a
   typed :class:`~repro.robust.errors.RobustError` (including injected
   staging failures) fails *its batch* typed and the queue keeps draining.
@@ -71,7 +76,9 @@ the runner into a service (ROADMAP's continuous-batching item):
   gets host spans where the work happens: ``engine.admit`` per request
   (linked to its batch at staging), and ``engine.stage`` /
   ``engine.dispatch`` (with its route) / ``engine.block`` /
-  ``engine.record`` per batch; the cache bumps
+  ``engine.record`` per batch; the drain loop bumps ``serve.batches`` per
+  fused dispatch and ``serve.dispatched_ahead`` per dispatch made while
+  the previous batch was in flight; the cache bumps
   ``serve_cache_{hit,miss,eviction}`` counters (a miss also records an
   event), and every shed/expiry/watchdog/breaker/sentinel action records
   its own event.  Installing a tracer never changes the forward path:
@@ -326,6 +333,23 @@ class _BucketStats:
     batch_walls_ms: list = field(default_factory=list)
 
 
+@dataclass
+class _Launch:
+    """One dispatched batch, from its dispatch to its record."""
+
+    batch: list[Request]
+    bucket: int
+    entry: _PlanEntry
+    x_dev: jax.Array
+    bid: int
+    breaker: CircuitBreaker | None
+    route: str
+    t0: float  # dispatch start
+    logits: jax.Array | None = None
+    report: object = None  # the guarded RunReport, fused+guarded only
+    err: RobustError | None = None
+
+
 def _percentile(values: list, q: float) -> float:
     # the shared obs.stats helper, kept under the historical name
     return percentile(values, q)
@@ -342,10 +366,11 @@ class ServingEngine:
     """Continuous bucketed batching over one graph's fused-pyramid runner.
 
     ``submit`` admits (or rejects) requests under the engine lock — safe
-    from any thread; ``drain`` forms buckets and executes them with the
-    double-buffered input stage (one drain loop at a time — concurrent
-    drains serialize); ``summary`` renders the bucket/SLO table.  The
-    engine owns no device state beyond the staged batch — all heavy reuse
+    from any thread; ``drain`` forms buckets and executes them with up to
+    two forwards in flight and the next input staged (one drain loop at a
+    time — concurrent drains serialize); ``summary`` renders the
+    bucket/SLO table.  The engine owns no device state beyond the batches
+    in flight and the staged one — all heavy reuse
     lives in the plan+jit cache, so two engines over the same graph share
     compiled executables through jax's own cache.  Completion listeners
     (:meth:`add_listener`) observe every terminal :class:`RequestResult` —
@@ -375,6 +400,10 @@ class ServingEngine:
         }
         self._breakers: dict[tuple, CircuitBreaker] = {}
         self._breaker_emitted: dict[tuple, int] = {}
+        # fused-route dispatches, and those made while the previous batch
+        # was still in flight (``serve.batches`` / ``serve.dispatched_ahead``
+        # in the tracer's counters)
+        self.dispatches = {"batches": 0, "dispatched_ahead": 0}
         self._listeners: list = []
         self._lock = threading.RLock()
         self._drain_lock = threading.Lock()
@@ -840,22 +869,162 @@ class ServingEngine:
                 stats.latencies_ms.append(lat_ms)
                 self._notify(result)
 
+    def _may_dispatch_ahead(self, bucket: int) -> bool:
+        """Whether the next batch may be dispatched before this one (of
+        ``bucket``) is blocked on: only where this batch's outcome cannot
+        change the next one's route or launch — no circuit breaker, no
+        guarded ladder — and no fault injector is armed, so the chaos
+        faults (queue stall, slow launch, poisoned output) keep the order
+        they are defined against (DESIGN.md §15)."""
+        return (not self.config.guarded and not get_injector().enabled
+                and self._breaker(bucket) is None)
+
+    def _dispatch(self, staged, ahead: bool) -> _Launch:
+        """Route and dispatch one staged batch (jax runs it asynchronously);
+        a typed failure is kept on the launch, to be failed in its turn.
+        ``ahead``: the previous batch is still in flight."""
+        batch, bucket, entry, x_dev, bid = staged
+        breaker = self._breaker(bucket)
+        route = "fused"
+        if breaker is not None and not breaker.allow():
+            route = breaker.pinned_rung or "reference"
+        launch = _Launch(batch, bucket, entry, x_dev, bid, breaker, route,
+                         time.perf_counter())
+        try:
+            launch.logits, launch.report = self._run_route(
+                route, entry, x_dev
+            )
+        except RobustError as e:
+            launch.err = e
+        tracer = get_tracer()
+        tracer.span(_DISPATCH, launch.t0, time.perf_counter(), bid,
+                    bucket=bucket, route=_ROUTES[route])
+        if route == "fused":
+            self.dispatches["batches"] += 1
+            tracer.bump("serve.batches")
+            if ahead:
+                self.dispatches["dispatched_ahead"] += 1
+                tracer.bump("serve.dispatched_ahead")
+        return launch
+
+    def _finish(self, launch: _Launch, t_free: float) -> float:
+        """Block on a dispatched batch, then run its sentinel, watchdog and
+        breaker bookkeeping and record (or fail) it.  ``t_free`` is when
+        the previous batch's result was ready: the batch's ``wall_ms``
+        runs from the later of that and its own dispatch start to its own
+        result, so a batch dispatched ahead is not charged for the wait
+        behind its predecessor.  Returns when its result was ready."""
+        batch, bucket, entry, route = (
+            launch.batch, launch.bucket, launch.entry, launch.route
+        )
+        err, logits, report = launch.err, launch.logits, launch.report
+        inj = get_injector()
+        tracer = get_tracer()
+        sentinel_tripped = False
+        if err is None:
+            t_block = time.perf_counter()
+            jax.block_until_ready(logits)
+            tracer.span(_BLOCK, t_block, time.perf_counter(), launch.bid,
+                        bucket=bucket)
+            if inj.enabled:
+                delay = inj.launch_delay(self._launch_name(bucket))
+                if delay:
+                    time.sleep(delay)
+                if route == "fused":
+                    logits = inj.corrupt_output(
+                        self._launch_name(bucket), logits
+                    )
+            if self.config.output_sentinel and not np.isfinite(
+                np.asarray(logits, dtype=np.float32)
+            ).all():
+                sentinel_tripped = True
+                with self._lock:
+                    self.resilience["sentinel_trips"] += 1
+                if tracer.enabled:
+                    tracer.bump("serve_sentinel_trip")
+                    tracer.record_event(
+                        "serve_sentinel",
+                        model=self.graph.name, bucket=bucket,
+                        route=route, action="reference_retry",
+                    )
+                logits = self._run_route("reference", entry, launch.x_dev)[0]
+                jax.block_until_ready(logits)
+        t_ready = time.perf_counter()
+        wall_ms = (t_ready - max(launch.t0, t_free)) * 1e3
+        wd_tripped = False
+        if err is None and self.config.watchdog_factor is not None:
+            thresh_ms = self._watchdog_threshold_ms(bucket, entry)
+            if (thresh_ms is not None
+                    and wall_ms > self.config.watchdog_factor * thresh_ms):
+                wd_tripped = True
+                with self._lock:
+                    self.resilience["watchdog_trips"] += 1
+                if tracer.enabled:
+                    tracer.bump("serve_watchdog_trip")
+                    tracer.record_event(
+                        "serve_watchdog",
+                        model=self.graph.name, bucket=bucket,
+                        wall_ms=wall_ms,
+                        threshold_ms=self.config.watchdog_factor * thresh_ms,
+                        route=route,
+                    )
+        breaker = launch.breaker
+        if breaker is not None and route == "fused":
+            degraded = report is not None and report.degraded
+            if err is not None or wd_tripped or sentinel_tripped or degraded:
+                breaker.record_failure(
+                    rung=self._pin_rung(report, sentinel_tripped)
+                )
+            else:
+                breaker.record_success()
+            self._flush_breaker(bucket, breaker)
+        if err is not None:
+            self._fail_batch(batch, bucket, err, wall_ms)
+        else:
+            t_rec = time.perf_counter()
+            self._record(
+                batch, bucket, logits, wall_ms,
+                calibrate=not (wd_tripped or sentinel_tripped),
+            )
+            tracer.span(_RECORD, t_rec, time.perf_counter(), launch.bid,
+                        bucket=bucket, rows=sum(r.rows for r in batch))
+        return t_ready
+
     def drain(self) -> list[RequestResult]:
         """Execute the queue to empty; returns the drained batches' results
         in completion order (failed batches included, with typed errors).
 
-        The loop is the double-buffered pipeline: dispatch bucket ``n``
-        (jax runs it asynchronously), immediately stage bucket ``n+1``'s
-        padded host batch onto the device, then block on ``n`` — the
-        ``n+1`` copy rides under ``n``'s compute, the host analogue of the
-        kernel's revolving input prefetch.  Around that PR 9 core sit the
-        resilience hooks (each a no-op unless configured/armed): injected
-        queue stalls, breaker routing, the slow-launch delay, the output
-        sentinel, the watchdog, and typed batch failure."""
+        The loop keeps two forwards in flight.  In steady state it
+        dispatches batch ``n+1`` (jax enqueues it behind ``n`` on the
+        device), then blocks on ``n`` and records it, then stages ``n+2``
+        (pad + ``device_put``) while ``n+1`` computes — so record, staging
+        and the next dispatch all run under device time, and the cycle
+        tends to ``max(host, device)`` rather than their sum.  Results
+        still complete in batch order.  It falls back to depth 1 —
+        dispatch ``n``, stage ``n+1``, block on and record ``n``, today's
+        double-buffered input stage — wherever the loop observes that it
+        must: no next batch is queued (a one-in-flight caller), a typed
+        dispatch failure, or :meth:`_may_dispatch_ahead` says ``n``'s
+        outcome could change ``n+1``'s route (a circuit breaker, the
+        guarded ladder, an armed fault injector).  A batch's ``wall_ms``
+        is its own service time: from the later of its dispatch start and
+        its predecessor's result, to its own result.  Around the core sit
+        the resilience hooks (each a no-op unless configured/armed):
+        injected queue stalls, breaker routing, the slow-launch delay, the
+        output sentinel, the watchdog, and typed batch failure.  It returns
+        only with nothing in flight."""
         completed: list[RequestResult] = []
         inj = get_injector()
+
+        def finish(launch: _Launch, t_free: float) -> float:
+            t_ready = self._finish(launch, t_free)
+            completed.extend(self.results[r.id] for r in launch.batch)
+            return t_ready
+
         with self._drain_lock:
             staged = self._next_staged()
+            ahead: _Launch | None = None  # dispatched, not yet blocked on
+            t_free = -float("inf")  # when the previous result was ready
             while staged is not None:
                 if inj.enabled and inj.queue_stalled():
                     with self._lock:
@@ -868,99 +1037,18 @@ class ServingEngine:
                         )
                     time.sleep(0.001)
                     continue
-                batch, bucket, entry, x_dev, bid = staged
-                breaker = self._breaker(bucket)
-                route = "fused"
-                if breaker is not None and not breaker.allow():
-                    route = breaker.pinned_rung or "reference"
-                tracer = get_tracer()
-                t0 = time.perf_counter()
-                err: RobustError | None = None
-                logits = report = None
                 try:
-                    logits, report = self._run_route(route, entry, x_dev)
-                except RobustError as e:
-                    err = e
-                tracer.span(_DISPATCH, t0, time.perf_counter(), bid,
-                            bucket=bucket, route=_ROUTES[route])
-                staged_next = self._next_staged()
-                sentinel_tripped = False
-                if err is None:
-                    t_block = time.perf_counter()
-                    jax.block_until_ready(logits)
-                    tracer.span(_BLOCK, t_block, time.perf_counter(), bid,
-                                bucket=bucket)
-                    if inj.enabled:
-                        delay = inj.launch_delay(self._launch_name(bucket))
-                        if delay:
-                            time.sleep(delay)
-                        if route == "fused":
-                            logits = inj.corrupt_output(
-                                self._launch_name(bucket), logits
-                            )
-                    if self.config.output_sentinel and not np.isfinite(
-                        np.asarray(logits, dtype=np.float32)
-                    ).all():
-                        sentinel_tripped = True
-                        with self._lock:
-                            self.resilience["sentinel_trips"] += 1
-                        tracer = get_tracer()
-                        if tracer.enabled:
-                            tracer.bump("serve_sentinel_trip")
-                            tracer.record_event(
-                                "serve_sentinel",
-                                model=self.graph.name, bucket=bucket,
-                                route=route, action="reference_retry",
-                            )
-                        logits = self._run_route(
-                            "reference", entry, x_dev
-                        )[0]
-                        jax.block_until_ready(logits)
-                wall_ms = (time.perf_counter() - t0) * 1e3
-                wd_tripped = False
-                if (err is None
-                        and self.config.watchdog_factor is not None):
-                    thresh_ms = self._watchdog_threshold_ms(bucket, entry)
-                    if (thresh_ms is not None and wall_ms
-                            > self.config.watchdog_factor * thresh_ms):
-                        wd_tripped = True
-                        with self._lock:
-                            self.resilience["watchdog_trips"] += 1
-                        tracer = get_tracer()
-                        if tracer.enabled:
-                            tracer.bump("serve_watchdog_trip")
-                            tracer.record_event(
-                                "serve_watchdog",
-                                model=self.graph.name, bucket=bucket,
-                                wall_ms=wall_ms,
-                                threshold_ms=(
-                                    self.config.watchdog_factor * thresh_ms
-                                ),
-                                route=route,
-                            )
-                if breaker is not None and route == "fused":
-                    degraded = report is not None and report.degraded
-                    if (err is not None or wd_tripped or sentinel_tripped
-                            or degraded):
-                        breaker.record_failure(
-                            rung=self._pin_rung(report, sentinel_tripped)
-                        )
-                    else:
-                        breaker.record_success()
-                    self._flush_breaker(bucket, breaker)
-                if err is not None:
-                    self._fail_batch(batch, bucket, err, wall_ms)
+                    launch = self._dispatch(staged, ahead is not None)
+                finally:  # the batch in flight lands first, even on a raise
+                    if ahead is not None:
+                        t_free = finish(ahead, t_free)
+                        ahead = None
+                staged = self._next_staged()
+                if (staged is not None and launch.err is None
+                        and self._may_dispatch_ahead(launch.bucket)):
+                    ahead = launch
                 else:
-                    t_rec = time.perf_counter()
-                    self._record(
-                        batch, bucket, logits, wall_ms,
-                        calibrate=not (wd_tripped or sentinel_tripped),
-                    )
-                    tracer.span(_RECORD, t_rec, time.perf_counter(), bid,
-                                bucket=bucket,
-                                rows=sum(r.rows for r in batch))
-                completed.extend(self.results[r.id] for r in batch)
-                staged = staged_next
+                    t_free = finish(launch, t_free)
         return completed
 
     def serve(self, xs) -> list[RequestResult]:
@@ -982,7 +1070,9 @@ class ServingEngine:
     def summary(self) -> dict:
         """The bucket/SLO/throughput table as one JSON-safe dict — modeled
         (``slo_us``/``steady_us``/``modeled_cycles``) next to measured
-        (``p50_ms``/``p95_ms``/``imgs_per_s``) per bucket, plus the serve
+        (``p50_ms``/``p95_ms``/``imgs_per_s``) per bucket, the drain
+        loop's ``dispatch`` counts (fused batches, and those dispatched
+        while the previous one was in flight), the serve
         and partition cache counters and the resilience section (shed /
         expired / failed / watchdog / sentinel / stall counts and one
         breaker snapshot per bucket) — DESIGN.md §14/§15's observable
@@ -1035,6 +1125,7 @@ class ServingEngine:
                     1 for r in self.results.values() if r.ok
                 ),
                 "rejected": self.rejected,
+                "dispatch": dict(self.dispatches),
                 "images": total_images,
                 "imgs_per_s": (
                     total_images / (total_wall_ms / 1e3)

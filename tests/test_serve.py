@@ -458,6 +458,117 @@ class TestHostSpans:
 
 
 # ---------------------------------------------------------------------------
+# two forwards in flight: dispatch n+1 before blocking on n
+# ---------------------------------------------------------------------------
+
+
+def _by_batch(spans, name: str) -> dict:
+    return {sp.id: sp for sp in spans if sp.name == name}
+
+
+class TestDispatchAhead:
+    @pytest.mark.parametrize("overrides, requests, ahead", [
+        ({}, 3, 2),  # default config, a next batch queued: two in flight
+        ({}, 1, 0),  # one request: never a batch n+1 to dispatch ahead
+        ({"breaker_threshold": 3}, 3, 0),  # n's outcome may reroute n+1
+        ({"guarded": True}, 3, 0),  # the ladder runs eagerly on the host
+    ], ids=["default", "one-request", "breaker", "guarded"])
+    def test_dispatch_order(self, overrides, requests, ahead):
+        """Where a next batch is queued and nothing needs n's outcome
+        first, batch n+1's dispatch starts before batch n's block; a
+        breaker, the guarded ladder or an empty queue keep depth 1, where
+        each dispatch starts after the previous block ended."""
+        eng = _engine(**overrides)
+        eng.serve([_images(4, seed=9)])  # compiles outside the collection
+        before = dict(eng.summary()["dispatch"])
+        with tracing() as col:
+            t0 = time.perf_counter()
+            eng.submit_many([_images(4, seed=s) for s in range(requests)])
+            done = eng.drain()
+            elapsed_ms = (time.perf_counter() - t0) * 1e3
+        assert [r.id for r in done] == sorted(r.id for r in done)
+        assert all(r.ok for r in done) and len(done) == requests
+        assert col.counters.get("serve.batches", 0) == requests
+        assert col.counters.get("serve.dispatched_ahead", 0) == ahead
+        after = eng.summary()["dispatch"]
+        assert after["batches"] - before["batches"] == requests
+        assert (after["dispatched_ahead"] - before["dispatched_ahead"]
+                == ahead)
+        spans = col.spans_between()
+        dispatch = _by_batch(spans, "engine.dispatch")
+        block = _by_batch(spans, "engine.block")
+        record = _by_batch(spans, "engine.record")
+        bids = sorted(dispatch)
+        assert len(bids) == requests
+        for n, m in zip(bids, bids[1:]):
+            if ahead:
+                assert dispatch[m].start_s < block[n].start_s
+            else:
+                assert dispatch[m].start_s >= block[n].end_s
+            assert record[n].end_s <= record[m].start_s  # batch order
+        # each wall is the batch's own service time: the walls of
+        # consecutive batches never overlap, so they fit in the drain
+        walls = eng._stats[4].batch_walls_ms[-requests:]
+        assert sum(walls) <= elapsed_ms
+
+    def test_logits_bitwise_equal_to_each_request_alone(self):
+        """Pipelined batches run the same executable on the same inputs:
+        every request's logits equal serving it alone, bit for bit, and
+        the drain returns them in completion (= batch) order."""
+        xs = [_images(4, seed=20 + s) for s in range(4)]
+        alone = [_engine().serve([x])[0] for x in xs]
+        eng = _engine()
+        ids = eng.submit_many(xs)
+        done = eng.drain()
+        assert [r.id for r in done] == ids
+        assert eng.summary()["dispatch"]["dispatched_ahead"] == len(xs) - 1
+        for r, a in zip(done, alone):
+            assert r.ok and r.bucket == a.bucket == 4
+            assert np.array_equal(r.logits, a.logits)
+
+    @pytest.mark.parametrize("typed", [True, False], ids=["typed", "untyped"])
+    def test_dispatch_failure_lands_after_the_batch_in_flight(
+            self, monkeypatch, typed):
+        """An error from batch n+1's dispatch while n is in flight: n is
+        still blocked on and recorded ok first.  A RobustError then fails
+        n+1 typed, the order holds and the drain returns with nothing in
+        flight; any other error propagates once n has landed."""
+        from repro.robust.errors import PlanError
+
+        eng = _engine()
+        eng.serve([_images(4, seed=9)])
+        run_route = eng._run_route
+        calls = []
+
+        def failing_second(route, entry, x_dev):
+            calls.append(route)
+            if len(calls) == 2:
+                raise (PlanError if typed else RuntimeError)("dispatch failed")
+            return run_route(route, entry, x_dev)
+
+        monkeypatch.setattr(eng, "_run_route", failing_second)
+        order = []
+        eng.add_listener(lambda res: order.append(res.id))
+        ids = eng.submit_many([_images(4, seed=s) for s in range(3)])
+        if not typed:
+            with pytest.raises(RuntimeError, match="dispatch failed"):
+                eng.drain()
+            assert order == ids[:1] and eng.results[ids[0]].ok
+            return
+        with tracing() as col:
+            done = eng.drain()
+        assert order == ids and [r.id for r in done] == ids
+        assert [r.ok for r in done] == [True, False, True]
+        assert isinstance(done[1].error, PlanError)
+        assert done[1].bucket == 4 and eng.resilience["failed"] == 1
+        # batch 1 was dispatched ahead of batch 0; batch 2 followed a
+        # failed launch, so nothing was in flight when it was dispatched
+        assert col.counters["serve.dispatched_ahead"] == 1
+        assert not eng.queue
+        assert all(eng.results[i] is r for i, r in zip(ids, done))
+
+
+# ---------------------------------------------------------------------------
 # batch-aware costing + serving cost model
 # ---------------------------------------------------------------------------
 
