@@ -1,3 +1,1 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels: ``fused_conv`` (fused conv pyramids), ``online_sop`` (SOP + END)."""

@@ -8,10 +8,9 @@ next step, which keeps SGD/Adam convergence (Seide et al., 1-bit SGD line of
 work).
 
 Composition: under ``jit`` the all-reduce is implicit in the sharded grad
-computation, so ``compress -> psum-in-int8 -> decompress`` is expressed as a
-custom reduction in :func:`compressed_mean` for shard_map-style use, and as a
-quantize/dequantize pair around the optimizer update for pjit use (XLA then
-moves int8, not fp32, across the 'pod' axis for the terms it reduces late).
+computation, so compression is a quantize/dequantize pair around the
+optimizer update (XLA then moves int8, not fp32, across the 'pod' axis for
+the terms it reduces late).
 """
 
 from __future__ import annotations
@@ -66,12 +65,3 @@ def compress_grads(grads, state: CompressState):
     err = jax.tree.map(lambda t: t[1], out, is_leaf=lambda x: isinstance(x, tuple))
     return deq, CompressState(error=err)
 
-
-def compressed_mean(g: jnp.ndarray, axis_name: str):
-    """shard_map building block: int8 all-reduce mean over ``axis_name``."""
-    q, scale = _quantize(g.astype(jnp.float32))
-    # reduce in int32 to avoid overflow, carry scales alongside
-    total = jax.lax.psum(q.astype(jnp.int32) * scale, axis_name)
-    n = jax.lax.psum(jnp.ones((), jnp.float32), axis_name)
-    flat = (total / n).reshape(-1)[: g.size]
-    return flat.reshape(g.shape).astype(g.dtype)

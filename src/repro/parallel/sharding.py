@@ -1,16 +1,11 @@
 """Logical-axis sharding rules: DP (fsdp) x TP (tensor) x EP (expert) x pods.
 
-Every parameter/cache leaf carries a tuple of logical axis names (see
-:mod:`repro.models.params`).  Rules map logical names to mesh axes; the
-resolver turns (shape, axes, mesh) into a NamedSharding with two safety
-valves needed by real architectures:
+Every parameter/cache leaf carries a tuple of logical axis names.  Rules
+map logical names to mesh axes; the resolver turns (shape, axes, mesh) into
+a PartitionSpec with two safety valves needed by real architectures:
 
 * divisibility fallback — a dim that does not divide by its mesh-axis extent
-  drops that mapping (replicates) rather than relying on GSPMD padding;
-  e.g. glm4's 2 KV heads cannot shard 16-way, arctic's 56 heads cannot
-  either, minicpm3's 73448 vocab divides by neither 16 nor 32.  For heads we
-  deliberately accept replication of the (small) KV projections instead of
-  padded sharding so the roofline's collective bytes stay honest.
+  drops that mapping (replicates) rather than relying on GSPMD padding.
 * one-mesh-axis-once — if two logical dims of one tensor resolve to the same
   mesh axis, the later one is dropped (a mesh axis can shard one dim only).
 
@@ -18,7 +13,6 @@ Default rule set (production mesh (pod, data, model)):
 
   batch/fsdp      -> ('pod', 'data')   # DP + FSDP parameter sharding
   tensor-ish dims -> ('model',)        # TP: heads / mlp / vocab / experts
-  cache_seq       -> ('data',) for long-context decode (sequence sharding)
 """
 
 from __future__ import annotations
@@ -26,9 +20,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any
 
-import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import Mesh, PartitionSpec
 
 
 DEFAULT_RULES: dict[str, tuple[str, ...]] = {
@@ -44,7 +37,7 @@ DEFAULT_RULES: dict[str, tuple[str, ...]] = {
     "experts": ("model",),
     "lora": ("model",),
     # serving
-    "cache_seq": (),  # overridden to ('data',) for long-context decode
+    "cache_seq": (),
     # activations
     "seq": (),
     "act_embed": (),
@@ -92,45 +85,3 @@ def partition_spec(
     while entries and entries[-1] is None:
         entries.pop()  # trailing Nones are implicit
     return PartitionSpec(*entries)
-
-
-def spec_shardings(spec_tree, mesh: Mesh, rules: ShardingRules):
-    """P-spec tree -> NamedSharding tree (params and caches alike)."""
-    from repro.models.params import P
-
-    return jax.tree.map(
-        lambda s: NamedSharding(mesh, partition_spec(s.shape, s.axes, mesh, rules)),
-        spec_tree,
-        is_leaf=lambda x: isinstance(x, P),
-    )
-
-
-def batch_sharding(mesh: Mesh, rules: ShardingRules, ndim: int = 2):
-    """Sharding for (B, S, ...) token batches: batch over DP axes."""
-    names = rules.mesh_axes_for("batch", mesh)
-    spec = PartitionSpec(names if len(names) > 1 else (names[0] if names else None))
-    return NamedSharding(mesh, spec)
-
-
-def shard_batch_spec(
-    shape: tuple[int, ...], mesh: Mesh, rules: ShardingRules,
-    logical: tuple[str | None, ...],
-) -> NamedSharding:
-    return NamedSharding(mesh, partition_spec(shape, logical, mesh, rules))
-
-
-# canonical rule variants -----------------------------------------------------
-
-def rules_for(step: str, *, long_context: bool = False) -> ShardingRules:
-    """Rule set per step kind (train / prefill / decode)."""
-    r = ShardingRules()
-    if step == "decode":
-        if long_context:
-            # batch=1: shard the cache sequence over data AND model
-            # (context parallelism); the pod axis replicates (B=1)
-            return r.override(cache_seq=("data", "model"), batch=("pod",))
-        # kv_heads rarely divide the 16-way model axis; shard the cache
-        # sequence over 'model' instead (context-parallel serving) — the
-        # resolver gives 'model' to cache_seq first, kv_heads then drops
-        return r.override(cache_seq=("model",))
-    return r

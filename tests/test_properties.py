@@ -1,15 +1,7 @@
-"""System-invariant property tests (hypothesis).
-
-* random fusion pyramids: fused tile execution == monolithic execution
-* MoE dispatch: capacity accounting, routing exactness without drops
-* chunked CE == naive CE
-* fused_conv kernel VMEM budget honored for planned configs
-"""
-
-import dataclasses
+"""System-invariant property test (hypothesis): on random fusion pyramids,
+fused tile execution == monolithic execution."""
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from hypothesis import given, settings, strategies as st
 
@@ -74,74 +66,3 @@ class TestFusedExecutorProperty:
             np.asarray(fused), np.asarray(ref), atol=2e-4,
             err_msg=f"spec={spec} region={region}",
         )
-
-
-class TestMoEInvariants:
-    def _route(self, T, E, k, capacity, seed=0):
-        from repro.models.moe import dispatch_combine, route_topk
-
-        key = jax.random.PRNGKey(seed)
-        x = jax.random.normal(key, (1, T, 8))
-        logits = jax.random.normal(jax.random.PRNGKey(seed + 1), (1, T, E))
-        idx, w = route_topk(logits, k)
-        disp, comb = dispatch_combine(x, idx, w, E, capacity)
-        return x, idx, w, disp, comb
-
-    @given(st.integers(8, 64), st.integers(2, 8), st.integers(1, 2),
-           st.integers(0, 20))
-    @settings(max_examples=30, deadline=None)
-    def test_capacity_never_exceeded(self, T, E, k, seed):
-        """Each (expert, slot) is claimed by at most one token: the combine
-        tensor (G,T,E,C) has at most one nonzero along T per (e,c)."""
-        cap = max(1, T * k // E)
-        x, idx, w, disp, comb = self._route(T, E, k, cap, seed)
-        occupancy = (np.asarray(comb) > 1e-9).sum(axis=1)  # (G, E, C)
-        assert occupancy.max() <= 1
-
-    @given(st.integers(8, 48), st.integers(2, 8), st.integers(1, 2),
-           st.integers(0, 10))
-    @settings(max_examples=30, deadline=None)
-    def test_no_drops_means_exact_routing(self, T, E, k, seed):
-        """With capacity >= T*k no token is dropped: combine weights per
-        token sum to 1 (softmax over selected experts)."""
-        x, idx, w, disp, comb = self._route(T, E, k, T * k, seed)
-        weight_per_token = np.asarray(comb.sum(axis=(2, 3)))  # (G, T)
-        np.testing.assert_allclose(weight_per_token, 1.0, atol=1e-5)
-
-    def test_dropped_tokens_lose_weight(self):
-        x, idx, w, disp, comb = self._route(64, 2, 2, 1, seed=3)
-        weight_per_token = np.asarray(comb.sum(axis=(2, 3)))
-        assert weight_per_token.min() < 0.999  # someone got dropped
-
-
-class TestChunkedCE:
-    def test_matches_naive_ce(self):
-        from repro.configs import get_config
-        from repro.models.model import chunked_ce, hidden_forward, init_params, logits_fn
-
-        cfg = dataclasses.replace(get_config("deepseek_7b").reduced(), dtype="float32")
-        params = init_params(cfg, jax.random.PRNGKey(0))
-        toks = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0, cfg.vocab)
-        hidden, _, _ = hidden_forward(cfg, params, toks[:, :-1])
-        targets = toks[:, 1:]
-        loss_chunked = chunked_ce(cfg, params, hidden, targets, chunk=8)
-        logits = logits_fn(cfg, params, hidden).astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        naive = -jnp.take_along_axis(logp, targets[..., None], axis=-1).mean()
-        np.testing.assert_allclose(
-            float(loss_chunked), float(naive), rtol=1e-5
-        )
-
-    def test_chunk_size_invariance(self):
-        from repro.configs import get_config
-        from repro.models.model import chunked_ce, hidden_forward, init_params
-
-        cfg = dataclasses.replace(get_config("phi4_mini_3_8b").reduced(), dtype="float32")
-        params = init_params(cfg, jax.random.PRNGKey(0))
-        toks = jax.random.randint(jax.random.PRNGKey(1), (2, 25), 0, cfg.vocab)
-        hidden, _, _ = hidden_forward(cfg, params, toks[:, :-1])
-        losses = [
-            float(chunked_ce(cfg, params, hidden, toks[:, 1:], chunk=c))
-            for c in (3, 8, 24)
-        ]
-        np.testing.assert_allclose(losses, losses[0], rtol=1e-5)

@@ -84,6 +84,15 @@ class TestData:
         t = batch_at(cfg, 3)["tokens"]
         assert t.min() >= 0 and t.max() < 311
 
+    def test_bigram_structure_present(self):
+        """The synthetic stream embeds a learnable bigram rule: about half
+        the transitions follow it."""
+        cfg = DataConfig(vocab=1000, seq_len=512, global_batch=4)
+        t = batch_at(cfg, 0)["tokens"]
+        pred = (t[:, :-1] * 31 + 7) % cfg.vocab
+        frac = (t[:, 1:] == pred).mean()
+        assert 0.35 < frac < 0.65
+
 
 class TestCheckpointer:
     def test_save_restore_roundtrip(self):
