@@ -167,6 +167,18 @@ def patch_lanes(level) -> int:
     return kq * kq * level.S * level.S * level.n_in
 
 
+def taps_per_pass(level) -> int:
+    """Adjacent taps of one kernel row that one MXU pass of conv ``level``
+    contracts together.  A level whose input channel block
+    (:func:`channel_blocks`) fills at most half of the MXU's 128-deep
+    contraction reads ``g`` taps' windows side by side as one ``(W, g *
+    lanes)`` operand against their stacked weight blocks, so a 64-channel
+    3x3 takes 6 passes a row, not 9.  1 for every level of 128 or more
+    lanes and every ``K = 1`` level (a patch-form level 0 among them).
+    Reads only shapes."""
+    return max(1, min(level.K, LANES // channel_blocks(level.n_in)[1]))
+
+
 def patch_spec(spec: FusionSpec) -> FusionSpec:
     """The spec the kernel runs: ``spec`` itself, or, when level 0 is a
     ``K > 1`` conv whose patch (:func:`patch_lanes`) fits
@@ -348,6 +360,22 @@ class TileProgram:
         cb, cl = channel_blocks(self.levels[0].n_in)
         return cb * (padded_lanes(cl) if cb == 1 else cl)
 
+    def folds(self) -> tuple[int, ...]:
+        """Per level, the taps one MXU pass contracts: :func:`taps_per_pass`
+        for a level fed by another level of the launch, 1 for level 0 (its
+        input comes from HBM, where the copies would cost an extra pass
+        over the image: measured slower, PERF.md §6)."""
+        return (1,) + tuple(taps_per_pass(p) for p in self.levels[1:])
+
+    def copies(self) -> tuple[int, ...]:
+        """Per level, how many times its output tile holds its channels
+        side by side in the lanes, copy ``t`` shifted ``t`` columns on: the
+        :meth:`folds` of the level that reads the tile, which then loads
+        that many adjacent taps' windows at once (1 for the last level,
+        whose output leaves the launch).  The copies fill lanes a
+        64-or-fewer-channel tile pads to anyway: no buffer grows."""
+        return self.folds()[1:] + (1,)
+
     def staged_levels(self) -> tuple[bool, ...]:
         """Per level: whether its input rows go through the f32 row stage.
         Mosaic reads a strided W window only from a 32-bit, 128-lane buffer,
@@ -401,6 +429,7 @@ class TileProgram:
                 for li in staged
             )
             bufs.append(("stage", (width, lanes), "float32"))
+        copies = self.copies()
         for li, p in enumerate(levels):
             cb, cl = channel_blocks(ct if li == q - 1 else p.n_out)
             if p.pool is not None:
@@ -410,13 +439,19 @@ class TileProgram:
                     "float32",
                 ))
             if li < q - 1:
-                bufs.append(("mid", (cb, p.pool_out, p.pool_out, cl), cdt))
+                bufs.append(
+                    ("mid", (cb, p.pool_out, p.pool_out, cl * copies[li]), cdt)
+                )
         region = self.out_region
         bufs.append(("out_block", (2, region, region, ct), cdt))
         bufs.append(("skip_block", (2, 1, q), "int32"))
         for li, p in enumerate(levels):
             tiled = li == q - 1 and c_tiles > 1
-            bufs.append(("bias", (c_tiles, 1, ct) if tiled else (1, p.n_out), cdt))
+            bufs.append((
+                "bias",
+                (c_tiles, 1, ct) if tiled else (1, p.n_out * copies[li]),
+                cdt,
+            ))
             if not streamed:
                 shape = (p.K, p.K, p.n_in, padded_lanes(ct if tiled else p.n_out))
                 bufs.append(("weights", (c_tiles, *shape) if tiled else shape, cdt))

@@ -39,7 +39,11 @@ Per grid cell (b, i, j):
     start();wait() path — bit-identical, only the movement schedule differs;
   * conv levels run one output row at a time from VMEM refs: K*K
     ``(W, Cin) @ (Cin, Cout)`` MXU dots per row, f32-accumulated, each
-    reading its input window with a (possibly strided) ref read — the WPU
+    reading its input window with a (possibly strided) ref read (a level of
+    64 or fewer input lanes fed by another level reads
+    :func:`~repro.core.program.taps_per_pass` adjacent taps' windows as one
+    operand, from a tile that holds its channels that many times, each copy
+    shifted a column on) — the WPU
     array of Fig. 5 maps onto MXU tiles (a narrow-input level 0 arrives in
     patch form, :func:`~repro.core.program.patch_spec`: K = 1 over its patch
     tensor, one dot per row).  Tiles are channel-blocked
@@ -171,21 +175,33 @@ def _conv_level(
     conv_out,
     emit,
     cdt,
+    fold: int = 1,
+    copies: int = 1,
 ):
     """One conv level, its activation (``prog.relu``) and its pool
     epilogue, one output row at a time.
 
     ``w_at(ki, kj, c, o)`` gives tap ``(ki, kj)``'s ``(lanes, 128)`` weights
     for input channel block ``c`` and 128-lane output block ``o``; ``bias``
-    is ``(1, n_out)`` f32.
+    is ``(1, copies * n_out)`` f32.  With ``copies > 1`` the level's weights
+    and bias repeat its ``n_out`` channels that many times across the
+    output lanes, and every row it emits holds them so.
     ``dots=False`` is the closed form of an all-zero input (the conv output
     is the bias everywhere) — bit-identical to the live path, which adds the
     bias to an exact-zero accumulator.  Rows are masked to the level's valid
     range, pooled from the f32 ``conv_out`` buffer, masked again, cast once,
     and handed to ``emit(r, c, value)``.  Returns the max of the emitted
-    values: the END predicate of the next level."""
+    values: the END predicate of the next level.
+
+    ``fold`` (:meth:`~repro.core.program.TileProgram.folds`) adjacent
+    taps of a kernel row share one MXU pass.  The input tile holds its channels
+    ``g`` times side by side, copy ``t`` shifted ``t`` columns on (the
+    producer's ``copies``), so one window load is the group's operand:
+    lanes ``[t * Cin, (t + 1) * Cin)`` hold tap ``(ki, kj + t)``'s
+    window, with no lane moves."""
     cb_in, cl_in = channel_blocks(prog.n_in)
-    cb, cl = channel_blocks(n_out)
+    width = copies * n_out
+    cb, cl = (1, width) if copies > 1 else channel_blocks(n_out)
     n_blocks = padded_lanes(n_out) // LANES
     # Mosaic multiplies f32 operands in one bf16 pass unless asked for full
     # f32 passes (bf16 operands are exact in one pass, and refuse HIGHEST)
@@ -194,34 +210,45 @@ def _conv_level(
     g0 = prog.o_base + idx[0] * prog.o_step
     g1 = prog.o_base + idx[1] * prog.o_step
     col_ok = _in_range(
-        jax.lax.broadcasted_iota(jnp.int32, (W, n_out), 0) + g1, prog.valid
+        jax.lax.broadcasted_iota(jnp.int32, (W, width), 0) + g1, prog.valid
     )
 
-    def patch(c, r, ki, kj):
+    def patch(c, r, ki, kj, lanes=cl_in):
         if stage is None:
-            return src.window(c, r + ki, src.off + kj, W)[:, :cl_in]
+            return src.window(c, r + ki, src.off + kj, W)[:, :lanes]
         rows = pl.ds(src.off + kj, W, stride=S) if S > 1 else pl.ds(src.off + kj, W)
-        return stage[rows, :][:, :cl_in]
+        return stage[rows, :][:, :lanes]
+
+    def one_pass(accs, c, r, taps):
+        """Add one MXU pass over ``taps``, adjacent in one kernel row: their
+        windows, one load from the input's copies, against their weight
+        blocks stacked on the sublane axis."""
+        lhs = patch(c, r, *taps[0], lanes=len(taps) * cl_in).astype(cdt)
+
+        def rhs(o):
+            ws = [w_at(ki, kj, c, o) for ki, kj in taps]
+            return ws[0] if len(ws) == 1 else jnp.concatenate(ws, axis=0)
+
+        return [
+            a + jnp.dot(lhs, rhs(o), precision=precision,
+                        preferred_element_type=jnp.float32)
+            for o, a in enumerate(accs)
+        ]
 
     def conv_row(r, m):
-        acc = jnp.zeros((W, n_out), jnp.float32)
+        acc = jnp.zeros((W, width), jnp.float32)
         if dots:
             accs = [jnp.zeros((W, LANES), jnp.float32)] * n_blocks
             for ki in range(K):
                 for c in range(cb_in):
                     if stage is not None:
-                        v = src.row(c, r * S + ki)[:, :cl_in]
-                        stage[: v.shape[0], :cl_in] = v.astype(jnp.float32)
-                    for kj in range(K):
-                        lhs = patch(c, r, ki, kj).astype(cdt)
-                        accs = [
-                            a + jnp.dot(lhs, w_at(ki, kj, c, o),
-                                        precision=precision,
-                                        preferred_element_type=jnp.float32)
-                            for o, a in enumerate(accs)
-                        ]
+                        v = src.row(c, r * S + ki)[:, : fold * cl_in]
+                        stage[: v.shape[0], : fold * cl_in] = v.astype(jnp.float32)
+                    for kj in range(0, K, fold):
+                        taps = [(ki, u) for u in range(kj, min(kj + fold, K))]
+                        accs = one_pass(accs, c, r, taps)
             acc = accs[0] if n_blocks == 1 else jnp.concatenate(accs, axis=1)
-            acc = acc[:, :n_out]
+            acc = acc[:, :width]
         acc = acc + bias
         if prog.relu:
             acc = jnp.maximum(acc, 0.0)
@@ -289,6 +316,8 @@ class _Launch:
         ]
         self.mid = bufs.get("mid", [])
         self.staged = program.staged_levels()
+        self.folds = program.folds()
+        self.copies = program.copies()
         self.x_hbm, self.x_sem = x_hbm, x_sem
 
     def x_dma(self, bi, ii, jj, slot) -> _Copies:
@@ -357,7 +386,7 @@ class _Launch:
         return _conv_level(
             src, w_at, bias, self.prog.levels[l], idx, n_out=n_out,
             dots=dots, stage=stage, conv_out=self.conv_out[l], emit=emit,
-            cdt=self.cdt,
+            cdt=self.cdt, fold=self.folds[l], copies=self.copies[l],
         )
 
     def skippable(self, l, end_skip: bool) -> bool:
@@ -366,8 +395,17 @@ class _Launch:
         return end_skip and l > 0 and self.prog.levels[l - 1].relu
 
     def mid_emit(self, l):
+        copies, ref = self.copies[l], self.mid[l]
+
         def emit(r, c, v):
-            self.mid[l][c, r] = v
+            ref[c, r] = v
+            # copy t of the channels holds the row t columns on, so that the
+            # next level reads t + 1 adjacent taps' windows in one load
+            n, lanes = v.shape[0], v.shape[1] // copies
+            for t in range(1, copies):
+                ref[c, r, pl.ds(0, n - t), pl.ds(t * lanes, lanes)] = (
+                    v[t:, t * lanes : (t + 1) * lanes]
+                )
         return emit
 
 
@@ -762,6 +800,14 @@ def fused_pyramid_pallas(
             last.K, last.K, last.n_in, c_tiles, ct
         ).transpose(3, 0, 1, 2, 4)
         biases[-1] = biases[-1].reshape(c_tiles, 1, ct)
+    # a level whose tile feeds a folded level computes its channels
+    # `copies` times over, in the lanes it pads to anyway
+    copies = program.copies()
+    weights = [
+        jnp.concatenate([w] * k, axis=-1) if k > 1 else w
+        for w, k in zip(weights, copies)
+    ]
+    biases = [jnp.tile(b, (1, k)) if k > 1 else b for b, k in zip(biases, copies)]
     weights = [
         jnp.pad(w, [(0, 0)] * (w.ndim - 1) + [(0, padded_lanes(n) - n)])
         for w, n in ((w, w.shape[-1]) for w in weights)

@@ -46,11 +46,13 @@ from .graph import Graph, Segment, fusable_segments, infer_shapes
 INFEASIBLE = (float("inf"), float("inf"))
 
 # tracer counters bumped as each plan is built: pyramids planned with
-# level 0 in patch form; per-image MACs of every conv level planned; and
-# those in pyramids of two or more conv levels
+# level 0 in patch form; per-image MACs of every conv level planned; those
+# in pyramids of two or more conv levels; and those of conv levels whose
+# kernel contracts two or more taps a pass (core/program TileProgram.folds)
 PATCH_LEVELS = "fused.patch_levels"
 CONV_MACS = "fused.conv_macs"
 CHAINED_CONV_MACS = "fused.chained_conv_macs"
+FOLDED_CONV_MACS = "fused.folded_conv_macs"
 
 
 @dataclass(frozen=True)
@@ -268,13 +270,14 @@ def brute_force_segment(
 # ---------------------------------------------------------------------------
 
 
-def conv_macs(spec: FusionSpec) -> int:
-    """Multiply-adds of the spec's conv levels for one image."""
+def conv_macs(spec: FusionSpec, where=lambda lvl: True) -> int:
+    """Multiply-adds of the spec's conv levels (those ``where`` accepts)
+    for one image."""
     sizes = spec.feature_sizes()
     return sum(
         lvl.K * lvl.K * lvl.n_in * lvl.n_out * sizes[l + 1] ** 2
         for l, lvl in enumerate(spec.levels)
-        if lvl.kind == "conv"
+        if lvl.kind == "conv" and where(lvl)
     )
 
 
@@ -284,8 +287,10 @@ def _segment_pyramids(
     """Attach covered node names to each launch, walking the chain.  As the
     plan is built, each pyramid whose level 0 runs in patch form bumps the
     tracer's :data:`PATCH_LEVELS` counter once, every pyramid adds its conv
-    levels' per-image MACs to :data:`CONV_MACS`, and a pyramid of two or
-    more conv levels adds them to :data:`CHAINED_CONV_MACS` too."""
+    levels' per-image MACs to :data:`CONV_MACS`, a pyramid of two or more
+    conv levels adds them to :data:`CHAINED_CONV_MACS` too, and the levels
+    the kernel runs two or more taps a pass add theirs to
+    :data:`FOLDED_CONV_MACS`."""
     out, li = [], 0
     tracer = get_tracer()
     for lp in launches:
@@ -295,6 +300,11 @@ def _segment_pyramids(
         tracer.bump(CONV_MACS, macs)
         if lp.spec.q_convs > 1:
             tracer.bump(CHAINED_CONV_MACS, macs)
+        folds = iter(lp.program.folds())
+        tracer.bump(
+            FOLDED_CONV_MACS,
+            conv_macs(lp.program.kernel_spec, lambda lvl: next(folds) > 1),
+        )
         n_levels = len(lp.spec.levels)
         names = tuple(n.name for n in segment.nodes[li : li + n_levels])
         out.append(PyramidPlan(launch=lp, node_names=names))

@@ -2,9 +2,10 @@
 
 Prints the auto-partition of a zoo model as a per-launch table (covered
 nodes, Q, grid, level-0 form, regime, plan knobs, modeled HBM/VMEM bytes
-with budget headroom, modeled cycles, each conv level's activation), the
-pyramids whose level 0 runs in patch form, and the plan build's conv-MAC
-counters (all conv MACs, and those in pyramids of two or more convs), and
+with budget headroom, modeled cycles, each conv level's taps per MXU pass
+and activation), the pyramids whose level 0 runs in patch form, and the
+plan build's conv-MAC counters (all conv MACs, those in pyramids of two or
+more convs, and those of levels run two or more taps a pass), and
 optionally:
 
 * ``--trace out.json`` — export a Chrome-trace / Perfetto JSON of every
@@ -58,7 +59,8 @@ def plan_table(plan, vmem_budget: int, out=print) -> None:
     out(
         f"{'launch':<26} {'nodes':>5} {'Q':>2} {'grid':>6} {'region':>6} "
         f"{'L0':<6} {'regime':<16} {'x/w/c':>6} {'hbm':>9} {'vmem':>9} "
-        f"{'headroom':>9} {'cycles':>10} {'us':>9}  activations"
+        f"{'headroom':>9} {'cycles':>10} {'us':>9}  {'taps/pass':<12} "
+        "activations"
     )
     for p in plan.pyramids:
         d = p.launch.describe(plan.batch, vmem_budget)
@@ -72,6 +74,7 @@ def plan_table(plan, vmem_budget: int, out=print) -> None:
             f"{_fmt_bytes(d['vmem_headroom_bytes']):>9} "
             f"{d['modeled_cycles']:>10,} "
             f"{d['modeled_cycles'] / DEFAULT_PARAMS.freq_mhz:>9,.1f}  "
+            + f"{' '.join(map(str, p.launch.program.folds())):<12} "
             + " ".join("relu" if lvl.relu else "linear"
                        for lvl in p.spec.levels if lvl.kind == "conv")
         )
@@ -188,6 +191,7 @@ def main(argv: list[str] | None = None) -> int:
     from repro.net.partition import (
         CHAINED_CONV_MACS,
         CONV_MACS,
+        FOLDED_CONV_MACS,
         PATCH_LEVELS,
         auto_partition,
         partition_cache_info,
@@ -232,12 +236,12 @@ def main(argv: list[str] | None = None) -> int:
     graph = MODELS[args.model](**kwargs)
 
     tracer = get_tracer()
-    counters = (PATCH_LEVELS, CONV_MACS, CHAINED_CONV_MACS)
+    counters = (PATCH_LEVELS, CONV_MACS, CHAINED_CONV_MACS, FOLDED_CONV_MACS)
     before = [tracer.counters.get(c, 0) for c in counters]
     plan = auto_partition(
         graph, batch=args.batch, vmem_budget=args.vmem_budget
     )
-    built, macs, chained = (
+    built, macs, chained, folded = (
         tracer.counters.get(c, 0) - b for c, b in zip(counters, before)
     )
     print(
@@ -252,9 +256,11 @@ def main(argv: list[str] | None = None) -> int:
         f"({PATCH_LEVELS} +{built} for this plan's build)"
     )
     share = f" ({100 * chained / macs:.1f}% chained)" if macs else ""
+    fshare = f" ({100 * folded / macs:.1f}% folded)" if macs else ""
     print(
         f"conv MACs per image: {CONV_MACS} +{macs:,}, "
-        f"{CHAINED_CONV_MACS} +{chained:,}{share}"
+        f"{CHAINED_CONV_MACS} +{chained:,}{share}, "
+        f"{FOLDED_CONV_MACS} +{folded:,}{fshare}"
     )
     info = partition_cache_info()
     print(

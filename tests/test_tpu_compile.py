@@ -7,9 +7,10 @@ VMEM than the scoped limit.  These tests compile for a *described* v5e chip
 fails here, at no chip time:
 
 * one fused-pyramid launch of every distinct kind (weight regime, input
-  slots, level strides, pools, compute dtype) the zoo's plans use at their
-  published input sizes, buckets 1 and 8, each compiled with the VMEM
-  limit its plan's budget sets (budget plus Mosaic's headroom);
+  slots, level strides, pools, taps per pass, compute dtype) the zoo's
+  plans use at their published input sizes, buckets 1 and 8, each
+  compiled with the VMEM limit its plan's budget sets (budget plus
+  Mosaic's headroom);
 * the whole ResNet-18 224x224 forward, whose HLO must hold one
   ``tpu_custom_call`` per planned pyramid (no launch left to interpret mode),
   each named after its pyramid (the name a profiler trace shows).
@@ -89,6 +90,7 @@ def _kind(launch, dtype):
         tuple(p.S for p in prog.levels),
         tuple(p.pool for p in prog.levels),
         tuple(p.relu for p in prog.levels),
+        prog.folds(),
         dtype,
     )
 
