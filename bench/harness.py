@@ -3,8 +3,9 @@
 A cell of ``BENCHMARK.json`` names a configuration and a traffic mix; the
 harness finds ``bench/configs/<config>.json``, ``bench/traffic/<mix>.json``
 and, for each metric the cell reports, ``bench/metrics/<reader>.py``
-(``glue_us_per_image.offline`` is read by ``glue_us_per_image.py``).  A
-new cell or metric is new files and new entries, never an edit here.
+(``glue_us_per_image.offline`` is read by ``glue_us_per_image.py``); each
+layer's op is ``bench/ops/<op>.py`` (``bench/model.py``).  A new cell,
+metric or layer op is new files and new entries, never an edit here.
 
 The run: check the device, build the program's graph and check it against
 the configuration's layer list, make weights and images from the seed,
@@ -16,7 +17,6 @@ benchmark's own float32 reference.
 from __future__ import annotations
 
 import gc
-import importlib.util
 import json
 import shutil
 import sys
@@ -29,6 +29,7 @@ from pathlib import Path
 import numpy as np
 
 from bench import client, model, trace
+from bench.registry import BenchError, load_module
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH_DIR = ROOT / "bench"
@@ -45,14 +46,6 @@ EXACT_LIMITS = {"unanswered": 0, "compiles_in_window": 0}
 
 def limits(config: dict) -> dict:
     return {**config["correct"], **EXACT_LIMITS}
-
-
-class BenchError(SystemExit):
-    """A run that cannot measure: it prints no result and exits non-zero."""
-
-    def __init__(self, message: str):
-        print(f"bench: {message}", file=sys.stderr, flush=True)
-        super().__init__(2)
 
 
 def load_spec() -> dict:
@@ -81,14 +74,8 @@ def cell_metrics(spec: dict, cell: str, per_layer: bool) -> list[dict]:
 
 def reader(metric: str):
     """The ``read(ctx)`` function of ``bench/metrics/<reader>.py``."""
-    base = metric.split(".")[0]
-    path = BENCH_DIR / "metrics" / f"{base}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{base}", path)
-    if spec is None or not path.exists():
-        raise BenchError(f"metric {metric}: no reader {path}")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    path = BENCH_DIR / "metrics" / f"{metric.split('.')[0]}.py"
+    return load_module(path, f"metric {metric}").read
 
 
 @dataclass
@@ -154,24 +141,19 @@ def import_program():
 
 
 def check_graph(g, config: dict) -> None:
-    """The program's graph has exactly the configuration's layer list."""
+    """The program's graph has exactly the configuration's layer list: each
+    node's name, op, inputs and the fields its op module names."""
     if (g.input_size, g.in_channels) != (config["input_size"], config["in_channels"]):
         raise BenchError(f"{g.name}: input {g.input_size}x{g.in_channels} differs from the config")
     if g.nodes[0].name != model.INPUT:
         raise BenchError(f"{g.name}: input node {g.nodes[0].name!r}, config says {model.INPUT!r}")
-    have = []
-    for n in g.nodes[1:]:
-        d = {"name": n.name, "op": n.op, "inputs": list(n.inputs)}
-        if n.op in ("conv", "pool"):
-            d.update(K=n.K, S=n.S, pad=n.pad)
-        if n.op in ("conv", "dense"):
-            d.update(n_out=n.n_out, relu=n.relu)
-        have.append(d)
-    for i, (a, b) in enumerate(zip(have, config["layers"])):
-        if a != b:
-            raise BenchError(f"{g.name} layer {i}: program has {a}, config has {b}")
-    if len(have) != len(config["layers"]):
-        raise BenchError(f"{g.name}: {len(have)} layers, config lists {len(config['layers'])}")
+    for i, (n, layer) in enumerate(zip(g.nodes[1:], config["layers"])):
+        have = {"name": n.name, "op": n.op, "inputs": list(n.inputs)}
+        have.update((f, getattr(n, f, None)) for f in model.op(layer["op"]).FIELDS)
+        if have != layer:
+            raise BenchError(f"{g.name} layer {i}: program has {have}, config has {layer}")
+    if len(g.nodes) - 1 != len(config["layers"]):
+        raise BenchError(f"{g.name}: {len(g.nodes) - 1} layers, config lists {len(config['layers'])}")
 
 
 def profile_options():

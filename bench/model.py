@@ -6,25 +6,42 @@ roofline and utilization metrics divide by, the weights drawn from
 ``--seed``, the plain float32 reference that decides ``correct``, and the
 int8 control that the comparison has to reject.
 
-Op semantics follow the published networks: ``conv`` is a 2-D convolution
-with bias and an optional fused ReLU, ``pool`` is a max pool, ``add`` a
-residual sum, ``relu`` a stand-alone activation, ``global_pool`` a spatial
-mean, ``flatten`` a row-major ``(H, W, C)`` flatten and ``dense`` a matrix
-product with bias and optional ReLU.  Feature maps are NHWC.
+Each layer's ``op`` names a module ``bench/ops/<op>.py`` that states the
+op's semantics and defines all that is known of it here:
+
+- ``FIELDS``: the layer keys, beyond ``name``, ``op`` and ``inputs``,
+  that ``harness.check_graph`` compares with the program's node;
+- ``CONV_STACK``: whether the op is part of the conv stack whose least
+  time ``conv_roofline`` takes;
+- ``out_shape(layer, ins)``: the ``(size, channels)`` leaving the layer,
+  from those of its inputs (``size`` 0 is a flat vector);
+- ``param_shapes(layer, ins)``: the shapes of its parameters, ``()`` for
+  none, and ``init(keys, layer, ins)``, which draws them from one key each;
+- ``flops(layer, ins, out)``: its model FLOPs for one image;
+- ``forward(layer, params, xs, int8)``: the float32 reference at
+  ``HIGHEST`` precision, or with ``int8`` its control.
+
+A new op is a new file there.  Feature maps are NHWC.
 """
 
 from __future__ import annotations
 
+import functools
 import json
+import math
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.registry import BenchError, load_module
+
 BENCH_DIR = Path(__file__).resolve().parent
+OPS_DIR = BENCH_DIR / "ops"
 INPUT = "image"  # the name every layer list gives the network input
 DTYPE_BYTES = {"float32": 4, "bfloat16": 2}
+OP_INTERFACE = ("FIELDS", "CONV_STACK", "out_shape", "param_shapes", "flops", "forward")
 
 
 def load_config(name: str) -> dict:
@@ -33,98 +50,109 @@ def load_config(name: str) -> dict:
         return json.load(f)
 
 
+def op(name: str):
+    """The module ``<OPS_DIR>/<name>.py`` that defines the layer op ``name``."""
+    return _load_op(OPS_DIR / f"{name}.py")
+
+
+@functools.cache
+def _load_op(path: Path):
+    mod = load_module(path, f"op {path.stem}")
+    missing = [n for n in OP_INTERFACE if not hasattr(mod, n)]
+    if missing:
+        raise BenchError(f"op {path.stem}: {path} does not define {missing}")
+    return mod
+
+
+def walk(config: dict):
+    """``(layer, op module, input shapes, output shape)`` for every layer
+    in order."""
+    out = {INPUT: (config["input_size"], config["in_channels"])}
+    for layer in config["layers"]:
+        mod = op(layer["op"])
+        ins = [out[i] for i in layer["inputs"]]
+        out[layer["name"]] = mod.out_shape(layer, ins)
+        yield layer, mod, ins, out[layer["name"]]
+
+
 def shapes(config: dict) -> dict[str, tuple[int, int]]:
     """``(size, channels)`` leaving every layer; ``size`` 0 is a flat vector."""
     out = {INPUT: (config["input_size"], config["in_channels"])}
-    for layer in config["layers"]:
-        size, ch = out[layer["inputs"][0]]
-        op = layer["op"]
-        if op in ("conv", "pool"):
-            size = (size + 2 * layer["pad"] - layer["K"]) // layer["S"] + 1
-            if op == "conv":
-                ch = layer["n_out"]
-        elif op == "global_pool":
-            size = 0
-        elif op == "flatten":
-            size, ch = 0, size * size * ch if size else ch
-        elif op == "dense":
-            size, ch = 0, layer["n_out"]
-        out[layer["name"]] = (size, ch)
+    out.update((layer["name"], shape) for layer, _, _, shape in walk(config))
     return out
 
 
 def weight_shapes(config: dict) -> dict[str, tuple[tuple[int, ...], int]]:
-    """``name -> (weight shape, fan_in)`` of every conv and dense layer."""
-    sh = shapes(config)
+    """``name -> (weight shape, fan_in)`` of every layer with parameters;
+    the weight is the first parameter, its fan-in the product of all but
+    its last axis."""
     out = {}
-    for layer in config["layers"]:
-        c_in = sh[layer["inputs"][0]][1]
-        if layer["op"] == "conv":
-            k = layer["K"]
-            out[layer["name"]] = ((k, k, c_in, layer["n_out"]), k * k * c_in)
-        elif layer["op"] == "dense":
-            out[layer["name"]] = ((c_in, layer["n_out"]), c_in)
+    for layer, mod, ins, _ in walk(config):
+        params = mod.param_shapes(layer, ins)
+        if params:
+            out[layer["name"]] = (params[0], math.prod(params[0][:-1]))
     return out
 
 
-def flops_per_image(config: dict, ops: tuple[str, ...] = ("conv", "dense")) -> int:
-    """Multiply-adds times two of the listed layer kinds, for one image."""
-    sh = shapes(config)
-    ws = weight_shapes(config)
-    total = 0
-    for layer in config["layers"]:
-        if layer["op"] not in ops:
-            continue
-        w_shape, _ = ws[layer["name"]]
-        size = sh[layer["name"]][0]
-        macs = int(np.prod(w_shape)) * (size * size if layer["op"] == "conv" else 1)
-        total += 2 * macs
-    return total
+def flops_per_image(config: dict, ops: tuple[str, ...] | None = None) -> int:
+    """Model FLOPs of the listed layer kinds (every kind by default), for
+    one image."""
+    return sum(mod.flops(layer, ins, out) for layer, mod, ins, out in walk(config)
+               if ops is None or layer["op"] in ops)
+
+
+def conv_stack(config: dict) -> list:
+    """``walk``'s entries for the layers of the conv stack, the work whose
+    least time ``conv_roofline`` takes."""
+    return [entry for entry in walk(config) if entry[1].CONV_STACK]
 
 
 def conv_stack_least_bytes(config: dict, rows: int) -> int:
-    """The fewest bytes the conv and pool work of one batch of ``rows``
-    images can move: every conv weight and bias read once, the input images
-    read once, and the last feature map of the conv/pool stack written once,
-    at the configuration's compute dtype."""
-    nbytes = DTYPE_BYTES[config["compute_dtype"]]
-    sh = shapes(config)
-    ws = weight_shapes(config)
-    weights = sum(
-        int(np.prod(ws[l["name"]][0])) + l["n_out"]
-        for l in config["layers"] if l["op"] == "conv"
-    )
-    size, ch = sh[INPUT]
+    """The fewest bytes the conv stack's work on one batch of ``rows``
+    images can move: every parameter of its layers read once, the input
+    images read once, and its last feature map written once, at the
+    configuration's compute dtype."""
+    stack = conv_stack(config)
+    weights = sum(math.prod(p) for layer, mod, ins, _ in stack
+                  for p in mod.param_shapes(layer, ins))
+    size, ch = config["input_size"], config["in_channels"]
     images = rows * size * size * ch
-    last = [l for l in config["layers"] if l["op"] in ("conv", "pool")][-1]
-    size, ch = sh[last["name"]]
-    return nbytes * (weights + images + rows * size * size * ch)
+    size, ch = stack[-1][3]
+    return DTYPE_BYTES[config["compute_dtype"]] * (weights + images + rows * size * size * ch)
 
 
 def conv_stack_least_seconds(config: dict, rows: int, peak: dict) -> tuple[float, str]:
-    """The least time the conv and pool work of ``rows`` images can take on
+    """The least time the conv stack's work on ``rows`` images can take on
     a chip with ``peak``, and which bound sets it (``compute`` or
     ``memory``)."""
-    t_flops = rows * flops_per_image(config, ("conv",)) / peak["bf16_flops_per_s"]
+    flops = sum(mod.flops(layer, ins, out) for layer, mod, ins, out in conv_stack(config))
+    t_flops = rows * flops / peak["bf16_flops_per_s"]
     t_bytes = conv_stack_least_bytes(config, rows) / peak["hbm_bytes_per_s"]
     return (t_flops, "compute") if t_flops >= t_bytes else (t_bytes, "memory")
 
 
+def he_normal(keys, w_shape: tuple[int, ...], b_shape: tuple[int, ...]):
+    """He-normal float32 weights (fan-in all but the last axis) and biases
+    of standard deviation 0.01, one key each."""
+    w = jax.random.normal(keys[0], w_shape, jnp.float32)
+    b = jax.random.normal(keys[1], b_shape, jnp.float32)
+    return w * (2.0 / math.prod(w_shape[:-1])) ** 0.5, b * 0.01
+
+
 def init_params(config: dict, seed: int):
-    """He-normal float32 weights and small normal biases for every conv and
-    dense layer, made on the device in one jitted call from ``seed``."""
-    ws = weight_shapes(config)
-    names = list(ws)
+    """Every layer's parameters, made on the device in one jitted call from
+    ``seed``: one split of the seed's key into one key per parameter, taken
+    in layer order."""
+    plan = [(layer, mod, ins, n) for layer, mod, ins, _ in walk(config)
+            if (n := len(mod.param_shapes(layer, ins)))]
 
     @jax.jit
     def make(key):
-        keys = jax.random.split(key, 2 * len(names))
-        out = {}
-        for i, name in enumerate(names):
-            shape, fan_in = ws[name]
-            w = jax.random.normal(keys[2 * i], shape, jnp.float32)
-            b = jax.random.normal(keys[2 * i + 1], (shape[-1],), jnp.float32)
-            out[name] = (w * (2.0 / fan_in) ** 0.5, b * 0.01)
+        keys = jax.random.split(key, sum(n for *_, n in plan))
+        out, at = {}, 0
+        for layer, mod, ins, n in plan:
+            out[layer["name"]] = mod.init(keys[at:at + n], layer, ins)
+            at += n
         return out
 
     return make(seed_key(seed))
@@ -151,7 +179,7 @@ def make_images(config: dict, seed: int, count: int) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def _quantize(x, axes):
+def quantize(x, axes):
     """Symmetric int8 quantization of ``x`` with one scale per slice that
     ``axes`` reduces over; returns ``(int8 values, float32 scale)``."""
     scale = jnp.max(jnp.abs(x), axis=axes, keepdims=True) / 127.0
@@ -160,71 +188,17 @@ def _quantize(x, axes):
     return q, scale
 
 
-def _conv(x, w, layer, int8: bool):
-    dims = ("NHWC", "HWIO", "NHWC")
-    pad = [(layer["pad"], layer["pad"])] * 2
-    stride = (layer["S"], layer["S"])
-    if not int8:
-        return jax.lax.conv_general_dilated(
-            x, w, stride, pad, dimension_numbers=dims,
-            precision=jax.lax.Precision.HIGHEST,
-        )
-    # activations per image, weights per output channel: the usual int8
-    # inference scheme, accumulated exactly in int32
-    xq, xs = _quantize(x, (1, 2, 3))
-    wq, ws = _quantize(w, (0, 1, 2))
-    acc = jax.lax.conv_general_dilated(
-        xq, wq, stride, pad, dimension_numbers=dims,
-        preferred_element_type=jnp.int32,
-    )
-    return acc.astype(jnp.float32) * xs * ws.reshape(1, 1, 1, -1)
-
-
-def _dense(x, w, int8: bool):
-    if not int8:
-        return jnp.dot(x, w, precision=jax.lax.Precision.HIGHEST)
-    xq, xs = _quantize(x, (1,))
-    wq, ws = _quantize(w, (0,))
-    acc = jnp.dot(xq, wq, preferred_element_type=jnp.int32)
-    return acc.astype(jnp.float32) * xs * ws
-
-
 def forward(config: dict, params, x, *, int8: bool = False):
     """Layer-by-layer float32 forward with full intermediate maps.
 
     ``int8=False`` is the reference: every product at ``HIGHEST``
-    precision.  ``int8=True`` is the control: each conv and dense layer
-    takes int8 operands and accumulates in int32, the step below the
+    precision.  ``int8=True`` is the control: each op with a product takes
+    int8 operands and accumulates in int32, the step below the
     configuration's bfloat16 operands; everything else stays float32."""
     values = {INPUT: x.astype(jnp.float32)}
     for layer in config["layers"]:
-        op, name = layer["op"], layer["name"]
-        a = values[layer["inputs"][0]]
-        if op == "conv":
-            w, b = params[name]
-            y = _conv(a, w, layer, int8) + b
-            y = jax.nn.relu(y) if layer["relu"] else y
-        elif op == "pool":
-            p = layer["pad"]
-            y = jax.lax.reduce_window(
-                a, -jnp.inf, jax.lax.max, (1, layer["K"], layer["K"], 1),
-                (1, layer["S"], layer["S"], 1), ((0, 0), (p, p), (p, p), (0, 0)),
-            )
-        elif op == "relu":
-            y = jax.nn.relu(a)
-        elif op == "add":
-            y = a + values[layer["inputs"][1]]
-        elif op == "global_pool":
-            y = jnp.mean(a, axis=(1, 2))
-        elif op == "flatten":
-            y = a.reshape(a.shape[0], -1)
-        elif op == "dense":
-            w, b = params[name]
-            y = _dense(a, w, int8) + b
-            y = jax.nn.relu(y) if layer["relu"] else y
-        else:
-            raise ValueError(f"layer {name}: unknown op {op!r}")
-        values[name] = y
+        xs = [values[i] for i in layer["inputs"]]
+        values[layer["name"]] = op(layer["op"]).forward(layer, params.get(layer["name"]), xs, int8)
     return values[config["layers"][-1]["name"]]
 
 

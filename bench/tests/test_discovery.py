@@ -39,6 +39,14 @@ def test_config_layers_equal_the_programs_graph(entry):
         harness.check_graph(graph, changed)
 
 
+@pytest.mark.parametrize("entry", SPEC["configs"], ids=lambda c: c["name"])
+def test_every_op_has_a_module(entry):
+    config = model.load_config(entry["name"])
+    for op in {layer["op"] for layer in config["layers"]}:
+        assert (model.OPS_DIR / f"{op}.py").is_file()
+        assert all(hasattr(model.op(op), n) for n in model.OP_INTERFACE)
+
+
 @pytest.mark.parametrize("cell", SPEC["workloads"], ids=lambda w: w["name"])
 def test_cells_find_their_files_and_metrics(cell):
     traffic = harness.load_traffic(cell["traffic"])
