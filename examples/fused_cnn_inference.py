@@ -35,7 +35,8 @@ from repro.net.runner import (
 from repro.obs import TraceCollector
 
 # interpret-friendly default scales off the chip (paper scale for LeNet only)
-INTERPRET_SIZE = {"lenet": 32, "alexnet": 67, "vgg16": 32, "resnet18": 32}
+INTERPRET_SIZE = {"lenet": 32, "alexnet": 67, "vgg16": 32, "resnet18": 32,
+                  "resnet50": 32, "convnext_tiny": 32}
 
 
 def main() -> None:
@@ -99,8 +100,8 @@ def main() -> None:
                           (args.batch, blob, blob, graph.in_channels)) * 3
     )
     sparse_params = {
-        k: (w, b - 0.3) if graph.node(k).op == "conv" else (w, b)
-        for k, (w, b) in params.items()
+        k: (v[0], v[1] - 0.3) if graph.node(k).op == "conv" else v
+        for k, v in params.items()
     }
     # run the sparse forward launch by launch (DESIGN.md #12): one
     # measured+modeled span per fused launch

@@ -61,10 +61,11 @@ DEFAULT_PARAMS = ArithParams()
 
 
 def _levels_with_pools(spec: FusionSpec):
-    """Group conv levels with their trailing pool (for the MP term)."""
+    """Group conv (and depthwise) levels with their trailing pool (for the
+    MP term)."""
     groups = []
     for lvl in spec.levels:
-        if lvl.kind == "conv":
+        if lvl.has_weights:
             groups.append([lvl, None])
         else:
             if groups and groups[-1][1] is None:
@@ -81,12 +82,17 @@ def ds1_cycles_per_movement(spec: FusionSpec, p: ArithParams = DEFAULT_PARAMS,
     Per conv level q: delta_OLM + delta_OLA*ceil(log2 K_q^2)
     + delta_OLA*ceil(log2 N_q) + ceil(log2 K_q^2) + ceil(log2 N_q) + MP_q,
     then a single trailing ``n`` — the digit stream is pipelined across the
-    whole fusion pyramid, so working precision is paid once.
+    whole fusion pyramid, so working precision is paid once.  A depthwise
+    level adds only its pool's MP: its work is the vector unit's
+    (:func:`vpu_split_cycles_per_movement`).
     """
     total = 0
     for conv, pool in _levels_with_pools(spec):
-        if conv is None:
-            total += p.mp_cycles if include_pool else 0
+        if conv is None or conv.kind == "depthwise":
+            # a leading pool, or a depthwise level (vector-unit work) and
+            # its pool
+            if include_pool and (conv is None or pool is not None):
+                total += p.mp_cycles
             continue
         lk = _log2c(conv.K * conv.K)
         ln = _log2c(conv.n_in)
@@ -106,8 +112,11 @@ def ds2_cycles_per_movement(spec: FusionSpec, p: ArithParams = DEFAULT_PARAMS,
     """
     total = 0
     for conv, pool in _levels_with_pools(spec):
-        if conv is None:
-            total += p.mp_cycles if include_pool else 0
+        if conv is None or conv.kind == "depthwise":
+            # a leading pool, or a depthwise level (vector-unit work) and
+            # its pool
+            if include_pool and (conv is None or pool is not None):
+                total += p.mp_cycles
             continue
         ln = _log2c(conv.n_in)
         total += (p.delta_olm + (p.n - 1) + p.acc) * conv.K * conv.K
@@ -133,8 +142,8 @@ def ds1_split_cycles_per_movement(
     groups = _levels_with_pools(spec)
     terms = []
     for conv, pool in groups:
-        if conv is None:
-            terms.append(p.mp_cycles)
+        if conv is None or conv.kind == "depthwise":
+            terms.append(p.mp_cycles if conv is None or pool is not None else 0)
             continue
         lk = _log2c(conv.K * conv.K)
         ln = _log2c(conv.n_in)
@@ -149,6 +158,41 @@ def ds1_split_cycles_per_movement(
     mid = sum(terms[:last_conv])
     last = sum(terms[last_conv:]) + p.n
     return mid, last
+
+
+def vpu_level_cycles(lvl, p: ArithParams = DEFAULT_PARAMS) -> int:
+    """Per-movement vector-unit cycles of one level, in Eq. (3)'s units:
+    the work the MXU does not do, which no dtype speeds up.  A depthwise
+    level multiplies each of its ``K^2`` taps by one weight per channel and
+    sums them in a ``K^2`` adder tree, with no tree over channels; an exact
+    GELU evaluates erf's rational approximation (eleven multiply-adds and a
+    divide in sequence); a LayerNorm sums over the channels twice (mean,
+    then variance) and multiplies; a layer scale is one multiply.  0 for a
+    conv level with ReLU or none and no epilogue."""
+    total = 0
+    if lvl.kind == "depthwise":
+        lk = _log2c(lvl.K * lvl.K)
+        total += p.delta_olm + p.delta_ola * lk + lk
+    if lvl.gelu:
+        total += 12 * (p.delta_olm + p.delta_ola)
+    if lvl.norm_eps is not None:
+        total += 2 * (p.delta_ola + 1) * _log2c(lvl.n_out) + p.delta_olm
+    if lvl.scale:
+        total += p.delta_olm
+    return total
+
+
+def vpu_split_cycles_per_movement(
+    spec: FusionSpec, p: ArithParams = DEFAULT_PARAMS
+) -> tuple[int, int]:
+    """:func:`vpu_level_cycles` summed as ``(mid, last)``, split where
+    :func:`ds1_split_cycles_per_movement` splits: the levels before the
+    last level with weights, and the rest.  ``(0, 0)`` for a chain of ReLU
+    and linear convs and pools."""
+    levels = spec.levels
+    last = max((i for i, l in enumerate(levels) if l.has_weights), default=0)
+    cycles = [vpu_level_cycles(l, p) for l in levels]
+    return sum(cycles[:last]), sum(cycles[last:])
 
 
 def mxu_scaled_cycles(cycles: int, compute_dtype) -> int:

@@ -13,9 +13,11 @@ Hardware note: USEFUSE *reuses* overlapping tile outputs from on-chip buffers
 recompute are identical, so the executor recomputes halos per tile while the
 intensity/cycle models charge the plan's actual buffer traffic.
 
-Layout: NHWC.  Conv weights: (K, K, Cin, Cout) + bias (Cout,).  Each conv
-level applies its own activation (``FusedLevel.relu``): ReLU, as in the
-paper's conv+ReLU[+pool] stacks, or none for a linear level.
+Layout: NHWC.  Conv weights: (K, K, Cin, Cout) + bias (Cout,); a depthwise
+level's (K, K, 1, C) + bias (C,).  Each conv level applies its own
+activation (``FusedLevel.relu``): ReLU, as in the paper's conv+ReLU[+pool]
+stacks, or none for a linear level; then the rest of its epilogue (exact
+GELU, LayerNorm, layer scale; :func:`level_epilogue`).
 """
 
 from __future__ import annotations
@@ -32,40 +34,83 @@ from .program import compile_windows
 
 @dataclass
 class PyramidParams:
-    """Weights for the conv levels of a fusion spec (index-aligned to convs)."""
+    """Weights for the levels with weights of a fusion spec (index-aligned
+    to them); ``epilogues[l]`` holds level ``l``'s LayerNorm gamma and beta
+    and layer-scale gamma, those it has, in that order (``None``: no level
+    has any)."""
 
     weights: list[jnp.ndarray]
     biases: list[jnp.ndarray]
+    epilogues: list[tuple] | None = None
 
 
 def init_pyramid_params(
     spec: FusionSpec, key: jax.Array, scale: float = 1.0
 ) -> PyramidParams:
-    ws, bs = [], []
+    ws, bs, eps = [], [], []
     for lvl in spec.levels:
-        if lvl.kind != "conv":
+        if not lvl.has_weights:
             continue
         key, k1, k2 = jax.random.split(key, 3)
-        fan_in = lvl.K * lvl.K * lvl.n_in
-        w = jax.random.normal(k1, (lvl.K, lvl.K, lvl.n_in, lvl.n_out)) * (
+        cin = 1 if lvl.kind == "depthwise" else lvl.n_in
+        fan_in = lvl.K * lvl.K * cin
+        w = jax.random.normal(k1, (lvl.K, lvl.K, cin, lvl.n_out)) * (
             scale * (2.0 / fan_in) ** 0.5
         )
         b = jax.random.normal(k2, (lvl.n_out,)) * 0.01
         ws.append(w.astype(jnp.float32))
         bs.append(b.astype(jnp.float32))
-    return PyramidParams(ws, bs)
+        # LayerNorm gamma and beta, layer-scale gamma: about 1, 0 and 1
+        centres = ((1.0, 0.0) if lvl.norm_eps is not None else ()) + (
+            (1.0,) if lvl.scale else ()
+        )
+        extra = []
+        for centre in centres:
+            key, k3 = jax.random.split(key)
+            extra.append(centre + 0.1 * jax.random.normal(k3, (lvl.n_out,)))
+        eps.append(tuple(extra))
+    return PyramidParams(ws, bs, eps if any(eps) else None)
 
 
 def _conv2d(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, stride: int,
             pad: int) -> jnp.ndarray:
+    """A conv, or a depthwise one when ``w`` is ``(K, K, 1, C)``."""
     out = jax.lax.conv_general_dilated(
         x,
         w,
         window_strides=(stride, stride),
         padding=[(pad, pad), (pad, pad)],
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        feature_group_count=x.shape[-1] if w.shape[2] == 1 else 1,
     )
     return out + b
+
+
+def layer_norm(x: jnp.ndarray, gamma, beta, eps: float) -> jnp.ndarray:
+    """LayerNorm over the last (channel) axis, in float32."""
+    x = x.astype(jnp.float32)
+    mean = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
+    return (x - mean) * jax.lax.rsqrt(var + eps) * gamma + beta
+
+
+def level_epilogue(x: jnp.ndarray, lvl, extra=()) -> jnp.ndarray:
+    """A level's activation and epilogue after its bias, in order: ReLU or
+    exact GELU, LayerNorm (``extra``'s gamma and beta), layer scale
+    (``extra``'s last)."""
+    if lvl.relu:
+        x = jax.nn.relu(x)
+    elif lvl.gelu:
+        x = jax.nn.gelu(x, approximate=False)
+    if lvl.norm_eps is not None:
+        x = layer_norm(x, extra[0], extra[1], lvl.norm_eps)
+    if lvl.scale:
+        x = x * extra[-1]
+    return x
+
+
+def _extras(params: PyramidParams, ci: int) -> tuple:
+    return () if params.epilogues is None else tuple(params.epilogues[ci])
 
 
 def _maxpool(x: jnp.ndarray, k: int, s: int) -> jnp.ndarray:
@@ -89,12 +134,11 @@ def reference_forward(
     ci = 0
     with jax.default_matmul_precision("highest"):
         for lvl in spec.levels:
-            if lvl.kind == "conv":
+            if lvl.has_weights:
                 x = _conv2d(
                     x, params.weights[ci], params.biases[ci], lvl.S, lvl.pad
                 )
-                if lvl.relu:
-                    x = jax.nn.relu(x)
+                x = level_epilogue(x, lvl, _extras(params, ci))
                 ci += 1
             else:
                 x = _maxpool(x, lvl.K, lvl.S)
@@ -181,10 +225,9 @@ def _tile_chain_2d(tile, g_pad, spec, params, windows):
             aj += plj
             bj += plj
         tile = tile[:, ai:bi, aj:bj, :]
-        if lvl.kind == "conv":
+        if lvl.has_weights:
             tile = _conv2d(tile, params.weights[ci], params.biases[ci], lvl.S, 0)
-            if lvl.relu:
-                tile = jax.nn.relu(tile)
+            tile = level_epilogue(tile, lvl, _extras(params, ci))
             ci += 1
         else:
             tile = _maxpool(tile, lvl.K, lvl.S)

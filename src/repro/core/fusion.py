@@ -35,17 +35,49 @@ from dataclasses import dataclass, field
 # ---------------------------------------------------------------------------
 
 
+class LevelEpilogue:
+    """What a level's activation and epilogue fields (``relu``, ``gelu``,
+    ``norm_eps``, ``scale``) imply, for :class:`FusedLevel` and the kernel
+    program's ``ConvLevelProg`` alike."""
+
+    @property
+    def nonneg(self) -> bool:
+        """Whether every value the level emits is ``>= 0``: a ReLU level
+        with no epilogue after the activation.  A pool after it ignores the
+        kernel's zero padding, and an all-zero tile of it proves the next
+        level's input zero (the END skip)."""
+        return (
+            self.relu and not self.gelu and self.norm_eps is None
+            and not self.scale
+        )
+
+    @property
+    def n_vec(self) -> int:
+        """Rows of the level's vector operand: the bias, then LayerNorm's
+        gamma and beta, then the layer scale."""
+        return 1 + 2 * (self.norm_eps is not None) + self.scale
+
+
 @dataclass(frozen=True)
-class FusedLevel:
-    """One level of the fusion pyramid: a conv or pooling stage.
+class FusedLevel(LevelEpilogue):
+    """One level of the fusion pyramid: a conv, depthwise conv or pooling
+    stage.
 
     Attributes mirror the paper's symbols: kernel ``K``, stride ``S``; ``pad``
     is symmetric spatial padding (the paper's examples are pad-0; AlexNet /
-    VGG need it).  ``kind`` is ``"conv"`` or ``"pool"``.  ``n_in``/``n_out``
-    are channel counts (N and M in the paper) used by cycle/intensity models.
-    ``relu`` is a conv level's activation: ReLU, or linear when ``False``
-    (a ResNet bottleneck's last conv).  Each level applies its own, so one
-    pyramid may mix them; pools ignore it.
+    VGG need it).  ``kind`` is ``"conv"``, ``"depthwise"`` (one ``K x K``
+    filter per channel, ``n_in == n_out``, no contraction over channels) or
+    ``"pool"``.  ``n_in``/``n_out`` are channel counts (N and M in the
+    paper) used by cycle/intensity models.  ``relu`` is a conv level's
+    activation: ReLU, or linear when ``False`` (a ResNet bottleneck's last
+    conv).  Each level applies its own, so one pyramid may mix them; pools
+    ignore it.
+
+    A conv or depthwise level may carry an epilogue, applied in this fixed
+    order after its bias: the activation (``relu``, or exact-erf ``gelu`` on
+    a level that is not ReLU), a LayerNorm over its channels with epsilon
+    ``norm_eps`` (``None``: none) and a per-channel ``scale`` (a ConvNeXt
+    block's layer scale).  Their parameters travel with the level's bias.
     """
 
     kind: str
@@ -56,10 +88,18 @@ class FusedLevel:
     n_out: int = 1
     name: str = ""
     relu: bool = True
+    gelu: bool = False
+    norm_eps: float | None = None
+    scale: bool = False
 
     def out_size(self, in_size: int) -> int:
         """Spatial output size for a (padded) input of ``in_size``."""
         return (in_size + 2 * self.pad - self.K) // self.S + 1
+
+    @property
+    def has_weights(self) -> bool:
+        """A conv or depthwise level: one of the kernel's program levels."""
+        return self.kind != "pool"
 
 
 @dataclass(frozen=True)
@@ -81,13 +121,20 @@ class FusionSpec:
         carried: int | None = None
         for l, lvl in enumerate(self.levels):
             label = lvl.name or f"level {l} ({lvl.kind})"
-            if lvl.kind not in ("conv", "pool"):
+            if lvl.kind not in ("conv", "depthwise", "pool"):
                 raise ValueError(f"{label}: unknown level kind {lvl.kind!r}")
-            if lvl.kind == "pool" and lvl.n_in != lvl.n_out:
+            if lvl.kind != "conv" and lvl.n_in != lvl.n_out:
+                what = "pools" if lvl.kind == "pool" else "depthwise convs"
                 raise ValueError(
-                    f"{label}: pools preserve channels, got "
+                    f"{label}: {what} preserve channels, got "
                     f"n_in={lvl.n_in} != n_out={lvl.n_out}"
                 )
+            if lvl.gelu and lvl.relu:
+                raise ValueError(f"{label}: one activation, ReLU or GELU")
+            if lvl.kind == "pool" and (
+                lvl.gelu or lvl.norm_eps is not None or lvl.scale
+            ):
+                raise ValueError(f"{label}: a pool level takes no epilogue")
             if carried is not None and lvl.n_in != carried:
                 raise ValueError(
                     f"{label}: n_in={lvl.n_in} does not chain with the "
@@ -97,7 +144,9 @@ class FusionSpec:
 
     @property
     def q_convs(self) -> int:
-        return sum(1 for l in self.levels if l.kind == "conv")
+        """Levels with weights (conv and depthwise): the kernel's program
+        levels, one weight tensor each."""
+        return sum(1 for l in self.levels if l.has_weights)
 
     def feature_sizes(self) -> list[int]:
         """Unpadded input spatial size of every level, plus the final output.
@@ -243,7 +292,7 @@ def uniform_tile_stride(
         ifm = sizes[l] + 2 * lvl.pad
         if T[l] > ifm:
             return None
-        if lvl.kind != "conv":
+        if lvl.kind == "pool":
             per_level.append(None)  # slaved to the preceding conv level
             continue
         per_level.append(

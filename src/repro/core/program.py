@@ -36,7 +36,7 @@ from __future__ import annotations
 from dataclasses import dataclass, replace
 
 from .dtypes import DTYPE_BYTES, canonical_dtype
-from .fusion import FusionSpec, receptive_window
+from .fusion import FusionSpec, LevelEpilogue, receptive_window
 
 # Planning budget for one launch's VMEM buffers: Mosaic's default scoped
 # limit on v5e (the chip has 128 MiB).
@@ -98,14 +98,19 @@ def padded_lanes(n: int) -> int:
     return _round_up(n, LANES)
 
 
-def weight_slot(levels) -> tuple[int, int, int, int]:
+def weight_slot(levels) -> tuple[int, int, int, int] | None:
     """``(K, K, Cin, Cout)`` of a streamed-weight scratch slot that can hold
-    any one of ``levels``' (lane-padded) weight tensors."""
+    any one of ``levels``' (lane-padded) conv weight tensors; ``None`` when
+    none of them is a conv.  A depthwise level's ``K * K * C`` weights stay
+    resident when the rest stream: they never take a slot."""
+    convs = [p for p in levels if p.kind == "conv"]
+    if not convs:
+        return None
     return (
-        max(p.K for p in levels),
-        max(p.K for p in levels),
-        max(p.n_in for p in levels),
-        max(padded_lanes(p.n_out) for p in levels),
+        max(p.K for p in convs),
+        max(p.K for p in convs),
+        max(p.n_in for p in convs),
+        max(padded_lanes(p.n_out) for p in convs),
     )
 
 
@@ -174,23 +179,27 @@ def taps_per_pass(level) -> int:
     contraction reads ``g`` taps' windows side by side as one ``(W, g *
     lanes)`` operand against their stacked weight blocks, so a 64-channel
     3x3 takes 6 passes a row, not 9.  1 for every level of 128 or more
-    lanes and every ``K = 1`` level (a patch-form level 0 among them).
-    Reads only shapes."""
+    lanes, every ``K = 1`` level (a patch-form level 0 among them) and
+    every depthwise level, which has no contraction.  Reads only shapes."""
+    if level.kind == "depthwise":
+        return 1
     return max(1, min(level.K, LANES // channel_blocks(level.n_in)[1]))
 
 
 def patch_spec(spec: FusionSpec) -> FusionSpec:
     """The spec the kernel runs: ``spec`` itself, or, when level 0 is a
     ``K > 1`` conv whose patch (:func:`patch_lanes`) fits
-    :data:`PATCH_MAX_LANES`, ``spec`` with level 0 in **patch form** — a
-    1x1, stride-1, pad-0 conv over the patch tensor, an input of level 0's
+    :data:`PATCH_MAX_LANES` or whose windows do not overlap (``K == S``,
+    a patchify stem or a ConvNeXt downsample: its patch tensor is the
+    input's own values, reshaped), ``spec`` with level 0 in **patch form** —
+    a 1x1, stride-1, pad-0 conv over the patch tensor, an input of level 0's
     output size (``kernels/fused_conv/ops.patch_tensor``).  Later levels,
     pools included, are unchanged.  Reads only shapes."""
     first = spec.levels[0]
     if not (
         first.kind == "conv"
         and first.K > 1
-        and patch_lanes(first) <= PATCH_MAX_LANES
+        and (patch_lanes(first) <= PATCH_MAX_LANES or first.K == first.S)
     ):
         return spec
     patch = replace(first, K=1, S=1, pad=0, n_in=patch_lanes(first))
@@ -204,7 +213,7 @@ def chain_channels(spec: FusionSpec) -> int:
     """Channel count leaving the chain (pools are channel-preserving)."""
     c = spec.levels[0].n_in
     for lvl in spec.levels:
-        if lvl.kind == "conv":
+        if lvl.has_weights:
             c = lvl.n_out
     return c
 
@@ -237,14 +246,15 @@ def compile_windows(spec: FusionSpec, out_region: int) -> WindowProgram:
 
 
 @dataclass(frozen=True)
-class ConvLevelProg:
+class ConvLevelProg(LevelEpilogue):
     """Static per-conv-level kernel program (offsets affine in tile index).
 
     ``o_base + i * o_step`` is the global output coordinate of tile row 0 at
     grid index ``i``; rows outside ``[0, valid)`` are this level's padding and
     get masked to zero.  A trailing pool level is folded in as an epilogue
-    with its own offset/valid triple.  ``relu`` is the level's activation
-    (the :class:`~repro.core.fusion.FusedLevel`'s).
+    with its own offset/valid triple.  ``kind`` (``conv`` or ``depthwise``),
+    the activation (``relu``, ``gelu``) and the epilogue (``norm_eps``,
+    ``scale``) are the :class:`~repro.core.fusion.FusedLevel`'s.
     """
 
     K: int
@@ -262,6 +272,19 @@ class ConvLevelProg:
     pool_o_step: int = 0
     pool_valid: int = 0
     relu: bool = True
+    kind: str = "conv"
+    gelu: bool = False
+    norm_eps: float | None = None
+    scale: bool = False
+
+    def weight_shape(self, n_out: int | None = None) -> tuple[int, ...]:
+        """The lane-padded weight tensor the kernel holds for this level
+        (``n_out`` of its output channels, all by default): ``(K, K, Cin,
+        Cout)``, or a depthwise level's ``(K * K, C)``, one row per tap."""
+        n_out = self.n_out if n_out is None else n_out
+        if self.kind == "depthwise":
+            return (self.K * self.K, padded_lanes(n_out))
+        return (self.K, self.K, self.n_in, padded_lanes(n_out))
 
 
 @dataclass(frozen=True)
@@ -313,12 +336,27 @@ class TileProgram:
         return self.pad_lo + self.kernel_spec.input_size + self.pad_hi
 
     def weight_floats(self) -> int:
-        return sum(p.K * p.K * p.n_in * p.n_out + p.n_out for p in self.levels)
+        """Every weight and vector value of the launch."""
+        return sum(
+            p.n_vec * p.n_out + p.K * p.K * p.n_out
+            * (1 if p.kind == "depthwise" else p.n_in)
+            for p in self.levels
+        )
+
+    def resident_weight_floats(self) -> int:
+        """The depthwise weights: resident whichever the regime."""
+        return sum(
+            p.K * p.K * p.n_out for p in self.levels if p.kind == "depthwise"
+        )
 
     def level_weight_counts(self) -> tuple[int, ...]:
-        """Flattened float count of each level's weight tensor (bias excluded)
-        — the slice table for streamed-weight launches."""
-        return tuple(p.K * p.K * p.n_in * p.n_out for p in self.levels)
+        """Flattened float count of each level's streamed weight tensor
+        (bias excluded; 0 for a depthwise level, whose weights never
+        stream) — the slice table for streamed-weight launches."""
+        return tuple(
+            0 if p.kind == "depthwise" else p.K * p.K * p.n_in * p.n_out
+            for p in self.levels
+        )
 
     def c_tile_options(self) -> tuple[int, ...]:
         """Legal output-channel tile counts of the last level, ascending and
@@ -330,8 +368,12 @@ class TileProgram:
         ``(P, Cin) @ (Cin, 1)`` dot through its matrix-vector special case,
         whose contraction order differs from the sliced-out column of the
         full dot — breaking the bitwise-parity guarantee every other slice
-        width keeps."""
-        m = self.levels[-1].n_out
+        width keeps.  None for a depthwise last level or one with a
+        LayerNorm, which needs all its channels at once."""
+        last = self.levels[-1]
+        if last.kind == "depthwise" or last.norm_eps is not None:
+            return ()
+        m = last.n_out
         return tuple(c for c in range(2, m // 2 + 1) if m % c == 0)
 
     def input_window(self) -> int:
@@ -364,8 +406,12 @@ class TileProgram:
         """Per level, the taps one MXU pass contracts: :func:`taps_per_pass`
         for a level fed by another level of the launch, 1 for level 0 (its
         input comes from HBM, where the copies would cost an extra pass
-        over the image: measured slower, PERF.md §6)."""
-        return (1,) + tuple(taps_per_pass(p) for p in self.levels[1:])
+        over the image: measured slower, PERF.md §6), and 1 for a level
+        fed by a depthwise level, which computes its channels once."""
+        return (1,) + tuple(
+            1 if prev.kind == "depthwise" else taps_per_pass(p)
+            for prev, p in zip(self.levels, self.levels[1:])
+        )
 
     def copies(self) -> tuple[int, ...]:
         """Per level, how many times its output tile holds its channels
@@ -449,21 +495,23 @@ class TileProgram:
             tiled = li == q - 1 and c_tiles > 1
             bufs.append((
                 "bias",
-                (c_tiles, 1, ct) if tiled else (1, p.n_out * copies[li]),
+                (c_tiles, p.n_vec, ct) if tiled
+                else (p.n_vec, p.n_out * copies[li]),
                 cdt,
             ))
-            if not streamed:
-                shape = (p.K, p.K, p.n_in, padded_lanes(ct if tiled else p.n_out))
+            if not streamed or p.kind == "depthwise":
+                shape = p.weight_shape(ct if tiled else p.n_out)
                 bufs.append(("weights", (c_tiles, *shape) if tiled else shape, cdt))
         if streamed:
             if c_tiles > 1:
-                if q > 1:
-                    bufs.append(("w_mid", (1, *weight_slot(levels[:-1])), cdt))
+                mid = weight_slot(levels[:-1])
+                if mid is not None:
+                    bufs.append(("w_mid", (1, *mid), cdt))
                 bufs.append(
                     ("w_slices",
                      (w_slots, last.K, last.K, last.n_in, padded_lanes(ct)), cdt)
                 )
-            else:
+            elif weight_slot(levels) is not None:
                 bufs.append(("w_ring", (w_slots, *weight_slot(levels)), cdt))
         return bufs
 
@@ -587,7 +635,11 @@ class TileProgram:
         movement, it does not add traffic."""
         del c_tiles  # traffic-invariant; see docstring
         w_reads = batch * self.alpha ** 2 if streamed else 1
-        vals = w_reads * self.weight_floats() + batch * self.out_size ** 2 * self.n_out
+        resident = self.resident_weight_floats()
+        vals = (
+            w_reads * (self.weight_floats() - resident) + resident
+            + batch * self.out_size ** 2 * self.n_out
+        )
         # skip flags stay int32 whatever the compute dtype
         flag_bytes = (
             DTYPE_BYTES["int32"] * batch * self.alpha ** 2 * self.q_convs
@@ -619,21 +671,22 @@ def compile_program(
     from repro.robust.errors import PlanError
 
     levels = spec.levels
-    if not (levels and levels[0].kind == "conv"):
+    if not (levels and levels[0].has_weights):
         raise PlanError(
             "chain must start with a conv level",
             levels=[lvl.kind for lvl in levels],
         )
     for l, lvl in enumerate(levels):
-        if lvl.kind == "pool" and levels[l - 1].kind != "conv":
+        if lvl.kind == "pool" and not levels[l - 1].has_weights:
             raise PlanError(
                 "each pool level must directly follow a conv level",
                 level=l, node=lvl.name,
             )
-        if lvl.kind == "pool" and not levels[l - 1].relu:
+        if lvl.kind == "pool" and not levels[l - 1].nonneg:
             raise PlanError(
-                "a pool level must follow a ReLU conv level (its zero"
-                " padding is neutral only for non-negative values)",
+                "a pool level must follow a ReLU conv level with no"
+                " epilogue (its zero padding is neutral only for"
+                " non-negative values)",
                 level=l, node=lvl.name,
             )
     source = spec
@@ -652,7 +705,7 @@ def compile_program(
     win = compile_windows(spec, out_region).windows
     progs = []
     for l, lvl in enumerate(levels):
-        if lvl.kind != "conv":
+        if lvl.kind == "pool":
             continue
         in_size = win[l].size
         out_sz = (in_size - lvl.K) // lvl.S + 1
@@ -683,6 +736,10 @@ def compile_program(
                 pool_o_step=pool_os,
                 pool_valid=pool_valid,
                 relu=lvl.relu,
+                kind=lvl.kind,
+                gelu=lvl.gelu,
+                norm_eps=lvl.norm_eps,
+                scale=lvl.scale,
             )
         )
     for prev, cur in zip(progs, progs[1:]):
@@ -859,12 +916,19 @@ class LaunchPlan:
             ds1_cycles_per_movement,
             ds1_split_cycles_per_movement,
             mxu_scaled_cycles,
+            vpu_split_cycles_per_movement,
         )
 
         bpv = self.program.bytes_per_val
         cdt = self.program.compute_dtype
         spec = self.program.kernel_spec
-        compute = mxu_scaled_cycles(ds1_cycles_per_movement(spec), cdt)
+        # vector-unit work (depthwise levels, epilogues) runs at its own
+        # rate whatever the dtype; only the MXU share scales with it
+        vpu_mid, vpu_last = vpu_split_cycles_per_movement(spec)
+        compute = (
+            mxu_scaled_cycles(ds1_cycles_per_movement(spec), cdt)
+            + vpu_mid + vpu_last
+        )
         if not self.streamed:
             return compute, None
         cnts = self.program.level_weight_counts()
@@ -872,8 +936,8 @@ class LaunchPlan:
             compute_mid, compute_last = ds1_split_cycles_per_movement(spec)
             return compute, {
                 "kind": "channel_tiled",
-                "compute_mid": mxu_scaled_cycles(compute_mid, cdt),
-                "compute_last": mxu_scaled_cycles(compute_last, cdt),
+                "compute_mid": mxu_scaled_cycles(compute_mid, cdt) + vpu_mid,
+                "compute_last": mxu_scaled_cycles(compute_last, cdt) + vpu_last,
                 "dma_mid": -(-bpv * sum(cnts[:-1]) // HBM_BYTES_PER_CYCLE),
                 "dma_slice": -(
                     -bpv * -(-cnts[-1] // self.c_tiles) // HBM_BYTES_PER_CYCLE

@@ -57,6 +57,17 @@ Per grid cell (b, i, j):
   * each conv level applies its own activation (``ConvLevelProg.relu``):
     ReLU, or none for a linear level such as a ResNet bottleneck's last
     conv;
+  * a depthwise level (``kind == "depthwise"``, a ConvNeXt block's 7x7)
+    has no contraction over channels: it runs on the vector unit, ``K*K``
+    multiply-adds of each tap's shifted window by that tap's per-channel
+    weight row, in f32, then emits through the same path as a conv level;
+  * a level's epilogue runs in f32 on each output row, in a fixed order
+    after the bias: the activation (ReLU, or the exact GELU of
+    :func:`gelu_exact` through the rational :func:`erf_f32`), a LayerNorm
+    over the level's real channels (the lanes a tile pads to are not part of
+    the row, so they count in neither the mean nor the variance), and a
+    per-channel scale.  The parameters ride as extra rows of the bias
+    operand;
   * END tile-skip (the paper's §3.2 insight at TPU-feasible granularity)
     generalizes to a **cascade**: at every level l >= 1 whose input comes
     from a ReLU level, if that post-ReLU tile is all zero the level's K^2
@@ -154,6 +165,61 @@ def _in_range(g, valid: int):
     return (g >= 0) & (g < valid)
 
 
+# erf(x) = x * P(x^2) / Q(x^2) on [-4, 4], the rational float32
+# approximation of XLA's and Eigen's erf: Mosaic has no lowering for
+# ``lax.erf_p``.  Beyond +-4, erf is +-1 in float32.  Largest absolute
+# error against ``jax.scipy.special.erf`` over the float32 range: 4.2e-7
+# (tests/test_convnext.py holds it under ERF_MAX_ERR).
+_ERF_P = (-2.72614225801306e-10, 2.77068142495902e-08, -2.10102402082508e-06,
+          -5.69250639462346e-05, -7.34990630326855e-04, -2.95459980854025e-03,
+          -1.60960333262415e-02)
+_ERF_Q = (-1.45660718464996e-05, -2.13374055278905e-04, -1.68282697438203e-03,
+          -7.37332916720468e-03, -1.42647390514189e-02)
+ERF_MAX_ERR = 5e-7
+
+
+def erf_f32(x):
+    """erf of a float32 array by :data:`_ERF_P` over :data:`_ERF_Q`."""
+    x = jnp.clip(x, -4.0, 4.0)
+    x2 = x * x
+    p = jnp.float32(_ERF_P[0])
+    for c in _ERF_P[1:]:
+        p = p * x2 + jnp.float32(c)
+    q = jnp.float32(_ERF_Q[0])
+    for c in _ERF_Q[1:]:
+        q = q * x2 + jnp.float32(c)
+    return x * p / q
+
+
+def gelu_exact(x):
+    """The exact GELU, ``x * Phi(x) = x / 2 * (1 + erf(x / sqrt 2))``, in
+    float32 (not the tanh approximation)."""
+    return 0.5 * x * (1.0 + erf_f32(x * jnp.float32(0.7071067811865476)))
+
+
+def _epilogue(acc, vec, prog: ConvLevelProg, n_out: int):
+    """A level's epilogue on one f32 output row ``(W, width)``: bias, the
+    activation, LayerNorm, scale.  ``vec`` is the level's f32 vector
+    operand, rows ``bias, [gamma, beta], [scale]`` (``prog.n_vec``).  The
+    LayerNorm's mean and variance are over the ``n_out`` real channels: a
+    row holds exactly those (copies repeat them), never lane padding."""
+    acc = acc + vec[0:1]
+    if prog.relu:
+        acc = jnp.maximum(acc, 0.0)
+    elif prog.gelu:
+        acc = gelu_exact(acc)
+    if prog.norm_eps is not None:
+        inv = jnp.float32(1.0 / n_out)
+        mean = jnp.sum(acc[:, :n_out], axis=1, keepdims=True) * inv
+        d = acc - mean
+        var = jnp.sum(jnp.square(d[:, :n_out]), axis=1, keepdims=True) * inv
+        acc = d * jax.lax.rsqrt(var + jnp.float32(prog.norm_eps))
+        acc = acc * vec[1:2] + vec[2:3]
+    if prog.scale:
+        acc = acc * vec[prog.n_vec - 1 : prog.n_vec]
+    return acc
+
+
 def _emit(emit, r, c, v, cdt, m):
     """Cast channel block ``c`` of output row ``r`` (f32) to the compute
     dtype once, store it, and fold its max into ``m``."""
@@ -165,7 +231,7 @@ def _emit(emit, r, c, v, cdt, m):
 def _conv_level(
     src: _Tile,
     w_at,
-    bias,
+    vec,
     prog: ConvLevelProg,
     idx,
     *,
@@ -178,12 +244,14 @@ def _conv_level(
     fold: int = 1,
     copies: int = 1,
 ):
-    """One conv level, its activation (``prog.relu``) and its pool
-    epilogue, one output row at a time.
+    """One conv or depthwise level, its epilogue (:func:`_epilogue`) and
+    its pool, one output row at a time.
 
     ``w_at(ki, kj, c, o)`` gives tap ``(ki, kj)``'s ``(lanes, 128)`` weights
-    for input channel block ``c`` and 128-lane output block ``o``; ``bias``
-    is ``(1, copies * n_out)`` f32.  With ``copies > 1`` the level's weights
+    for input channel block ``c`` and 128-lane output block ``o`` (a
+    depthwise level's ``w_at(ki, kj, c)``: the tap's f32 ``(1, lanes)``
+    weight row of block ``c``); ``vec`` is ``(n_vec, copies * n_out)``
+    f32, the bias first.  With ``copies > 1`` the level's weights
     and bias repeat its ``n_out`` channels that many times across the
     output lanes, and every row it emits holds them so.
     ``dots=False`` is the closed form of an all-zero input (the conv output
@@ -235,9 +303,25 @@ def _conv_level(
             for o, a in enumerate(accs)
         ]
 
+    def dw_row(r):
+        """The depthwise sum of one output row, channel block by block."""
+        blocks = []
+        for c in range(cb_in):
+            a = jnp.zeros((W, cl_in), jnp.float32)
+            for ki in range(K):
+                if stage is not None:
+                    v = src.row(c, r * S + ki)[:, :cl_in]
+                    stage[: v.shape[0], :cl_in] = v.astype(jnp.float32)
+                for kj in range(K):
+                    a = a + patch(c, r, ki, kj).astype(jnp.float32) * w_at(ki, kj, c)
+            blocks.append(a)
+        return blocks[0] if cb_in == 1 else jnp.concatenate(blocks, axis=1)
+
     def conv_row(r, m):
         acc = jnp.zeros((W, width), jnp.float32)
-        if dots:
+        if dots and prog.kind == "depthwise":
+            acc = dw_row(r)
+        elif dots:
             accs = [jnp.zeros((W, LANES), jnp.float32)] * n_blocks
             for ki in range(K):
                 for c in range(cb_in):
@@ -249,9 +333,7 @@ def _conv_level(
                         accs = one_pass(accs, c, r, taps)
             acc = accs[0] if n_blocks == 1 else jnp.concatenate(accs, axis=1)
             acc = acc[:, :width]
-        acc = acc + bias
-        if prog.relu:
-            acc = jnp.maximum(acc, 0.0)
+        acc = _epilogue(acc, vec, prog, n_out)
         acc = acc * (col_ok & _in_range(r + g0, prog.valid))
         for c in range(cb):
             block = acc[:, c * cl : (c + 1) * cl]
@@ -381,18 +463,19 @@ class _Launch:
         self.x_dma(bi, i, j, slot).wait()
         return slot
 
-    def level(self, l, src, w_at, bias, idx, emit, *, n_out, dots):
+    def level(self, l, src, w_at, vec, idx, emit, *, n_out, dots):
         stage = self.stage if self.staged[l] else None
         return _conv_level(
-            src, w_at, bias, self.prog.levels[l], idx, n_out=n_out,
+            src, w_at, vec, self.prog.levels[l], idx, n_out=n_out,
             dots=dots, stage=stage, conv_out=self.conv_out[l], emit=emit,
             cdt=self.cdt, fold=self.folds[l], copies=self.copies[l],
         )
 
     def skippable(self, l, end_skip: bool) -> bool:
         """Whether level ``l`` may be END-skipped: its input is the output
-        of a ReLU level, so a zero max proves the input all zero."""
-        return end_skip and l > 0 and self.prog.levels[l - 1].relu
+        of a ReLU level with no epilogue after it, so a zero max proves the
+        input all zero."""
+        return end_skip and l > 0 and self.prog.levels[l - 1].nonneg
 
     def mid_emit(self, l):
         copies, ref = self.copies[l], self.mid[l]
@@ -438,6 +521,23 @@ def _ref_reader(ref, lead=()):
     return w_at
 
 
+def _dw_reader(ref, prog: ConvLevelProg):
+    """``w_at`` over a depthwise level's ``(K * K, C)`` weights: tap
+    ``(ki, kj)``'s f32 weight row of channel block ``c``."""
+    _, cl = channel_blocks(prog.n_out)
+    full = ref.shape[-1]
+
+    def w_at(ki, kj, c):
+        row = pl.ds(ki * prog.K + kj, 1)
+        return ref[row, _lanes(c, cl, full)].astype(jnp.float32)
+    return w_at
+
+
+def _resident_reader(ref, prog: ConvLevelProg):
+    """``w_at`` over a level's weights held whole in VMEM."""
+    return _dw_reader(ref, prog) if prog.kind == "depthwise" else _ref_reader(ref)
+
+
 def _slot_dma(w_hbm, ring, slot, prog: ConvLevelProg, full, sem):
     """DMA one level's whole lane-padded ``(K, K, Cin, Cout)`` tensor into
     a slot."""
@@ -470,11 +570,14 @@ def _pyramid_kernel(
     idx = (i, j)
 
     if stream:
-        ring, w_sem = ctx.bufs["w_ring"][0], sems[1]
+        ring, w_sem = ctx.bufs.get("w_ring", [None])[0], sems[1]
         full = weight_slot(progs)
 
         def w_dma(l):
-            """Level l's weights into its ring slot."""
+            """Level l's weights into its ring slot; none for a depthwise
+            level, whose weights stay resident."""
+            if progs[l].kind == "depthwise":
+                return _Copies()
             return _slot_dma(
                 w_refs[l], ring, l % w_slots, progs[l], full, w_sem.at[l % w_slots]
             )
@@ -499,8 +602,8 @@ def _pyramid_kernel(
 
         def fetch_w(l=l, prev_live=prev_live):
             # called inside level l's live branch only
-            if not stream:
-                return _ref_reader(w_refs[l])
+            if not stream or progs[l].kind == "depthwise":
+                return _resident_reader(w_refs[l], progs[l])
             if w_slots > 1:
                 if l > 0 and prev_live is not None:
                     # predecessor skipped => no prefetch: fetch on demand
@@ -601,9 +704,9 @@ def _ktiled_kernel(
     live_max = sems.pop()  # SMEM (1,) f32
     ctx = _Launch(program, scratch, names, x_hbm, sems.pop(0))
     if stream:
-        if q > 1:
+        mid_full = weight_slot(progs[:-1])
+        if mid_full is not None:
             w_mid, wm_sem = ctx.bufs["w_mid"][0], sems.pop(0)
-            mid_full = weight_slot(progs[:-1])
         w_slices, wk_sem = ctx.bufs["w_slices"][0], sems.pop(0)
 
         def wm_dma(l):
@@ -643,12 +746,12 @@ def _ktiled_kernel(
             emit = ctx.mid_emit(l)
 
             def run_level(l=l, src=src, bias=bias, emit=emit):
-                if stream:
+                if stream and progs[l].kind == "conv":
                     wm_dma(l).start()
                     wm_dma(l).wait()
                     w_at = _slot_reader(w_mid, 0, progs[l], mid_full)
                 else:
-                    w_at = _ref_reader(w_refs[l])
+                    w_at = _resident_reader(w_refs[l], progs[l])
                 return ctx.level(l, src, w_at, bias, idx, emit,
                                  n_out=progs[l].n_out, dots=True)
 
@@ -728,7 +831,10 @@ def fused_pyramid_pallas(
     safe.
 
     Weights/biases are flat per-conv-level lists, index-aligned with
-    ``program.levels``.  With ``stream_weights`` the weights stay in HBM
+    ``program.levels``; a depthwise level's weights are ``(K, K, 1, C)``.
+    A level's bias may be its whole ``(n_vec, n_out)`` vector operand: the
+    bias, then its LayerNorm's gamma and beta, then its layer scale.  With
+    ``stream_weights`` the conv weights stay in HBM
     (memory space ANY) and each level's tensor is DMA'd into one of
     ``w_slots`` shared VMEM scratch slots — double-buffered (prefetch
     overlapping compute) when ``w_slots == 2`` — the fallback when the
@@ -780,6 +886,11 @@ def fused_pyramid_pallas(
     assert x_slots in (1, 2), "x_slots: 1 (serial) or 2 (revolving pipeline)"
     assert len(weights) == q, "one weight tensor per conv level"
     assert len(biases) == q, "one bias per conv level"
+    # each level's vector operand: the bias, then its epilogue's rows
+    biases = [b.reshape(-1, b.shape[-1]) for b in biases]
+    assert all(
+        b.shape == (p.n_vec, p.n_out) for b, p in zip(biases, program.levels)
+    ), "one (n_vec, n_out) bias-and-epilogue operand per level"
     last = program.levels[-1]
     assert c_tiles >= 1 and last.n_out % c_tiles == 0, (
         f"c_tiles {c_tiles} must divide the last level's Cout {last.n_out}"
@@ -792,14 +903,17 @@ def fused_pyramid_pallas(
     assert x_padded.shape[1] == x_padded.shape[2] == program.padded_input, (
         "x_padded spatial dims must equal the program's padded input"
     )
-    weights = list(weights)
-    biases = [b.reshape(1, -1) for b in biases]
+    # a depthwise level's (K, K, 1, C) weights as (K * K, C), a tap a row
+    weights = [
+        w.reshape(p.K * p.K, p.n_out) if p.kind == "depthwise" else w
+        for w, p in zip(weights, program.levels)
+    ]
     ct = last.n_out // c_tiles
     if c_tiles > 1:
         weights[-1] = weights[-1].reshape(
             last.K, last.K, last.n_in, c_tiles, ct
         ).transpose(3, 0, 1, 2, 4)
-        biases[-1] = biases[-1].reshape(c_tiles, 1, ct)
+        biases[-1] = biases[-1].reshape(-1, c_tiles, ct).transpose(1, 0, 2)
     # a level whose tile feeds a folded level computes its channels
     # `copies` times over, in the lanes it pads to anyway
     copies = program.copies()
@@ -830,7 +944,7 @@ def fused_pyramid_pallas(
     scratch_shapes = [pltpu.VMEM(s, jnp_dtype(d)) for _, s, d in scratch]
     scratch_shapes.append(pltpu.SemaphoreType.DMA((x_slots,)))
     if stream_weights:
-        if tiled and q > 1:
+        if tiled and weight_slot(program.levels[:-1]) is not None:
             scratch_shapes.append(pltpu.SemaphoreType.DMA(()))
         scratch_shapes.append(pltpu.SemaphoreType.DMA((w_slots,)))
     if tiled:
@@ -841,9 +955,10 @@ def fused_pyramid_pallas(
 
     in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
     operands: list[jnp.ndarray] = [x_padded]
-    for w, b in zip(weights, biases):
+    for w, b, p in zip(weights, biases, program.levels):
+        streams = stream_weights and p.kind == "conv"
         in_specs += [
-            pl.BlockSpec(memory_space=pl.ANY) if stream_weights else whole(w),
+            pl.BlockSpec(memory_space=pl.ANY) if streams else whole(w),
             whole(b),
         ]
         operands += [w, b]

@@ -48,6 +48,7 @@ def fused_pyramid(
     x: jnp.ndarray,
     weights: list,
     biases: list,
+    epilogues: list | None = None,
     *,
     spec: FusionSpec,
     out_region: int | None = None,
@@ -63,9 +64,13 @@ def fused_pyramid(
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Fused Q-conv pyramid forward as a single kernel launch.
 
-    ``x``: (B, H, W, C) NHWC; ``weights[l]``: (K, K, Cin, Cout) and
-    ``biases[l]``: (Cout,) per conv level, in chain order.  Each conv level
-    applies its own activation (``spec.levels[l].relu``).  ``out_region``
+    ``x``: (B, H, W, C) NHWC; ``weights[l]``: (K, K, Cin, Cout) — a
+    depthwise level's (K, K, 1, C) — and ``biases[l]``: (Cout,) per level
+    with weights, in chain order.  Each level applies its own activation
+    (``spec.levels[l].relu``, ``.gelu``); ``epilogues[l]`` holds the
+    parameters of the rest of its epilogue, in order: a LayerNorm's gamma
+    and beta (``norm_eps`` set), then a layer scale's gamma (``scale``),
+    each ``(Cout,)`` (``None``: no level has either).  ``out_region``
     must tile the final output exactly; ``None`` picks the largest region
     fitting the VMEM budget.  ``streamed`` / ``w_slots`` / ``x_slots`` /
     ``c_tiles`` pin the weight regime, the input landing-buffer depth, and
@@ -173,10 +178,16 @@ def fused_pyramid(
         x,
         ((0, 0), (prog.pad_lo, prog.pad_hi), (prog.pad_lo, prog.pad_hi), (0, 0)),
     )
+    if epilogues is None:
+        epilogues = [()] * len(biases)
+    vecs = [
+        jnp.stack([b, *extra]) if extra else b
+        for b, extra in zip(biases, epilogues)
+    ]
     return fused_pyramid_pallas(
         xp,
         weights,
-        [b.astype(cdt) for b in biases],
+        [v.astype(cdt) for v in vecs],
         program=prog,
         end_skip=end_skip,
         interpret=interpret,
@@ -270,8 +281,9 @@ def fused_conv2(
 
 def conv_groups(spec: FusionSpec) -> list[list]:
     """Split the level chain into [conv + trailing pools] groups — the
-    indivisible units of chunking (a pool executes as its conv's epilogue)."""
-    if not (spec.levels and spec.levels[0].kind == "conv"):
+    indivisible units of chunking (a pool executes as its conv's epilogue);
+    a depthwise level starts a group as a conv does."""
+    if not (spec.levels and spec.levels[0].has_weights):
         from repro.robust.errors import PreflightError
 
         raise PreflightError(
@@ -280,7 +292,7 @@ def conv_groups(spec: FusionSpec) -> list[list]:
         )
     groups: list[list] = []
     for lvl in spec.levels:
-        if lvl.kind == "conv":
+        if lvl.has_weights:
             groups.append([lvl])
         else:
             groups[-1].append(lvl)
@@ -320,7 +332,7 @@ def plan_chunks(
     cur: list = []
     for g in groups:
         if cur:
-            convs = sum(l.kind == "conv" for l in cur)
+            convs = sum(l.has_weights for l in cur)
             capped = max_convs_per_chunk is not None and convs >= max_convs_per_chunk
             if capped or not fits(cur + g):
                 chunks.append(FusionSpec(levels=tuple(cur), input_size=size))

@@ -1,18 +1,18 @@
 """CNN graph IR: whole networks as explicit dataflow graphs.
 
 A :class:`Graph` is a topologically-ordered tuple of :class:`Node` — conv /
-pool / relu / residual-add / global-pool / flatten / dense — each naming its
-producer nodes.  Construction validates the whole graph (unique names,
-forward references only, shape/channel chaining) so malformed networks fail
-here with a named node, not deep inside a kernel.
+depthwise conv / pool / relu / residual-add / global-pool / flatten / dense /
+layernorm / gelu / layer scale — each naming its producer nodes.
+Construction validates the whole graph (unique names, forward references
+only, shape/channel chaining) so malformed networks fail here with a named
+node, not deep inside a kernel.
 
 The IR is the single source of the model zoo: :func:`lenet5`,
-:func:`alexnet`, :func:`vgg16`, :func:`resnet18` and :func:`resnet50`
-replace the raw tuple tables that used to live in ``core/cnn_models.py``
-(which now *derives* its paper fusion specs from these graphs).  All
-builders take ``input_size`` so
-tests and interpret-mode demos can run reduced-scale variants of the same
-topology.
+:func:`alexnet`, :func:`vgg16`, :func:`resnet18`, :func:`resnet50` and
+:func:`convnext_tiny` replace the raw tuple tables that used to live in
+``core/cnn_models.py`` (which now *derives* its paper fusion specs from
+these graphs).  All builders take ``input_size`` so tests and
+interpret-mode demos can run reduced-scale variants of the same topology.
 
 :func:`fusable_segments` extracts the maximal linear conv/pool chains the
 auto-partitioner (:mod:`repro.net.partition`) is allowed to cut into fusion
@@ -28,6 +28,13 @@ level (:class:`~repro.core.fusion.FusedLevel`), so a chain may mix ReLU and
 linear convs — a ResNet bottleneck's ``1x1 → 3x3 → 1x1 (linear)`` is one
 chain.  Only a pool must follow a ReLU conv: the kernel pads and masks with
 zeros, which a max pool ignores only over non-negative values.
+
+A ``layernorm``, ``gelu`` or ``layer_scale`` node that follows a chain's
+conv or depthwise level lowers into that level as its epilogue (in the
+fixed order bias → activation → LayerNorm → scale), so a ConvNeXt block's
+``dw → ln → pw1 → gelu → pw2 → scale`` is one chain of three levels; one
+that follows no level (a LayerNorm after a residual add, or on the pooled
+vector) runs as a plain op.
 """
 
 from __future__ import annotations
@@ -37,14 +44,21 @@ from dataclasses import dataclass, replace
 from repro.core.dtypes import canonical_dtype
 from repro.core.fusion import FusedLevel, FusionSpec
 
-_OPS = ("input", "conv", "pool", "relu", "add", "global_pool", "flatten", "dense")
+# ops a chain's conv or depthwise level absorbs as its epilogue
+EPILOGUE_OPS = ("layernorm", "gelu", "layer_scale")
+_OPS = ("input", "conv", "depthwise", "pool", "relu", "add", "global_pool",
+        "flatten", "dense") + EPILOGUE_OPS
 
 
 @dataclass(frozen=True)
 class Node:
-    """One IR node.  ``K``/``S``/``pad`` apply to conv and pool nodes,
-    ``n_out`` to conv and dense nodes, ``relu`` to conv and dense nodes
-    (fused activation)."""
+    """One IR node.  ``K``/``S``/``pad`` apply to conv, depthwise and pool
+    nodes, ``n_out`` to conv and dense nodes, ``relu`` to conv, depthwise and
+    dense nodes (fused activation), ``eps`` to layernorm nodes.  A depthwise
+    (one ``K x K`` filter per channel) keeps its channels; a layernorm
+    normalizes over the channels of each pixel (or of a flat vector), then
+    applies its per-channel gamma and beta; a layer_scale multiplies each
+    channel by its own gamma; a gelu is the exact (erf) GELU."""
 
     op: str
     name: str
@@ -54,6 +68,7 @@ class Node:
     pad: int = 0
     n_out: int = 0
     relu: bool = True
+    eps: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -140,7 +155,7 @@ def infer_shapes(graph: Graph) -> dict[str, Shape]:
         if n.op == "input":
             shapes[n.name] = Shape(graph.input_size, graph.in_channels)
             continue
-        if n.op in ("conv", "pool"):
+        if n.op in ("conv", "depthwise", "pool"):
             s = ins[0]
             if not s.is_map:
                 raise ValueError(f"node {n.name}: {n.op} needs a feature map")
@@ -152,7 +167,7 @@ def infer_shapes(graph: Graph) -> dict[str, Shape]:
                 )
             ch = n.n_out if n.op == "conv" else s.channels
             shapes[n.name] = Shape(out, ch)
-        elif n.op == "relu":
+        elif n.op in ("relu",) + EPILOGUE_OPS:
             shapes[n.name] = ins[0]
         elif n.op == "add":
             if ins[0] != ins[1]:
@@ -200,6 +215,12 @@ class Segment:
     def node_names(self) -> tuple[str, ...]:
         return tuple(n.name for n in self.nodes)
 
+    @property
+    def level_nodes(self) -> tuple[tuple[str, ...], ...]:
+        """Per level of :meth:`spec`, the names of the nodes it lowers: a
+        conv, depthwise or pool node, then the epilogue nodes it absorbed."""
+        return level_nodes(self.nodes)
+
     def spec(self) -> FusionSpec:
         """Lower the chain to the fusion planner's :class:`FusionSpec`."""
         return FusionSpec(
@@ -208,15 +229,47 @@ class Segment:
         )
 
 
+def level_nodes(nodes) -> tuple[tuple[str, ...], ...]:
+    """Group a chain's nodes by the level each lowers into: a conv, depthwise
+    or pool node starts a level, an epilogue node joins the one before."""
+    out: list[list[str]] = []
+    for n in nodes:
+        if n.op in EPILOGUE_OPS:
+            out[-1].append(n.name)
+        else:
+            out.append([n.name])
+    return tuple(tuple(g) for g in out)
+
+
+def _absorb(level: FusedLevel, n: Node) -> FusedLevel | None:
+    """``level`` with epilogue node ``n`` lowered into it, or ``None`` when
+    the fixed order bias → activation → LayerNorm → scale leaves it no
+    place (a pool, an activation after ReLU or after a LayerNorm, ...)."""
+    if level.kind == "pool" or level.scale:
+        return None
+    if n.op == "layer_scale":
+        return replace(level, scale=True)
+    if level.norm_eps is not None:
+        return None
+    if n.op == "layernorm":
+        return replace(level, norm_eps=n.eps)
+    if level.relu or level.gelu:
+        return None
+    return replace(level, gelu=True)
+
+
 def _levels(nodes: tuple[Node, ...], in_channels: int) -> tuple[FusedLevel, ...]:
     levels, c = [], in_channels
     for n in nodes:
-        if n.op == "conv":
+        if n.op in EPILOGUE_OPS:
+            levels[-1] = _absorb(levels[-1], n)
+        elif n.op in ("conv", "depthwise"):
+            n_out = n.n_out if n.op == "conv" else c
             levels.append(
-                FusedLevel("conv", K=n.K, S=n.S, pad=n.pad, n_in=c,
-                           n_out=n.n_out, name=n.name, relu=n.relu)
+                FusedLevel(n.op, K=n.K, S=n.S, pad=n.pad, n_in=c,
+                           n_out=n_out, name=n.name, relu=n.relu)
             )
-            c = n.n_out
+            c = n_out
         else:
             levels.append(
                 FusedLevel("pool", K=n.K, S=n.S, pad=n.pad, n_in=c, n_out=c,
@@ -228,12 +281,15 @@ def _levels(nodes: tuple[Node, ...], in_channels: int) -> tuple[FusedLevel, ...]
 def fusable_segments(graph: Graph) -> tuple[Segment, ...]:
     """Maximal fusable chains, in topological order.
 
-    A conv starts or extends a chain; a pool extends one.  A node extends the
-    current chain only when it consumes the chain tail and the tail has no
-    other consumer; a pool also needs a ReLU conv before it (see the module
-    docstring).  Convs of either activation chain freely.  Everything else
-    (residual add, fork, head op) terminates the chain: these are the cut
-    points.
+    A conv or depthwise conv starts or extends a chain; a pool extends one.
+    A node extends the current chain only when it consumes the chain tail
+    and the tail has no other consumer; a pool also needs a ReLU conv before
+    it (see the module docstring).  Convs of either activation chain
+    freely.  An epilogue node (layernorm, gelu, layer_scale) extends the
+    chain when the level before it has a free place for it
+    (:func:`_absorb`), and otherwise ends it and runs as a plain op.
+    Everything else (residual add, fork, head op) terminates the chain:
+    these are the cut points.
     """
     shapes = infer_shapes(graph)
     n_consumers = {k: len(v) for k, v in graph.consumers().items()}
@@ -253,22 +309,31 @@ def fusable_segments(graph: Graph) -> tuple[Segment, ...]:
             )
             cur.clear()
 
+    def follows_tail(n: Node) -> bool:
+        return bool(cur) and n.inputs[0] == cur[-1].name and (
+            n_consumers[cur[-1].name] == 1
+        )
+
     for n in graph.nodes:
-        if n.op in ("conv", "pool"):
-            extends = (
-                cur
-                and n.inputs[0] == cur[-1].name
-                and n_consumers[cur[-1].name] == 1
-                and (n.op == "conv" or cur[-1].relu)
+        if n.op in ("conv", "depthwise", "pool"):
+            extends = follows_tail(n) and (
+                n.op != "pool"
+                or (cur[-1].op in ("conv", "depthwise", "pool") and cur[-1].relu)
             )
             if extends:
                 cur.append(n)
                 continue
             flush()
-            if n.op == "conv":
+            if n.op != "pool":
                 cur.append(n)
             # an orphan pool (no conv head) cannot start a pyramid; the
             # runner executes it as a plain op
+        elif (
+            n.op in EPILOGUE_OPS
+            and follows_tail(n)
+            and _absorb(_levels(tuple(cur), 0)[-1], n) is not None
+        ):
+            cur.append(n)
         else:
             flush()
     flush()
@@ -301,6 +366,17 @@ class _Builder:
 
     def pool(self, name, K, S, pad=0, *, src=None) -> str:
         return self._add(Node("pool", name, (src or self.tail,), K=K, S=S, pad=pad))
+
+    def depthwise(self, name, K, S, pad, *, src=None) -> str:
+        return self._add(
+            Node("depthwise", name, (src or self.tail,), K=K, S=S, pad=pad,
+                 relu=False)
+        )
+
+    def layernorm(self, name, eps, *, src=None) -> str:
+        return self._add(
+            Node("layernorm", name, (src or self.tail,), relu=False, eps=eps)
+        )
 
     def op(self, op, name, *srcs, n_out=0, relu=True) -> str:
         return self._add(
@@ -445,12 +521,57 @@ def resnet50(input_size: int = 224, num_classes: int = 1000, *,
     return b.graph("resnet50", input_size, 3, compute_dtype)
 
 
+# ConvNeXt-T (Liu et al. 2022, arXiv:2201.03545; torchvision convnext_tiny):
+# blocks and widths per stage, and the LayerNorm epsilon
+_CONVNEXT_T_DEPTHS = (3, 3, 9, 3)
+_CONVNEXT_T_WIDTHS = (96, 192, 384, 768)
+_CONVNEXT_LN_EPS = 1e-6
+
+
+def convnext_tiny(input_size: int = 224, num_classes: int = 1000, *,
+                  compute_dtype: str = "float32",
+                  depths: tuple[int, ...] = _CONVNEXT_T_DEPTHS) -> Graph:
+    """ConvNeXt-T: a 4x4/4 patchify stem and a LayerNorm, four stages of
+    blocks ``7x7 depthwise (bias) → LayerNorm → 1x1 to 4C, GELU → 1x1
+    back to C (linear) → layer scale → residual add`` at widths 96, 192,
+    384, 768, a LayerNorm and a 2x2/2 conv between stages, then global
+    average pool, LayerNorm and the classifier.  Every LayerNorm is over
+    channels with epsilon 1e-6; stochastic depth is the identity at
+    inference.  ``depths`` (published: 3, 3, 9, 3) lets tests run fewer
+    blocks at the published widths.  Each block's ``dw..scale`` is one
+    fusable chain of three levels; the downsample and head LayerNorms
+    follow no level and run as plain ops."""
+    b = _Builder()
+    b.conv("stem", 4, 4, 0, _CONVNEXT_T_WIDTHS[0], relu=False)
+    b.layernorm("stem_ln", _CONVNEXT_LN_EPS)
+    i = 0
+    for stage, (depth, width) in enumerate(zip(depths, _CONVNEXT_T_WIDTHS)):
+        if stage:
+            b.layernorm(f"s{stage}_ln", _CONVNEXT_LN_EPS)
+            b.conv(f"s{stage}_down", 2, 2, 0, width, relu=False)
+        for _ in range(depth):
+            blk, block_in = f"b{i}", b.tail
+            b.depthwise(f"{blk}_dw", 7, 1, 3, src=block_in)
+            b.layernorm(f"{blk}_ln", _CONVNEXT_LN_EPS)
+            b.conv(f"{blk}_pw1", 1, 1, 0, 4 * width, relu=False)
+            b.op("gelu", f"{blk}_gelu", relu=False)
+            b.conv(f"{blk}_pw2", 1, 1, 0, width, relu=False)
+            b.op("layer_scale", f"{blk}_scale", relu=False)
+            b.op("add", f"{blk}_add", b.tail, block_in)
+            i += 1
+    b.op("global_pool", "gap")
+    b.layernorm("head_ln", _CONVNEXT_LN_EPS)
+    b.op("dense", "FC", n_out=num_classes, relu=False)
+    return b.graph("convnext_tiny", input_size, 3, compute_dtype)
+
+
 MODELS = {
     "lenet": lenet5,
     "alexnet": alexnet,
     "vgg16": vgg16,
     "resnet18": resnet18,
     "resnet50": resnet50,
+    "convnext_tiny": convnext_tiny,
 }
 
 

@@ -47,12 +47,17 @@ INFEASIBLE = (float("inf"), float("inf"))
 
 # tracer counters bumped as each plan is built: pyramids planned with
 # level 0 in patch form; per-image MACs of every conv level planned; those
-# in pyramids of two or more conv levels; and those of conv levels whose
-# kernel contracts two or more taps a pass (core/program TileProgram.folds)
+# in pyramids of two or more levels with weights; those of conv levels
+# whose kernel contracts two or more taps a pass (core/program
+# TileProgram.folds); per-image MACs of every depthwise level planned; and
+# those of depthwise levels followed in their launch by another level (a
+# ConvNeXt block's pw1), whose output then never leaves VMEM
 PATCH_LEVELS = "fused.patch_levels"
 CONV_MACS = "fused.conv_macs"
 CHAINED_CONV_MACS = "fused.chained_conv_macs"
 FOLDED_CONV_MACS = "fused.folded_conv_macs"
+DW_MACS = "fused.dw_macs"
+CHAINED_DW_MACS = "fused.chained_dw_macs"
 
 
 @dataclass(frozen=True)
@@ -198,7 +203,7 @@ def partition_segment(
     cost: dict[tuple[int, int], tuple[float, float]] = {}
     for i in range(n):
         for j in range(i + 1, n + 1):
-            convs = sum(1 for g in groups[i:j] for l in g if l.kind == "conv")
+            convs = sum(1 for g in groups[i:j] for l in g if l.has_weights)
             if max_convs is not None and convs > max_convs:
                 cost[(i, j)] = INFEASIBLE
                 continue
@@ -270,28 +275,41 @@ def brute_force_segment(
 # ---------------------------------------------------------------------------
 
 
+def level_macs(spec: FusionSpec) -> list[tuple]:
+    """``(level, multiply-adds for one image)`` of each level with weights
+    of the spec, in order; a depthwise level's ``K*K*C`` a pixel."""
+    sizes = spec.feature_sizes()
+    return [
+        (lvl, lvl.K * lvl.K * lvl.n_out * sizes[l + 1] ** 2
+         * (1 if lvl.kind == "depthwise" else lvl.n_in))
+        for l, lvl in enumerate(spec.levels)
+        if lvl.has_weights
+    ]
+
+
 def conv_macs(spec: FusionSpec, where=lambda lvl: True) -> int:
     """Multiply-adds of the spec's conv levels (those ``where`` accepts)
     for one image."""
-    sizes = spec.feature_sizes()
     return sum(
-        lvl.K * lvl.K * lvl.n_in * lvl.n_out * sizes[l + 1] ** 2
-        for l, lvl in enumerate(spec.levels)
-        if lvl.kind == "conv" and where(lvl)
+        m for lvl, m in level_macs(spec) if lvl.kind == "conv" and where(lvl)
     )
 
 
 def _segment_pyramids(
     segment: Segment, launches: list[LaunchPlan]
 ) -> list[PyramidPlan]:
-    """Attach covered node names to each launch, walking the chain.  As the
-    plan is built, each pyramid whose level 0 runs in patch form bumps the
-    tracer's :data:`PATCH_LEVELS` counter once, every pyramid adds its conv
-    levels' per-image MACs to :data:`CONV_MACS`, a pyramid of two or more
-    conv levels adds them to :data:`CHAINED_CONV_MACS` too, and the levels
+    """Attach covered node names to each launch, walking the chain (a
+    level's absorbed epilogue nodes go with it).  As the plan is built,
+    each pyramid whose level 0 runs in patch form bumps the tracer's
+    :data:`PATCH_LEVELS` counter once, every pyramid adds its conv levels'
+    per-image MACs to :data:`CONV_MACS`, a pyramid of two or more levels
+    with weights adds them to :data:`CHAINED_CONV_MACS` too, and the levels
     the kernel runs two or more taps a pass add theirs to
-    :data:`FOLDED_CONV_MACS`."""
+    :data:`FOLDED_CONV_MACS`.  Its depthwise levels add their MACs to
+    :data:`DW_MACS`, and to :data:`CHAINED_DW_MACS` where another level
+    follows them in the launch."""
     out, li = [], 0
+    groups = segment.level_nodes
     tracer = get_tracer()
     for lp in launches:
         if lp.program.patch:
@@ -300,16 +318,20 @@ def _segment_pyramids(
         tracer.bump(CONV_MACS, macs)
         if lp.spec.q_convs > 1:
             tracer.bump(CHAINED_CONV_MACS, macs)
-        folds = iter(lp.program.folds())
-        tracer.bump(
-            FOLDED_CONV_MACS,
-            conv_macs(lp.program.kernel_spec, lambda lvl: next(folds) > 1),
-        )
+        kernel_macs = level_macs(lp.program.kernel_spec)
+        tracer.bump(FOLDED_CONV_MACS, sum(
+            m for (lvl, m), fold in zip(kernel_macs, lp.program.folds())
+            if lvl.kind == "conv" and fold > 1
+        ))
+        for i, (lvl, m) in enumerate(kernel_macs):
+            if lvl.kind == "depthwise":
+                tracer.bump(DW_MACS, m)
+                tracer.bump(CHAINED_DW_MACS, m if i + 1 < len(kernel_macs) else 0)
         n_levels = len(lp.spec.levels)
-        names = tuple(n.name for n in segment.nodes[li : li + n_levels])
+        names = tuple(itertools.chain.from_iterable(groups[li : li + n_levels]))
         out.append(PyramidPlan(launch=lp, node_names=names))
         li += n_levels
-    assert li == len(segment.nodes), "launches must tile the segment"
+    assert li == len(groups), "launches must tile the segment"
     return out
 
 
@@ -556,7 +578,7 @@ def paper_partition(
         elif si == 0 and head_convs is not None:
             convs = head = 0
             for gi, g in enumerate(groups):
-                convs += sum(1 for l in g if l.kind == "conv")
+                convs += sum(1 for l in g if l.has_weights)
                 if convs == head_convs:
                     head = gi + 1
                     break

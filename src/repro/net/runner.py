@@ -4,9 +4,10 @@
 sequence of fused-pyramid Pallas launches (one per chosen pyramid, weights
 resident or streamed per the plan) stitched together with the plain-JAX ops
 the plan left outside pyramids: residual adds, standalone activations,
-global pooling, flatten, and the dense classifier head.  The whole forward
-is jit-compiled with the plan as a static argument; the per-launch END skip
-flag maps are returned alongside the logits.
+LayerNorms that follow no level, global pooling, flatten, and the dense
+classifier head.  The whole forward is jit-compiled with the plan as a
+static argument; the per-launch END skip flag maps are returned alongside
+the logits.
 
 ``reference_network`` is the monolithic oracle: the same graph executed
 node-by-node with full intermediate feature maps via
@@ -35,6 +36,7 @@ import numpy as np
 
 from repro.core.cycle_model import DEFAULT_PARAMS
 from repro.core.dtypes import canonical_dtype, jnp_dtype
+from repro.core.executor import layer_norm
 from repro.kernels.fused_conv.ops import fused_pyramid
 from repro.obs.trace import LaunchSpan, get_tracer
 from repro.robust.guard import get_guard
@@ -42,7 +44,9 @@ from repro.robust.guard import get_guard
 from .graph import Graph, Node, infer_shapes
 from .partition import PartitionPlan, auto_partition
 
-Params = dict[str, tuple[jnp.ndarray, jnp.ndarray]]
+# node name -> its parameters: (w, b) of a conv, depthwise or dense node,
+# (gamma, beta) of a layernorm, (gamma,) of a layer_scale
+Params = dict[str, tuple[jnp.ndarray, ...]]
 
 # Documented end-to-end bf16 logit tolerance vs the f32 reference.  bf16
 # keeps f32's exponent range but only 8 mantissa bits: each layer's
@@ -66,21 +70,44 @@ def bf16_logit_tol(reference) -> float:
     )
 
 
+def param_shapes(n: Node, c_in: int) -> tuple[tuple[int, ...], ...]:
+    """The shapes of node ``n``'s parameters, given its input channels:
+    conv ``(K, K, Cin, Cout)`` + bias, depthwise ``(K, K, 1, C)`` + bias,
+    dense ``(fan_in, n_out)`` + bias, layernorm gamma and beta, layer_scale
+    gamma; ``()`` for a node without."""
+    if n.op == "conv":
+        return (n.K, n.K, c_in, n.n_out), (n.n_out,)
+    if n.op == "depthwise":
+        return (n.K, n.K, 1, c_in), (c_in,)
+    if n.op == "dense":
+        return (c_in, n.n_out), (n.n_out,)
+    return {"layernorm": ((c_in,), (c_in,)), "layer_scale": ((c_in,),)}.get(
+        n.op, ()
+    )
+
+
 def init_network_params(graph: Graph, key: jax.Array, scale: float = 1.0) -> Params:
-    """He-initialized weights for every conv and dense node, keyed by node
-    name: conv ``(K, K, Cin, Cout)`` + bias, dense ``(fan_in, n_out)`` + bias."""
+    """Parameters for every node that has them, keyed by node name:
+    He-initialized weights (fan-in all but the last axis) with biases of
+    standard deviation 0.01 for conv, depthwise and dense nodes; LayerNorm
+    gamma ``1 + 0.1 N(0, 1)`` and beta ``0.1 N(0, 1)``; layer-scale gamma
+    ``1 + 0.1 N(0, 1)`` (an identity-like 1e-6, torchvision's training
+    init, would hide the block from every comparison)."""
     shapes = infer_shapes(graph)
     params: Params = {}
     for n in graph.nodes:
-        if n.op not in ("conv", "dense"):
+        found = param_shapes(n, shapes[n.inputs[0]].channels if n.inputs else 0)
+        if not found:
             continue
-        key, k1, k2 = jax.random.split(key, 3)
-        c_in = shapes[n.inputs[0]].channels
-        fan_in = (n.K * n.K * c_in) if n.op == "conv" else c_in
-        shape = (n.K, n.K, c_in, n.n_out) if n.op == "conv" else (c_in, n.n_out)
-        w = jax.random.normal(k1, shape) * (scale * (2.0 / fan_in) ** 0.5)
-        b = jax.random.normal(k2, (n.n_out,)) * 0.01
-        params[n.name] = (w.astype(jnp.float32), b.astype(jnp.float32))
+        key, *keys = jax.random.split(key, 1 + len(found))
+        draws = [jax.random.normal(k, s) for k, s in zip(keys, found)]
+        if n.op in ("conv", "depthwise", "dense"):
+            w_shape = found[0]
+            fan_in = int(np.prod(w_shape[:-1]))
+            draws = [draws[0] * (scale * (2.0 / fan_in) ** 0.5), draws[1] * 0.01]
+        else:  # gamma around 1, then beta around 0
+            draws = [(1.0 if i == 0 else 0.0) + 0.1 * d for i, d in enumerate(draws)]
+        params[n.name] = tuple(d.astype(jnp.float32) for d in draws)
     return params
 
 
@@ -93,6 +120,7 @@ def _conv_node(x, n: Node, w, b):
         x, w, window_strides=(n.S, n.S),
         padding=[(n.pad, n.pad), (n.pad, n.pad)],
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        feature_group_count=x.shape[-1] if n.op == "depthwise" else 1,
         precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     ) + b
@@ -112,6 +140,16 @@ def _pool_node(x, n: Node):
 def _head_op(values, n: Node, params: Params, graph: Graph | None = None):
     if n.op == "relu":
         return jax.nn.relu(values[n.inputs[0]])
+    if n.op in ("layernorm", "gelu", "layer_scale"):
+        # in f32, cast back to the network dtype once
+        x = values[n.inputs[0]]
+        if n.op == "layernorm":
+            y = layer_norm(x, *params[n.name], n.eps)
+        elif n.op == "gelu":
+            y = jax.nn.gelu(x.astype(jnp.float32), approximate=False)
+        else:
+            y = x.astype(jnp.float32) * params[n.name][0]
+        return y.astype(x.dtype)
     if n.op == "add":
         return values[n.inputs[0]] + values[n.inputs[1]]
     if n.op == "global_pool":
@@ -136,8 +174,8 @@ def _head_op(values, n: Node, params: Params, graph: Graph | None = None):
 
     raise PreflightError(
         f"node {n.name!r} has op {n.op!r}, which the runner cannot execute"
-        " (expected one of relu/add/global_pool/flatten/dense outside"
-        " pyramids)",
+        " (expected one of relu/add/global_pool/flatten/dense/layernorm/"
+        "gelu/layer_scale outside pyramids)",
         node=n.name, op=n.op,
         graph=graph.name if graph is not None else None,
     )
@@ -151,7 +189,7 @@ def reference_network(x: jnp.ndarray, graph: Graph, params: Params) -> jnp.ndarr
     values = {graph.nodes[0].name: x.astype(jnp.float32)}
     with jax.default_matmul_precision("highest"):
         for n in graph.nodes[1:]:
-            if n.op == "conv":
+            if n.op in ("conv", "depthwise"):
                 w, b = params[n.name]
                 values[n.name] = _conv_node(values[n.inputs[0]], n, w, b)
             elif n.op == "pool":
@@ -173,7 +211,26 @@ def prepare_network_params(
     re-preparing at another dtype is safe.
     """
     jdt = jnp_dtype(plan.compute_dtype if dtype is None else dtype)
-    return {k: (w.astype(jdt), b.astype(jdt)) for k, (w, b) in params.items()}
+    return {k: tuple(a.astype(jdt) for a in v) for k, v in params.items()}
+
+
+def pyramid_operands(graph: Graph, node_names, params: Params):
+    """``(weights, biases, epilogues)`` of a pyramid covering
+    ``node_names``, per level with weights in chain order, as
+    :func:`~repro.kernels.fused_conv.ops.fused_pyramid` takes them:
+    ``epilogues[l]`` gathers the parameters of the layernorm and
+    layer_scale nodes the level absorbed."""
+    weights, biases, epilogues = [], [], []
+    for m in node_names:
+        op = graph.node(m).op
+        if op in ("conv", "depthwise"):
+            w, b = params[m]
+            weights.append(w)
+            biases.append(b)
+            epilogues.append(())
+        elif op in ("layernorm", "layer_scale"):
+            epilogues[-1] += tuple(params[m])
+    return weights, biases, epilogues
 
 
 def _forward(
@@ -204,11 +261,10 @@ def _forward(
             pyr = plan.pyramid_at(n.name)
             if pyr is None:
                 continue  # interior pyramid node: computed with its launch
-            conv_names = [m for m in pyr.node_names
-                          if graph.node(m).op == "conv"]
+            operands = pyramid_operands(graph, pyr.node_names, params)
             x_in = values[n.inputs[0]]
 
-            def call(pyr=pyr, x_in=x_in, conv_names=conv_names, **overrides):
+            def call(pyr=pyr, x_in=x_in, operands=operands, **overrides):
                 kwargs = dict(
                     spec=pyr.spec,
                     out_region=pyr.launch.out_region,
@@ -227,19 +283,14 @@ def _forward(
                 # wrapper retries may override launch knobs, e.g.
                 # call(interpret=True) on the degradation ladder
                 kwargs.update(overrides)
-                return fused_pyramid(
-                    x_in,
-                    [params[m][0] for m in conv_names],
-                    [params[m][1] for m in conv_names],
-                    **kwargs,
-                )
+                return fused_pyramid(x_in, *operands, **kwargs)
 
             y, skip = call() if launch_wrapper is None else launch_wrapper(
                 pyr, call, x_in
             )
             values[pyr.node_names[-1]] = y
             skips[pyr.name] = skip
-        elif n.op == "conv":
+        elif n.op in ("conv", "depthwise"):
             w, b = params[n.name]
             values[n.name] = _conv_node(
                 values[n.inputs[0]], n, w.astype(jdt), b.astype(jdt)

@@ -2,11 +2,14 @@
 
 Prints the auto-partition of a zoo model as a per-launch table (covered
 nodes, Q, grid, level-0 form, regime, plan knobs, modeled HBM/VMEM bytes
-with budget headroom, modeled cycles, each conv level's taps per MXU pass
-and activation), the pyramids whose level 0 runs in patch form, and the
-plan build's conv-MAC counters (all conv MACs, those in pyramids of two or
-more convs, and those of levels run two or more taps a pass), and
-optionally:
+with budget headroom, modeled cycles, each level's taps per MXU pass and
+its kind, activation and epilogue: ``relu``, ``linear``, ``gelu``, with
+``dw:`` before a depthwise level and ``+ln`` / ``+scale`` after a
+LayerNorm / layer scale), the pyramids whose level 0 runs in patch form,
+and the plan build's conv-MAC counters (all conv MACs, those in pyramids of
+two or more levels, and those of levels run two or more taps a pass; for a
+model with depthwise levels, their MACs and those chained to the next
+level in their launch), and optionally:
 
 * ``--trace out.json`` — export a Chrome-trace / Perfetto JSON of every
   launch's modeled fill/steady/drain DMA-vs-MXU timeline
@@ -46,11 +49,20 @@ from repro.core.cycle_model import DEFAULT_PARAMS
 # interpret-friendly --run scales (paper scale for LeNet only); the table
 # itself defaults to paper scale via the graph builders
 RUN_SIZE = {"lenet": 32, "alexnet": 67, "vgg16": 32, "resnet18": 32,
-            "resnet50": 32}
+            "resnet50": 32, "convnext_tiny": 32}
 
 
 def _fmt_bytes(n: int) -> str:
     return f"{n / 1024:,.0f}K" if n < 32 * 1024 * 1024 else f"{n / 2**20:,.1f}M"
+
+
+def level_label(lvl) -> str:
+    """A level's kind, activation and epilogue as the plan table prints
+    it: ``relu``, ``linear`` or ``gelu``, ``dw:`` before a depthwise
+    level's, ``+ln`` and ``+scale`` after."""
+    parts = ["relu" if lvl.relu else "gelu" if lvl.gelu else "linear"]
+    parts += ["ln"] * (lvl.norm_eps is not None) + ["scale"] * lvl.scale
+    return ("dw:" if lvl.kind == "depthwise" else "") + "+".join(parts)
 
 
 def plan_table(plan, vmem_budget: int, out=print) -> None:
@@ -75,8 +87,8 @@ def plan_table(plan, vmem_budget: int, out=print) -> None:
             f"{d['modeled_cycles']:>10,} "
             f"{d['modeled_cycles'] / DEFAULT_PARAMS.freq_mhz:>9,.1f}  "
             + f"{' '.join(map(str, p.launch.program.folds())):<12} "
-            + " ".join("relu" if lvl.relu else "linear"
-                       for lvl in p.spec.levels if lvl.kind == "conv")
+            + " ".join(level_label(lvl)
+                       for lvl in p.spec.levels if lvl.has_weights)
         )
     out(
         f"total: {plan.n_launches()} launches, "
@@ -190,7 +202,9 @@ def main(argv: list[str] | None = None) -> int:
     from repro.net.graph import MODELS
     from repro.net.partition import (
         CHAINED_CONV_MACS,
+        CHAINED_DW_MACS,
         CONV_MACS,
+        DW_MACS,
         FOLDED_CONV_MACS,
         PATCH_LEVELS,
         auto_partition,
@@ -236,12 +250,13 @@ def main(argv: list[str] | None = None) -> int:
     graph = MODELS[args.model](**kwargs)
 
     tracer = get_tracer()
-    counters = (PATCH_LEVELS, CONV_MACS, CHAINED_CONV_MACS, FOLDED_CONV_MACS)
+    counters = (PATCH_LEVELS, CONV_MACS, CHAINED_CONV_MACS, FOLDED_CONV_MACS,
+                DW_MACS, CHAINED_DW_MACS)
     before = [tracer.counters.get(c, 0) for c in counters]
     plan = auto_partition(
         graph, batch=args.batch, vmem_budget=args.vmem_budget
     )
-    built, macs, chained, folded = (
+    built, macs, chained, folded, dw, dw_chained = (
         tracer.counters.get(c, 0) - b for c, b in zip(counters, before)
     )
     print(
@@ -262,6 +277,12 @@ def main(argv: list[str] | None = None) -> int:
         f"{CHAINED_CONV_MACS} +{chained:,}{share}, "
         f"{FOLDED_CONV_MACS} +{folded:,}{fshare}"
     )
+    if dw:
+        print(
+            f"depthwise MACs per image: {DW_MACS} +{dw:,}, "
+            f"{CHAINED_DW_MACS} +{dw_chained:,} "
+            f"({100 * dw_chained / dw:.1f}% chained)"
+        )
     info = partition_cache_info()
     print(
         f"partition cache: {info.hits} hits / {info.misses} misses "

@@ -110,19 +110,21 @@ def _reference_walk(x_in, pyr, graph, params, jdt, magnitude_limit=None):
     sentinel, or ``None`` when the recompute is clean — i.e. the original
     fault did not reproduce and was the kernel execution itself.
     """
-    from repro.net.runner import _conv_node, _pool_node
+    from repro.net.runner import _conv_node, _head_op, _pool_node
 
     y = x_in
     level = -1
     first_bad = None
     for nm in pyr.node_names:
         n = graph.node(nm)
-        if n.op == "conv":
+        if n.op in ("conv", "depthwise"):
             level += 1
             w, b = params[nm]
             y = _conv_node(y, n, w.astype(jdt), b.astype(jdt))
-        else:
+        elif n.op == "pool":
             y = _pool_node(y, n)
+        else:  # an epilogue node the level absorbed
+            y = _head_op({n.inputs[0]: y}, n, params, graph)
         if first_bad is None:
             if sentinel_trips(sentinel_stats(y), magnitude_limit) is not None:
                 first_bad = level
@@ -134,15 +136,14 @@ def _run_subplan(x_in, subs, params, graph, cdt, *, end_skip, interpret,
     """Execute a replanned pyramid chain: each sub-pyramid as its own fused
     launch."""
     from repro.kernels.fused_conv.ops import fused_pyramid
+    from repro.net.runner import pyramid_operands
 
     y = x_in
     sub_skips = {}
     for sp in subs:
-        conv_names = [m for m in sp.node_names if graph.node(m).op == "conv"]
         y, sk = fused_pyramid(
             y,
-            [params[m][0] for m in conv_names],
-            [params[m][1] for m in conv_names],
+            *pyramid_operands(graph, sp.node_names, params),
             spec=sp.spec,
             out_region=sp.launch.out_region,
             streamed=sp.launch.streamed,

@@ -146,6 +146,8 @@ def check_request(x, graph, *, require_finite: bool = True) -> None:
 
 
 def _check_plan_structure(plan) -> None:
+    from repro.net.graph import EPILOGUE_OPS
+
     graph = plan.graph
     names = {n.name for n in graph.nodes}
     for pyr in plan.pyramids:
@@ -157,7 +159,7 @@ def _check_plan_structure(plan) -> None:
                     launch=pyr.name,
                 )
             op = graph.node(nm).op
-            if op not in ("conv", "pool"):
+            if op not in ("conv", "depthwise", "pool") + EPILOGUE_OPS:
                 raise PreflightError(
                     f"plan pyramid {pyr.name} covers node {nm!r} of op"
                     f" {op!r}; pyramids fuse conv/pool chains only",
